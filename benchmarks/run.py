@@ -199,7 +199,7 @@ def auto_plan_agreement_smoke() -> dict:
     from repro.core.ntt import distributed as dntt
     from repro.core.pim import (FOURIERPIM_8, FP32, INT32, aritpim,
                                 fft_pim, ntt_pim, polymul_pim)
-    from repro.dist import collectives
+    from repro.dist import collectives, compat
 
     cfg = FOURIERPIM_8
     rng = np.random.default_rng(0)
@@ -262,7 +262,7 @@ def auto_plan_agreement_smoke() -> dict:
         distributed candidate, traced at the REAL shard count on an
         AbstractMesh (no devices needed for a ``lower()`` trace, so the
         single-CPU smoke can measure the D=64 tier it plans for)."""
-        mesh = jax.sharding.AbstractMesh((("model", D),))
+        mesh = compat.abstract_mesh((D,), ("model",))
         if wl == "polymul-mod":
             params = NTTParams.make(n)
             build = dntt.make_sharded_ntt_polymul(
@@ -454,11 +454,11 @@ def bench_fourier_smoke(path: str = "BENCH_fourier.json") -> dict:
         emit(f"smoke/pim_polymul_real/n={n}", 0.0,
              f"cycle_ratio={ratio:.3f};gate<={REAL_COMPLEX_CYCLE_GATE}")
 
-    # Interpret-mode wall clock: the serve fast path (two-for-one + paired
-    # inverse = 1.5 transforms/product) must beat the complex kernel's 3
-    # even through the Pallas interpreter. The shape is large enough that
-    # butterfly work dominates the interpreter/XLA-op overhead (smaller
-    # shapes are overhead-bound and time ~equal).
+    # Interpret-mode wall clock: the serve fast path (half-spectrum real
+    # polymul, half the matrix work per product) against the complex
+    # kernel, through the Pallas interpreter. The shape is large enough that
+    # matrix work dominates the interpreter/XLA-op overhead (smaller shapes
+    # are overhead-bound and time ~equal).
     B, n = 16, 8192
     rng = np.random.default_rng(0)
     a = jnp.asarray(rng.standard_normal((B, n)), jnp.float32)
@@ -656,6 +656,8 @@ def main(argv=None) -> None:
                     help="reduced CI subset (~seconds, asserts harness "
                          "wiring instead of sweeping)")
     args = ap.parse_args(argv)
+    from repro.launch.cache import setup_compile_cache
+    setup_compile_cache()
     print("name,us_per_call,derived")
     if args.smoke:
         smoke()

@@ -3,7 +3,7 @@
 Given (batch, n, mesh) decide:
   * execution tier: single-device Pallas kernel (batch-sharded) vs.
     distributed four-step FFT (sequence-sharded over the model axis);
-  * kernel config: radix (2 or 4), batch block (VMEM budget).
+  * kernel config: batch block (VMEM budget).
 
 The decision mirrors the paper's configuration ladder (§4.3-4.5): the
 r/2r-configurations are "fits in one array" (-> our single-kernel tier, batch
@@ -15,13 +15,15 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.kernels.fft import VMEM_BUDGET_BYTES, plan_batch_block
+from repro.kernels.fft import plan_batch_block
 
 
 @dataclasses.dataclass(frozen=True)
 class FFTPlan:
     tier: str           # "local" | "distributed"
-    radix: int          # 2 or 4
+    #: butterfly radix; only the distributed tiers' local XLA stages read
+    #: it (the local Pallas kernels are radix-free, docs/fourier.md)
+    radix: int
     block_b: int        # batch block per kernel invocation (local tier)
     seq_shards: int     # model-axis shards of the sequence (distributed tier)
     #: exact modular route: transforms dispatch to the NTT kernel
@@ -29,9 +31,9 @@ class FFTPlan:
     #: FFT — required for crypto polymul where results must be bit-exact
     #: mod q (docs/ntt.md).
     exact: bool = False
-    #: real-Hermitian route: two-for-one packed rfft/irfft kernels and the
-    #: paired-inverse real polymul (kernels.fft.rfft_planes /
-    #: kernels.polymul.polymul_real_planes) — half the butterflies and HBM
+    #: real-Hermitian route: half-spectrum rfft/irfft kernels and the
+    #: half-spectrum real polymul (kernels.fft.rfft_planes /
+    #: kernels.polymul.polymul_real_planes) — half the matrix work and HBM
     #: traffic of the complex tier on real input, with the doubled batch
     #: block the halved working set buys (docs/fourier.md).
     real: bool = False
@@ -46,28 +48,27 @@ class FFTPlan:
         if self.exact:
             kind = "NTT (exact mod-q)"
         elif self.real:
-            kind = "real-packed FFT (two-for-one Hermitian)"
+            kind = "real-packed FFT (half spectrum)"
         else:
             kind = "FFT"
         if self.tier == "local":
-            return (f"local Pallas {kind} kernel, radix-{self.radix}, "
+            algo = ("radix-2 in-place stages" if self.exact
+                    else "four-step DFT on the MXU")
+            return (f"local Pallas {kind} kernel, {algo}, "
                     f"batch block {self.block_b} (VMEM-resident)")
         return (f"four-step distributed {kind} over {self.seq_shards} "
                 f"devices, radix-{self.radix} local stages")
 
 
-# A single sequence must keep ~2 fp32 planes x live factor in VMEM.
-_MAX_LOCAL_N = VMEM_BUDGET_BYTES // (2 * 4 * 4)   # = 256K points
-# Exact tier: one uint32 residue plane, ~4 live copies in the fused polymul
-# (operands + transforms) — twice the float threshold per byte of VMEM.
-_MAX_LOCAL_N_EXACT = VMEM_BUDGET_BYTES // (4 * 4)  # = 512K points
-# Real tier: one fp32 plane per point PER SEQUENCE, but the minimum
-# schedulable unit is a PAIR of rows packed into one full complex row
-# (the kernels require even blocks), so the longest local sequence matches
-# the complex tier — the packing doubles the batch block, not the ceiling.
-# (Unlike the exact tier, whose single-uint32-plane rows schedule at
-# blk=1 and genuinely halve the per-point footprint.)
-_MAX_LOCAL_N_REAL = _MAX_LOCAL_N                   # = 256K points
+# Local ceilings are compile facts for v5e (tests/test_tpu_compile.py
+# compiles the kernels there): at 2^16 every kernel compiles and its planned
+# block fits the VMEM budget; at 2^18 the float kernels run out of VMEM even
+# at one row. The exact tier holds per-stage twiddle planes instead of DFT
+# matrices and has the same ceiling. The real tier's rows are half the
+# planes of a complex row, which doubles the batch block, not the ceiling.
+_MAX_LOCAL_N = 1 << 16
+_MAX_LOCAL_N_EXACT = 1 << 16
+_MAX_LOCAL_N_REAL = _MAX_LOCAL_N
 
 
 def plan(n: int, batch: int, *, model_shards: int = 1,
@@ -85,11 +86,11 @@ def plan(n: int, batch: int, *, model_shards: int = 1,
     (``NTTParams.subparams``) and ledger-accounted all-to-alls — the plan
     comes back with ``seq_shards > 1`` and ``exact=True``.
     ``real=True`` routes real-coefficient workloads (the paper's polymul
-    serving case) to the two-for-one packed tier: the rfft/irfft kernels and
-    the paired-inverse ``polymul_real`` with the DOUBLED batch block
+    serving case) to the half-spectrum tier: the rfft/irfft kernels and
+    the real ``polymul_real`` with the DOUBLED batch block
     (``plan_batch_block(n, real=True)``) the halved per-row footprint buys.
-    The local-n ceiling matches the complex tier (the minimum block is a
-    row pair = one full complex row). Mutually exclusive with ``exact``
+    The local-n ceiling matches the complex tier. Mutually exclusive with
+    ``exact``
     (residues are not packed).
     ``force_distributed=True`` pins the distributed tier even where the
     policy would keep the sequence local (serve's explicit --model-shards

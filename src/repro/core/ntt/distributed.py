@@ -21,9 +21,10 @@ Z-order directly, saving 2 all-to-alls per transform — the collective-level
 analogue of the paper's §5 DFT·IDFT permutation cancellation, same as the
 float path.
 
-All local butterflies are the *same jnp Montgomery arithmetic the Pallas
-kernel runs* (``kernels.ntt.ntt_stages`` — plain jnp, usable outside
-pallas_call), so distributed == local is exact ``==``, never allclose.
+The local sub-transforms are a plain-jnp radix-2 Stockham sweep built on the
+Pallas kernel's own Montgomery primitives (``kernels.ntt._mont_mul`` and
+friends). Arithmetic mod q is exact, so distributed == local is exact
+``==``, never allclose, whatever butterfly order each side runs.
 Every all-to-all goes through ``dist.collectives.all_to_all``, so traced
 traffic lands in the byte ledger; ``four_step_collective_stats`` is the
 closed form tests pin against that ledger.
@@ -53,12 +54,40 @@ def _mont_u32(params: NTTParams, values: np.ndarray) -> np.ndarray:
     return params.to_montgomery(values).astype(np.uint32)
 
 
+def _master_table(params: NTTParams, base: int) -> jnp.ndarray:
+    """(1, n) uint32 Montgomery-form powers of ``base``."""
+    pw = params.powers(base)
+    return jnp.asarray(params.to_montgomery(pw).astype(np.uint32)[None, :])
+
+
+def _stockham(x, w, *, n: int, q: int, qinv: int):
+    """Radix-2 Stockham sweeps of (B, n) residues, natural order in and out.
+
+    Stage L views the row as (B, L, r) and uses the strided slice
+    W[::n/(2L)][:L] of the master Montgomery table ``w`` — XLA slices with
+    any stride, so this runs outside any kernel.
+    """
+    b = x.shape[0]
+    y = x.reshape(b, 1, n)
+    L, r = 1, n
+    while r > 1:
+        half = r // 2
+        e, o = y[:, :, :half], y[:, :, half:]
+        stride = n // (2 * L)
+        tw = jax.lax.slice_in_dim(w, 0, L * stride, stride, axis=1)
+        t = kntt._mont_mul(o, tw[:, :, None], q, qinv)
+        y = jnp.concatenate([kntt._add_mod(e, t, q), kntt._sub_mod(e, t, q)],
+                            axis=1)
+        L, r = 2 * L, half
+    return y.reshape(b, n)
+
+
 def _local_ntt(x: jax.Array, sub: NTTParams, *, inverse: bool) -> jax.Array:
     """Unscaled last-axis transform of (..., m) with the sub-length roots."""
-    w = kntt._master_table(sub, sub.w_inv if inverse else sub.w)
+    w = _master_table(sub, sub.w_inv if inverse else sub.w)
     shp = x.shape
-    y = kntt.ntt_stages(x.reshape(-1, shp[-1]), w, n=shp[-1], q=sub.q,
-                        qinv=sub.qinv)
+    y = _stockham(x.reshape(-1, shp[-1]), w, n=shp[-1], q=sub.q,
+                  qinv=sub.qinv)
     return y.reshape(shp)
 
 

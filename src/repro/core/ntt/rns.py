@@ -262,7 +262,8 @@ def rns_polymul_reference(a, b, rns: RNSParams, *,
 
 
 def rns_polymul(a, b, rns: RNSParams, *, negacyclic: bool = True,
-                interpret: bool = True, block_b: int | None = None
+                interpret: bool | None = None,
+                block_b: int | None = None
                 ) -> np.ndarray:
     """Exact product mod (x^n ± 1, Q) through the fused Pallas kernel: one
     launch for all k limbs (``kernels.ntt.rns_ntt_polymul``), then CRT.

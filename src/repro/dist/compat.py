@@ -1,83 +1,48 @@
-"""Version shims over jax's distribution APIs.
+"""Thin wrappers over jax's distribution APIs.
 
-The distribution layer is written against present-day jax (``jax.shard_map``
-with ``check_vma``, ``jax.set_mesh``, ``jax.sharding.AxisType``); the pinned
-toolchain may be an older 0.4.x jaxlib where those live under different
-names (``jax.experimental.shard_map`` with ``check_rep``, the resource-env
-``with mesh:`` context, no axis types). Everything in repro that touches
-meshes or shard_map goes through this module so call sites read like
-current jax and keep working unchanged when the toolchain moves.
+Everything in repro that builds meshes or calls shard_map goes through this
+module, so a change in those APIs (jax 0.9 made ``AbstractMesh`` take axis
+sizes and names as two sequences) is absorbed in one place and call sites
+stay as they are.
 """
 from __future__ import annotations
 
 import contextlib
-import inspect
 
 import jax
 
-# ---------------------------------------------------------------------------
-# shard_map: jax.shard_map(check_vma=) vs jax.experimental check_rep=
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised only on old jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` with the replication-check kwarg name papered over."""
-    kwargs = {}
-    if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kwargs["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# Mesh construction: axis_types appeared with sharding-in-types
-# ---------------------------------------------------------------------------
-
-_MAKE_MESH_PARAMS = frozenset(inspect.signature(jax.make_mesh).parameters)
+    """``jax.shard_map``; ``check_vma=None`` keeps jax's default."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def axis_types_auto(n: int):
-    """``(AxisType.Auto,) * n`` on jax versions that have axis types."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return (axis_type.Auto,) * n if axis_type is not None else None
+    """``(AxisType.Auto,) * n``."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None):
     kwargs = {}
     if devices is not None:
         kwargs["devices"] = devices
-    if axis_types is not None and "axis_types" in _MAKE_MESH_PARAMS:
+    if axis_types is not None:
         kwargs["axis_types"] = axis_types
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
 
 
-# ---------------------------------------------------------------------------
-# Ambient mesh context: jax.set_mesh vs the legacy resource env
-# ---------------------------------------------------------------------------
+def abstract_mesh(axis_shapes, axis_names) -> jax.sharding.AbstractMesh:
+    """A device-free mesh of the given axis sizes and names, for tracing a
+    sharded program at a shard count the host does not have."""
+    return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names))
+
 
 @contextlib.contextmanager
 def set_mesh(mesh):
-    """Activate ``mesh`` for jit tracing under it.
-
-    On new jax this is ``jax.set_mesh``; on old jax it is the legacy
-    ``with mesh:`` resource env. Both additionally push the mesh onto
-    ``repro.dist.sharding``'s ambient stack so ``constrain`` resolves it.
-    """
+    """Activate ``mesh`` for jit tracing under it: ``jax.set_mesh``, plus
+    ``repro.dist.sharding``'s ambient stack so ``constrain`` resolves it."""
     from repro.dist import sharding
-    with contextlib.ExitStack() as stack:
-        if hasattr(jax, "set_mesh"):
-            stack.enter_context(jax.set_mesh(mesh))
-        else:
-            stack.enter_context(mesh)
-        stack.enter_context(sharding.use_mesh(mesh))
+    with jax.set_mesh(mesh), sharding.use_mesh(mesh):
         yield mesh

@@ -104,16 +104,14 @@ def current_mesh() -> Mesh | None:
     """The ambient mesh: ``use_mesh`` stack, else jax's own mesh context."""
     if _STATE.mesh_stack:
         return _STATE.mesh_stack[-1]
-    # New jax: a concrete mesh activated by jax.set_mesh.
-    get_mesh = getattr(jax.sharding, "get_mesh", None)
-    if get_mesh is not None:
-        try:
-            m = get_mesh()
-            if isinstance(m, Mesh) and not m.empty:
-                return m
-        except Exception:
-            pass
-    # Old jax: the legacy resource env filled by ``with mesh:``.
+    # A concrete mesh activated by jax.set_mesh (get_mesh raises under jit).
+    try:
+        m = jax.sharding.get_mesh()
+        if isinstance(m, Mesh) and not m.empty:
+            return m
+    except ValueError:
+        pass
+    # The resource env filled by ``with mesh:``.
     try:
         from jax._src import mesh as _mesh_lib
         m = _mesh_lib.thread_resources.env.physical_mesh

@@ -79,10 +79,7 @@ def _load_manifest(final: str) -> dict:
 
 
 def _flatten_with_paths(tree: Any):
-    # jax.tree.flatten_with_path is newer jax; tree_util spells it on 0.4.x
-    flatten = getattr(jax.tree, "flatten_with_path",
-                      jax.tree_util.tree_flatten_with_path)
-    flat, treedef = flatten(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     out = []
     for path, leaf in flat:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))

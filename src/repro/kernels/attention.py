@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fft import resolve_interpret
+
 NEG = -1e30
 
 
@@ -71,11 +73,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     window: int = 1 << 30, causal: bool = True,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q, k, v: (H, S, hd) (fold batch/GQA groups into H upstream).
 
     Returns (H, S, hd). Blocks padded to bq/bk internally.
     """
+    interpret = resolve_interpret(interpret)
     H, S, hd = q.shape
     bq = min(bq, S)
     bk = min(bk, S)

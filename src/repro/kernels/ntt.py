@@ -1,10 +1,11 @@
-"""Pallas TPU kernel: batched Stockham NTT + fused modular polymul.
+"""Pallas TPU kernel: batched NTT + fused modular polymul.
 
 The exact (mod-q) counterpart of ``kernels/fft.py`` / ``kernels/polymul.py``:
-the same single-VMEM-residency Stockham schedule, with the complex butterfly
-replaced by a modular one in uint32 lanes. This opens the paper's §5 crypto
-workload end to end — RLWE/FHE polynomial products must be bit-exact, which
-the float FFT path cannot deliver.
+the same single-VMEM-residency, row-at-a-time structure over the same (R, C)
+view of a row (``kernels.fft.split``), with radix-2 modular butterflies in
+uint32 lanes. This opens the paper's §5 crypto workload end to end —
+RLWE/FHE polynomial products must be bit-exact, which the float FFT path
+cannot deliver.
 
 Arithmetic strategy (all in 32-bit lanes; no 64-bit integers needed, so the
 kernel runs identically under jax's default x64-disabled config and on TPU):
@@ -18,15 +19,21 @@ kernel runs identically under jax's default x64-disabled config and on TPU):
   butterfly multiply is ONE Montgomery REDC: mont(v, w_mont) = v*w mod q.
   Data itself stays in the normal domain throughout — the same trick NTT
   libraries use so no domain conversion passes are needed.
-* The fused ``ntt_polymul`` folds the negacyclic psi-twist into the input
-  multiply and the psi^{-1}/n untwist into the output multiply — the exact
-  analogue of ``kernels/polymul.py``'s permutation-cancellation/scaling
-  fusion (paper §5): forward x2 -> pointwise modmul -> inverse, one VMEM
-  residency, zero extra passes for twist/scale.
 
-Batching reuses ``plan_batch_block`` from kernels/fft.py: a uint32 residue
-plane is half the footprint of the fp32 complex planes, so the FFT's block
-plan is strictly conservative here.
+Butterfly schedule (docs/ntt.md): in-place, no permutation inside a kernel.
+A stage of distance d pairs element i with i ^ d. For d >= C the partner is
+d/C sublane rows away, below C it is d lanes away; either way it is one
+``pltpu.roll`` of the (R, C) row plane plus a select, so no stage ever
+shrinks or strides the lane axis. The forward transform is decimation in
+frequency (natural in, bit-reversed out), the inverse decimation in time
+(bit-reversed in, natural out): in the fused polymul the two orders cancel
+(the paper's §5 permutation cancellation), and ``ntt_batched`` restores
+natural order with one gather outside the kernel.
+
+The fused ``ntt_polymul`` folds the negacyclic psi-twist into the input
+multiply and the psi^{-1}/n untwist into the output multiply — the exact
+analogue of ``kernels/polymul.py``'s fusion (paper §5): forward x2 ->
+pointwise modmul -> inverse, one VMEM residency, zero extra passes.
 """
 from __future__ import annotations
 
@@ -36,9 +43,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.ntt.ref import NTTParams
-from repro.kernels.fft import plan_batch_block
+from repro.core.ntt.ref import NTTParams, bit_reverse_indices
+from repro.kernels.fft import (_fit_block, batched_call, for_each_row,
+                               pad_rows, plan_batch_block, resolve_interpret,
+                               split)
 
 # Plain Python ints: weak-typed scalars stay out of the kernel closure
 # (pallas_call rejects captured traced constants).
@@ -100,112 +110,94 @@ def _sub_mod(a, b, q):
 
 
 # ---------------------------------------------------------------------------
-# Stockham sweeps (mirrors kernels/fft.py::stockham_stages, radix-2)
+# In-place radix-2 stages on one (R, C) row plane
 # ---------------------------------------------------------------------------
 
-def _ntt_radix2_stage(y, w, L, r, n, q: int, qinv: int):
-    """One Stockham sweep (B, L, r) -> (B, 2L, r/2) over F_q."""
-    half = r // 2
-    e = y[:, :, :half]
-    o = y[:, :, half:]
-    stride = n // (2 * L)
-    tw = jax.lax.slice_in_dim(w, 0, L * stride, stride, axis=1)[:, :, None]
-    t = _mont_mul(o, tw, q, qinv)
-    return jnp.concatenate([_add_mod(e, t, q), _sub_mod(e, t, q)], axis=1)
+def _distances(n: int) -> list[int]:
+    """Butterfly distances of the decimation-in-frequency order: n/2 .. 1."""
+    return [n >> s for s in range(1, n.bit_length())]
 
 
-def ntt_stages(x, w, *, n: int, q: int, qinv: int):
-    """All Stockham sweeps on VMEM-resident values.
+def _partner(x, d: int, axis: int):
+    """(x[i ^ d] along ``axis``, mask of positions whose bit d is set).
 
-    x: (B_blk, n) uint32 residues. w: (1, n) master Montgomery twiddle table
-    (powers of the n-th root; of its inverse for the inverse transform).
-    Output is in natural order — Stockham autosorts, so like the float
-    kernel there is no bit-reversal permutation anywhere.
+    ``pltpu.roll`` by d brings either x[i - d] or x[i + d] to position i;
+    rolling the index plane the same way tells which, so the select holds
+    whichever direction the roll has.
     """
-    b = x.shape[0]
-    y = x.reshape(b, 1, n)
-    L, r = 1, n
-    while r > 1:
-        y = _ntt_radix2_stage(y, w, L, r, n, q, qinv)
-        L, r = 2 * L, r // 2
-    return y.reshape(b, n)
+    i = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    down = pltpu.roll(i, d, axis) == (i ^ d)
+    p = jnp.where(down, pltpu.roll(x, d, axis),
+                  pltpu.roll(x, x.shape[axis] - d, axis))
+    return p, (i & d) != 0
 
 
-def _ntt_kernel(w_ref, x_ref, o_ref, *, n: int, q: int, qinv: int,
-                scale_mont: int | None):
-    y = ntt_stages(x_ref[...], w_ref[...], n=n, q=q, qinv=qinv)
-    if scale_mont is not None:     # inverse: fold in n^-1 (Montgomery form)
-        y = _mont_mul(y, jnp.uint32(scale_mont), q, qinv)
-    o_ref[...] = y
+def _stage(rows, lanes, s: int, d: int, shape):
+    """(roll axis, distance along it, twiddle plane) of the stage with
+    element distance d, the s-th of the DIF order, on an (R, C) plane."""
+    r_, c_ = shape
+    if d >= c_:
+        return 0, d // c_, rows[s]
+    return 1, d, lanes[s - (r_.bit_length() - 1)]
 
 
-def _rns_ntt_polymul_kernel(scal_ref, wf_ref, wi_ref, twist_ref, untwist_ref,
-                            a_ref, b_ref, c_ref, *, n: int, negacyclic: bool,
-                            prefetch: bool):
-    """One grid cell = one (limb, batch-block) tile of the RNS polymul.
+def ntt_dif(x, rows, lanes, q, qinv):
+    """Forward cyclic NTT of one (R, C) row, natural in, bit-reversed out.
 
-    Identical dataflow to ``_ntt_polymul_kernel``; the limb's modulus
-    constants are *data* (scal_ref row: q, qinv, r2) instead of closure
-    constants, which is what lets k different-q transforms share a single
-    pallas launch on the (limb, batch) grid.
-
-    ``prefetch=True`` is the scalar-prefetch layout
-    (``pltpu.PrefetchScalarGridSpec``): ``scal_ref`` is the WHOLE (k, 4)
-    table resident in SMEM before the body runs — the per-limb constants
-    never occupy a VMEM block and are available for the twiddle DMAs.
-    ``prefetch=False`` is the scalar-Ref fallback (a (1, 4) VMEM block per
-    grid cell), kept for backends/modes without SMEM prefetch. Both paths
-    are pinned bit-exactly equal in tests/test_rns_ntt.py.
+    ``rows``/``lanes`` are the ``stage_tables(..., dif=True)`` ref or array
+    stacks: stage s turns the pair (u, v) into (u + v, (u - v) * w_s), with
+    Montgomery one at the lower positions so one multiply covers both.
     """
-    row = pl.program_id(0) if prefetch else 0
-    q = scal_ref[row, 0]
-    qinv = scal_ref[row, 1]
-    r2 = scal_ref[row, 2]
-    wf = wf_ref[...]
-    wi = wi_ref[...]
-    a = a_ref[0]
-    b = b_ref[0]
-    if negacyclic:
-        tw = twist_ref[...]
-        a = _mont_mul(a, tw, q, qinv)
-        b = _mont_mul(b, tw, q, qinv)
-    fa = ntt_stages(a, wf, n=n, q=q, qinv=qinv)
-    fb = ntt_stages(b, wf, n=n, q=q, qinv=qinv)
-    p = _mont_mul(_mont_mul(fa, r2, q, qinv), fb, q, qinv)
-    c = ntt_stages(p, wi, n=n, q=q, qinv=qinv)
-    c_ref[...] = _mont_mul(c, untwist_ref[...], q, qinv)[None]
+    for s, dist in enumerate(_distances(x.size)):
+        axis, d, tw = _stage(rows, lanes, s, dist, x.shape)
+        p, up = _partner(x, d, axis)
+        v = jnp.where(up, _sub_mod(p, x, q), _add_mod(x, p, q))
+        x = _mont_mul(v, tw, q, qinv)
+    return x
 
 
-def _ntt_polymul_kernel(wf_ref, wi_ref, twist_ref, untwist_ref,
-                        a_ref, b_ref, c_ref, *, n: int, q: int, qinv: int,
-                        r2: int, negacyclic: bool):
-    """Fused modular polymul: twist -> NTT x2 -> pointwise -> INTT -> untwist,
-    one VMEM residency (paper §5 structure, exact arithmetic)."""
-    wf = wf_ref[...]
-    wi = wi_ref[...]
-    a = a_ref[...]
-    b = b_ref[...]
-    if negacyclic:                 # psi^j twist: x^n+1 products via cyclic NTT
-        tw = twist_ref[...]
-        a = _mont_mul(a, tw, q, qinv)
-        b = _mont_mul(b, tw, q, qinv)
-    fa = ntt_stages(a, wf, n=n, q=q, qinv=qinv)
-    fb = ntt_stages(b, wf, n=n, q=q, qinv=qinv)
-    # Pointwise product needs one operand in Montgomery form first (r2 hop).
-    p = _mont_mul(_mont_mul(fa, jnp.uint32(r2), q, qinv), fb, q, qinv)
-    c = ntt_stages(p, wi, n=n, q=q, qinv=qinv)
-    # untwist table carries psi^{-j} * n^{-1} (or just n^{-1} for cyclic).
-    c_ref[...] = _mont_mul(c, untwist_ref[...], q, qinv)
+def ntt_dit(x, rows, lanes, q, qinv):
+    """Unscaled cyclic NTT of one (R, C) row, bit-reversed in, natural out
+    (``stage_tables(..., dif=False)``): (u, v) -> (u + v w_s, u - v w_s)."""
+    dists = _distances(x.size)
+    for s in reversed(range(len(dists))):
+        axis, d, tw = _stage(rows, lanes, s, dists[s], x.shape)
+        p, up = _partner(x, d, axis)
+        v = _mont_mul(jnp.where(up, x, p), tw, q, qinv)
+        x = jnp.where(up, _sub_mod(p, v, q), _add_mod(x, v, q))
+    return x
 
 
-# ---------------------------------------------------------------------------
-# Host-side wrappers
-# ---------------------------------------------------------------------------
+@functools.lru_cache(maxsize=64)
+def stage_tables(params: NTTParams, base: int, dif: bool
+                 ) -> tuple[np.ndarray, np.ndarray]:
+    """Per-stage Montgomery twiddles, uint32, in the DIF stage order.
 
-def _master_table(params: NTTParams, base: int) -> jnp.ndarray:
-    """(1, n) uint32 Montgomery-form powers of ``base``."""
+    The stage of distance d multiplies element i by base^((i mod d) n/(2d))
+    — for i < d exactly the old strided slice ``W[::n/(2d)][:d]`` of the
+    master table W = base^k. ``dif=True`` puts Montgomery one at the lower
+    positions (bit d clear). Stages with d >= C vary over the whole plane:
+    ``rows`` is (max(1, #such stages), R, C). Below C they vary along lanes
+    only: ``lanes`` is (#such stages, 1, C). Cached as numpy.
+    """
+    n = params.n
+    r_, c_ = split(n)
     pw = params.powers(base)
-    return jnp.asarray(params.to_montgomery(pw).astype(np.uint32)[None, :])
+    i = np.arange(n)
+    one = params.to_montgomery(np.ones(1, np.uint64))[0]
+    rows, lanes = [], []
+    for d in _distances(n):
+        t = params.to_montgomery(pw[(i % d) * (n // (2 * d))])
+        if dif:
+            t = np.where(i & d, t, one)
+        t = t.astype(np.uint32)
+        if d >= c_:
+            rows.append(t.reshape(r_, c_))
+        else:
+            lanes.append(t[:c_].reshape(1, c_))
+    if not rows:
+        rows.append(np.zeros((r_, c_), np.uint32))      # never read
+    return np.stack(rows), np.stack(lanes)
 
 
 def untwist_table(params: NTTParams, negacyclic: bool) -> np.ndarray:
@@ -219,6 +211,96 @@ def untwist_table(params: NTTParams, negacyclic: bool) -> np.ndarray:
                 % np.uint64(params.q))
     return np.full(params.n, params.n_inv, np.uint64)
 
+
+@functools.lru_cache(maxsize=64)
+def polymul_tables(params: NTTParams, negacyclic: bool
+                   ) -> tuple[np.ndarray, ...]:
+    """Every table of the fused polymul, uint32: forward DIF and inverse
+    DIT stage stacks, and the (R, C) twist and untwist planes (Montgomery
+    form; the untwist folds in n^{-1})."""
+    r_, c_ = split(params.n)
+    twist = params.to_montgomery(
+        params.powers(params.psi) if negacyclic
+        else np.ones(params.n, np.uint64))            # cyclic: identity
+    untwist = params.to_montgomery(untwist_table(params, negacyclic))
+    return (*stage_tables(params, params.w, True),
+            *stage_tables(params, params.w_inv, False),
+            twist.astype(np.uint32).reshape(r_, c_),
+            untwist.astype(np.uint32).reshape(r_, c_))
+
+
+def _polymul_row(a, b, tabs, q, qinv, r2, negacyclic: bool):
+    """twist -> DIF x2 -> pointwise (one operand hops into Montgomery form
+    via r2) -> DIT -> untwist·n^{-1}, one (R, C) row."""
+    fr, fl, ir, il, twist, untwist = tabs
+    if negacyclic:                 # psi^j twist: x^n+1 products via cyclic NTT
+        a = _mont_mul(a, twist[...], q, qinv)
+        b = _mont_mul(b, twist[...], q, qinv)
+    fa = ntt_dif(a, fr, fl, q, qinv)
+    fb = ntt_dif(b, fr, fl, q, qinv)
+    p = _mont_mul(_mont_mul(fa, _u32(r2), q, qinv), fb, q, qinv)
+    return _mont_mul(ntt_dit(p, ir, il, q, qinv), untwist[...], q, qinv)
+
+
+# ---------------------------------------------------------------------------
+# Kernels
+# ---------------------------------------------------------------------------
+
+def _ntt_kernel(rows_ref, lanes_ref, x_ref, o_ref, *, q: int, qinv: int,
+                dif: bool, scale_mont: int | None):
+    stages = ntt_dif if dif else ntt_dit
+
+    def row(b):
+        y = stages(x_ref[b], rows_ref, lanes_ref, q, qinv)
+        if scale_mont is not None:     # inverse: fold in n^-1 (Montgomery)
+            y = _mont_mul(y, jnp.uint32(scale_mont), q, qinv)
+        o_ref[b] = y
+    for_each_row([x_ref], row)
+
+
+def _ntt_polymul_kernel(*refs, q: int, qinv: int, r2: int, negacyclic: bool):
+    """Fused modular polymul, one VMEM residency (paper §5 structure, exact
+    arithmetic)."""
+    *tabs, a_ref, b_ref, c_ref = refs
+
+    def row(b):
+        c_ref[b] = _polymul_row(a_ref[b], b_ref[b], tabs, q, qinv, r2,
+                                negacyclic)
+    for_each_row([a_ref], row)
+
+
+def _rns_ntt_polymul_kernel(scal_ref, *refs, negacyclic: bool,
+                            prefetch: bool):
+    """One grid cell = one (limb, batch-block) tile of the RNS polymul.
+
+    Identical dataflow to ``_ntt_polymul_kernel``; the limb's modulus
+    constants are *data* (scal_ref row: q, qinv, r2) instead of closure
+    constants, which is what lets k different-q transforms share a single
+    pallas launch on the (limb, batch) grid.
+
+    ``prefetch=True`` is the scalar-prefetch layout
+    (``pltpu.PrefetchScalarGridSpec``): ``scal_ref`` is the WHOLE (k, 4)
+    table resident in SMEM before the body runs — the per-limb constants
+    never occupy a VMEM block. ``prefetch=False`` is the scalar-Ref layout
+    (a (1, 4) VMEM block per grid cell). Both paths are pinned bit-exactly
+    equal in tests/test_rns_ntt.py.
+    """
+    *tabs, a_ref, b_ref, c_ref = refs
+    if prefetch:
+        row_id = pl.program_id(0)
+        q, qinv, r2 = (scal_ref[row_id, j] for j in range(3))
+    else:
+        q, qinv, r2 = (scal_ref[0, j] for j in range(3))
+
+    def row(b):
+        c_ref[b] = _polymul_row(a_ref[b], b_ref[b], tabs, q, qinv, r2,
+                                negacyclic)
+    for_each_row([a_ref], row)
+
+
+# ---------------------------------------------------------------------------
+# Host-side wrappers
+# ---------------------------------------------------------------------------
 
 def _as_residues(x, q: int):
     """Reduce integer coefficients into [0, q) uint32 — same contract as
@@ -239,46 +321,40 @@ def _as_residues(x, q: int):
 @functools.partial(jax.jit, static_argnames=("params", "inverse",
                                              "interpret", "block_b"))
 def ntt_batched(x: jax.Array, params: NTTParams, *, inverse: bool = False,
-                interpret: bool = True, block_b: int | None = None
+                interpret: bool | None = None, block_b: int | None = None
                 ) -> jax.Array:
     """Batched cyclic NTT of uint32 residues (B, n) mod ``params.q``.
 
     Bit-exact equal to ``core.ntt.ref.ntt``/``intt`` (tests/test_ntt.py).
-    Same grid/tiling contract as ``fft_planes``: grid=(B/B_blk,), each
-    program transforms its block entirely in VMEM.
+    Same grid/tiling contract as ``fft_planes``. The forward kernel leaves
+    bit-reversed order and the inverse kernel expects it, so one gather
+    (after the forward, before the inverse) keeps the API in natural order.
     """
     x = _as_residues(x, params.q)
     b, n = x.shape
     assert n == params.n, f"n={n} != params.n={params.n}"
-    blk = block_b or plan_batch_block(n)
-    pad = (-b) % blk
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-    bp = x.shape[0]
-    w = _master_table(params, params.w_inv if inverse else params.w)
-    scale = None
+    interpret = resolve_interpret(interpret)
+    r_, c_ = split(n)
+    rev = jnp.asarray(bit_reverse_indices(n))
     if inverse:
-        scale = params.n_inv * (1 << 32) % params.q   # Montgomery n^-1
-    kern = functools.partial(_ntt_kernel, n=n, q=params.q, qinv=params.qinv,
-                             scale_mont=scale)
-    y = pl.pallas_call(
-        kern,
-        grid=(bp // blk,),
-        in_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0)),    # twiddles (broadcast)
-            pl.BlockSpec((blk, n), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((blk, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bp, n), jnp.uint32),
-        interpret=interpret,
-    )(w, x)
-    return y[:b] if pad else y
+        x = x[:, rev]
+    blk = block_b or _fit_block(plan_batch_block(n), b)
+    (xp,) = pad_rows([x.reshape(b, r_, c_)], blk)
+    scale = params.n_inv * (1 << 32) % params.q if inverse else None
+    kern = functools.partial(_ntt_kernel, q=params.q, qinv=params.qinv,
+                             dif=not inverse, scale_mont=scale)
+    base = params.w_inv if inverse else params.w
+    (y,) = batched_call(kern, stage_tables(params, base, not inverse), [xp],
+                        [jax.ShapeDtypeStruct(xp.shape, jnp.uint32)],
+                        blk=blk, interpret=interpret)
+    y = y[:b].reshape(b, n)
+    return y if inverse else y[:, rev]
 
 
 @functools.partial(jax.jit, static_argnames=("params", "negacyclic",
                                              "interpret", "block_b"))
 def ntt_polymul(a: jax.Array, b: jax.Array, params: NTTParams, *,
-                negacyclic: bool = True, interpret: bool = True,
+                negacyclic: bool = True, interpret: bool | None = None,
                 block_b: int | None = None) -> jax.Array:
     """Exact polynomial product mod (x^n + 1, q) — or x^n - 1 with
     ``negacyclic=False`` — of residue batches (B, n), fully fused.
@@ -291,31 +367,17 @@ def ntt_polymul(a: jax.Array, b: jax.Array, params: NTTParams, *,
     assert a.shape == bb.shape
     bsz, n = a.shape
     assert n == params.n, f"n={n} != params.n={params.n}"
-    blk = block_b or max(1, plan_batch_block(n) // 2)  # 3 transforms live
-    pad = (-bsz) % blk
-    if pad:
-        a = jnp.pad(a, ((0, pad), (0, 0)))
-        bb = jnp.pad(bb, ((0, pad), (0, 0)))
-    bp = a.shape[0]
-    wf = _master_table(params, params.w)
-    wi = _master_table(params, params.w_inv)
-    twist = _master_table(params, params.psi if negacyclic else 1)
-    untwist = jnp.asarray(params.to_montgomery(
-        untwist_table(params, negacyclic)).astype(np.uint32)[None, :])
-    kern = functools.partial(_ntt_polymul_kernel, n=n, q=params.q,
+    interpret = resolve_interpret(interpret)
+    r_, c_ = split(n)
+    blk = block_b or _fit_block(max(1, plan_batch_block(n) // 2), bsz)
+    ops = pad_rows([v.reshape(bsz, r_, c_) for v in (a, bb)], blk)
+    kern = functools.partial(_ntt_polymul_kernel, q=params.q,
                              qinv=params.qinv, r2=params.r2,
                              negacyclic=negacyclic)
-    bspec = pl.BlockSpec((blk, n), lambda i: (i, 0))
-    wspec = pl.BlockSpec((1, n), lambda i: (0, 0))
-    c = pl.pallas_call(
-        kern,
-        grid=(bp // blk,),
-        in_specs=[wspec, wspec, wspec, wspec, bspec, bspec],
-        out_specs=bspec,
-        out_shape=jax.ShapeDtypeStruct((bp, n), jnp.uint32),
-        interpret=interpret,
-    )(wf, wi, twist, untwist, a, bb)
-    return c[:bsz] if pad else c
+    (c,) = batched_call(kern, polymul_tables(params, negacyclic), ops,
+                        [jax.ShapeDtypeStruct(ops[0].shape, jnp.uint32)],
+                        blk=blk, interpret=interpret)
+    return c[:bsz].reshape(bsz, n)
 
 
 # ---------------------------------------------------------------------------
@@ -324,33 +386,21 @@ def ntt_polymul(a: jax.Array, b: jax.Array, params: NTTParams, *,
 
 @functools.lru_cache(maxsize=32)
 def _rns_tables(rns, negacyclic: bool):
-    """Per-limb constant stacks for the RNS kernel, all uint32:
-    scalars (k, 4) = [q, qinv, r2, 0]; wf/wi/twist/untwist (k, n) in
-    Montgomery form. Cached on the hashable RNSParams as NUMPY arrays
+    """Per-limb stacks for the RNS kernel, all uint32: scalars (k, 1, 4) =
+    [q, qinv, r2, 0] and each ``polymul_tables`` array with a leading limb
+    axis, so every block is one limb's whole table (its last two dims are
+    the table's own). Cached on the hashable RNSParams as NUMPY arrays
     (caching jnp values across jit traces would leak tracers)."""
-    k, n = rns.k, rns.n
-    scal = np.zeros((k, 4), np.uint32)
-    wf = np.empty((k, n), np.uint32)
-    wi = np.empty((k, n), np.uint32)
-    twist = np.empty((k, n), np.uint32)
-    untwist = np.empty((k, n), np.uint32)
-    for i, p in enumerate(rns.limbs):
-        scal[i] = (p.q, p.qinv, p.r2, 0)
-        wf[i] = p.to_montgomery(p.powers(p.w)).astype(np.uint32)
-        wi[i] = p.to_montgomery(p.powers(p.w_inv)).astype(np.uint32)
-        twist[i] = p.to_montgomery(
-            p.powers(p.psi) if negacyclic
-            else np.ones(n, np.uint64)).astype(np.uint32)    # cyclic: unused
-        untwist[i] = p.to_montgomery(
-            untwist_table(p, negacyclic)).astype(np.uint32)
-    return scal, wf, wi, twist, untwist
+    scal = np.array([[(p.q, p.qinv, p.r2, 0)] for p in rns.limbs], np.uint32)
+    per_limb = [polymul_tables(p, negacyclic) for p in rns.limbs]
+    return (scal, *(np.stack(t) for t in zip(*per_limb)))
 
 
 @functools.partial(jax.jit, static_argnames=("rns", "negacyclic",
                                              "interpret", "block_b",
                                              "scalar_prefetch"))
 def rns_ntt_polymul(ar: jax.Array, br: jax.Array, rns, *,
-                    negacyclic: bool = True, interpret: bool = True,
+                    negacyclic: bool = True, interpret: bool | None = None,
                     block_b: int | None = None,
                     scalar_prefetch: bool | None = None) -> jax.Array:
     """Limb-batched exact polymul: residue stacks (k, B, n) -> (k, B, n).
@@ -358,9 +408,8 @@ def rns_ntt_polymul(ar: jax.Array, br: jax.Array, rns, *,
     ``rns`` is a ``core.ntt.rns.RNSParams`` (kept opaque here so the kernel
     layer has no core->kernels cycle); inputs are per-limb REDUCED residues
     (< q_i each, as ``rns.to_rns`` produces). All k limbs and all batch
-    blocks run through ONE pallas launch: the limb dimension rides the same
-    ``plan_batch_block`` grid the batched single-modulus kernels use, so an
-    8-limb 100-bit-Q product costs one kernel dispatch, not eight.
+    blocks run through ONE pallas launch on the (limb, batch-block) grid, so
+    an 8-limb 100-bit-Q product costs one kernel dispatch, not eight.
     CRT reconstruction (``rns.crt_to_modulus``) lives with the caller.
 
     ``scalar_prefetch`` hoists the per-limb q/qinv/r2 table to TPU scalar
@@ -376,43 +425,36 @@ def rns_ntt_polymul(ar: jax.Array, br: jax.Array, rns, *,
         "RNS kernel wants pre-reduced uint32 residue stacks (rns.to_rns)"
     k, bsz, n = ar.shape
     assert k == rns.k and n == rns.n, (ar.shape, rns.k, rns.n)
-    blk = block_b or max(1, plan_batch_block(n) // 2)  # 3 transforms live
+    interpret = resolve_interpret(interpret)
+    r_, c_ = split(n)
+    blk = block_b or _fit_block(max(1, plan_batch_block(n) // 2), bsz)
     pad = (-bsz) % blk
-    if pad:
-        ar = jnp.pad(ar, ((0, 0), (0, pad), (0, 0)))
-        br = jnp.pad(br, ((0, 0), (0, pad), (0, 0)))
-    bp = ar.shape[1]
-    scal, wf, wi, twist, untwist = (jnp.asarray(t) for t in
-                                    _rns_tables(rns, negacyclic))
+    ar, br = (jnp.pad(v.reshape(k, bsz, r_, c_),
+                      ((0, 0), (0, pad), (0, 0), (0, 0))) for v in (ar, br))
+    bp = bsz + pad
+    scal, *tabs = (jnp.asarray(t) for t in _rns_tables(rns, negacyclic))
     prefetch = (not interpret) if scalar_prefetch is None else scalar_prefetch
-    kern = functools.partial(_rns_ntt_polymul_kernel, n=n,
-                             negacyclic=negacyclic, prefetch=prefetch)
-    out_shape = jax.ShapeDtypeStruct((k, bp, n), jnp.uint32)
+    kern = functools.partial(_rns_ntt_polymul_kernel, negacyclic=negacyclic,
+                             prefetch=prefetch)
+    out_shape = jax.ShapeDtypeStruct((k, bp, r_, c_), jnp.uint32)
+    # With prefetch the index maps get the scalar Ref as a trailing argument.
+    def limb_spec(shape):        # one limb's whole table, limb axis squeezed
+        nd = len(shape) - 1
+        return pl.BlockSpec((None,) + tuple(shape[1:]),
+                            lambda l, i, *_: (l,) + (0,) * nd)
+
+    bspec = pl.BlockSpec((None, blk, r_, c_), lambda l, i, *_: (l, i, 0, 0))
+    in_specs = [limb_spec(t.shape) for t in tabs] + [bspec, bspec]
     if prefetch:
-        from jax.experimental.pallas import tpu as pltpu
-        # index maps gain the prefetched scal Ref as a trailing argument.
-        wspec = pl.BlockSpec((1, n), lambda l, i, s: (l, 0))
-        bspec = pl.BlockSpec((1, blk, n), lambda l, i, s: (l, i, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(k, bp // blk),
-            in_specs=[wspec, wspec, wspec, wspec, bspec, bspec],
-            out_specs=bspec,
-        )
-        c = pl.pallas_call(
-            kern, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret,
-        )(scal, wf, wi, twist, untwist, ar, br)
+            num_scalar_prefetch=1, grid=(k, bp // blk),
+            in_specs=in_specs, out_specs=bspec)
+        c = pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
+                           interpret=interpret)(scal[:, 0], *tabs, ar, br)
     else:
-        sspec = pl.BlockSpec((1, 4), lambda l, i: (l, 0))
-        wspec = pl.BlockSpec((1, n), lambda l, i: (l, 0))
-        bspec = pl.BlockSpec((1, blk, n), lambda l, i: (l, i, 0))
         c = pl.pallas_call(
-            kern,
-            grid=(k, bp // blk),
-            in_specs=[sspec, wspec, wspec, wspec, wspec, bspec, bspec],
-            out_specs=bspec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(scal, wf, wi, twist, untwist, ar, br)
-    return c[:, :bsz] if pad else c
+            kern, grid=(k, bp // blk),
+            in_specs=[limb_spec(scal.shape)] + in_specs, out_specs=bspec,
+            out_shape=out_shape, interpret=interpret,
+        )(scal, *tabs, ar, br)
+    return c[:, :bsz].reshape(k, bsz, n)

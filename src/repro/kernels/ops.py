@@ -36,12 +36,8 @@ def _as2d(x):
     return x.reshape(-1, n), x.shape[:-1]
 
 
-def _pallas_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def fft(x: jax.Array, *, inverse: bool = False, backend: str | None = None,
-        radix: int = 2) -> jax.Array:
+def fft(x: jax.Array, *, inverse: bool = False,
+        backend: str | None = None) -> jax.Array:
     """Batched FFT of a complex array (..., n)."""
     backend = backend or _auto_backend()
     if backend == "xla":
@@ -49,8 +45,7 @@ def fft(x: jax.Array, *, inverse: bool = False, backend: str | None = None,
     x2, lead = _as2d(x)
     xr = jnp.real(x2).astype(jnp.float32)
     xi = jnp.imag(x2).astype(jnp.float32)
-    yr, yi = _kfft.fft_planes(xr, xi, inverse=inverse, radix=radix,
-                              interpret=_pallas_interpret())
+    yr, yi = _kfft.fft_planes(xr, xi, inverse=inverse)
     return (yr + 1j * yi).astype(jnp.complex64).reshape(*lead, x.shape[-1])
 
 
@@ -96,14 +91,14 @@ _packed_to_halfspec = packed_to_halfspec
 _halfspec_to_packed = halfspec_to_packed
 
 
-def rfft(x: jax.Array, *, backend: str | None = None, radix: int = 2,
+def rfft(x: jax.Array, *, backend: str | None = None,
          packed: bool = False):
     """FFT of a real array (..., n): half-spectrum only (Hermitian symmetry).
 
     Returns complex (..., n/2+1) matching ``np.fft.rfft``, or the packed
     planes ``(re, im)`` of shape (..., n/2) with ``packed=True``. The Pallas
-    route runs the two-for-one kernel: two real rows per complex transform,
-    half the butterflies and half the HBM traffic of ``fft`` on real input.
+    route runs the half-spectrum kernel: half the matrix work and half the
+    HBM traffic of ``fft`` on real input.
     """
     if jnp.iscomplexobj(x):
         raise TypeError(f"rfft needs real input, got {x.dtype}")
@@ -114,20 +109,19 @@ def rfft(x: jax.Array, *, backend: str | None = None, radix: int = 2,
         half = full[..., :n // 2 + 1]
         return _halfspec_to_packed(half) if packed else half
     x2, lead = _as2d(x)
-    yr, yi = _kfft.rfft_planes(x2.astype(jnp.float32), radix=radix,
-                               interpret=_pallas_interpret())
+    yr, yi = _kfft.rfft_planes(x2.astype(jnp.float32))
     yr = yr.reshape(*lead, n // 2)
     yi = yi.reshape(*lead, n // 2)
     return (yr, yi) if packed else _packed_to_halfspec(yr, yi)
 
 
-def irfft(x, *, backend: str | None = None, radix: int = 2,
+def irfft(x, *, backend: str | None = None,
           packed: bool = False) -> jax.Array:
     """Inverse of ``rfft``: half-spectrum -> real (..., n).
 
     ``x`` is complex (..., n/2+1) (numpy layout), or the packed plane pair
-    with ``packed=True``. The Pallas route re-mirrors two half-spectra per
-    inverse complex transform inside the kernel.
+    with ``packed=True``. The Pallas route folds the mirror half of the
+    spectrum back as 2*Re inside the kernel.
     """
     if packed:
         pr, pi = x
@@ -145,31 +139,28 @@ def irfft(x, *, backend: str | None = None, radix: int = 2,
             jnp.float32)
     p2, lead = _as2d(pr)
     q2, _ = _as2d(pi)
-    y = _kfft.irfft_planes(p2, q2, radix=radix,
-                           interpret=_pallas_interpret())
+    y = _kfft.irfft_planes(p2, q2)
     return y.reshape(*lead, n)
 
 
 def polymul_real(a: jax.Array, b: jax.Array, *, mode: str = "linear",
-                 backend: str | None = None, radix: int = 2,
+                 backend: str | None = None,
                  block_b: int | None = None) -> jax.Array:
     """Polynomial product of REAL coefficient arrays — the explicit fast
     path (``polymul`` also auto-detects real input, but serving code routes
     here so the selection is visible/testable). Raises on complex input,
     then delegates to ``polymul``'s real branch (one dispatch to keep in
-    sync): the fused two-for-one kernel — one forward FFT per product, one
-    inverse per pair of products (1.5 transform-equivalents vs the complex
-    path's 3).
+    sync): the fused half-spectrum kernel — half the matrix work of the
+    complex path per product.
     """
     if jnp.iscomplexobj(a) or jnp.iscomplexobj(b):
         raise TypeError(f"polymul_real needs real input, got "
                         f"{a.dtype}/{b.dtype}")
-    return polymul(a, b, mode=mode, backend=backend, radix=radix,
-                   block_b=block_b)
+    return polymul(a, b, mode=mode, backend=backend, block_b=block_b)
 
 
 def polymul(a: jax.Array, b: jax.Array, *, mode: str = "linear",
-            backend: str | None = None, radix: int = 2,
+            backend: str | None = None,
             block_b: int | None = None) -> jax.Array:
     """Polynomial multiplication via the convolution theorem (paper Eq. (9)).
 
@@ -177,8 +168,8 @@ def polymul(a: jax.Array, b: jax.Array, *, mode: str = "linear",
     mode='linear'  : full product — inputs zero-padded to 2n (paper fn. 4);
                      returns length 2n (last coefficient structurally 0).
 
-    Real inputs dispatch to the Eq. (10) real-packed path (one complex FFT
-    for both operands); complex inputs use the three-transform path.
+    Real inputs dispatch to the Eq. (10) half-spectrum path; complex inputs
+    use the three-transform path.
     """
     assert a.shape == b.shape
     n = a.shape[-1]
@@ -202,15 +193,31 @@ def polymul(a: jax.Array, b: jax.Array, *, mode: str = "linear",
     b2, _ = _as2d(b)
     if real_in:
         c = _kpoly.polymul_real_planes(a2.astype(jnp.float32),
-                                       b2.astype(jnp.float32), radix=radix,
-                                       interpret=_pallas_interpret(),
+                                       b2.astype(jnp.float32),
                                        block_b=block_b)
         return c.reshape(*lead, n)
     cr, ci = _kpoly.polymul_complex_planes(
         jnp.real(a2).astype(jnp.float32), jnp.imag(a2).astype(jnp.float32),
         jnp.real(b2).astype(jnp.float32), jnp.imag(b2).astype(jnp.float32),
-        radix=radix, interpret=_pallas_interpret(), block_b=block_b)
+        block_b=block_b)
     return (cr + 1j * ci).astype(jnp.complex64).reshape(*lead, n)
+
+
+def hermitian_split(zr, zi):
+    """Split Z = FFT(a + i b) of two real rows into their spectra (Eq. (10)).
+
+    A_k = (Z_k + conj(Z_{n-k})) / 2,  B_k = -i (Z_k - conj(Z_{n-k})) / 2,
+    on split planes; Z_{n-k} is a flip then a rotate by one so k=0 stays.
+    The results are EXACTLY Hermitian in fp32 (each component of A_{n-k} is
+    the same float expression as ±A_k's).
+    """
+    zrr = jnp.roll(jnp.flip(zr, axis=-1), 1, axis=-1)
+    zri = jnp.roll(jnp.flip(zi, axis=-1), 1, axis=-1)
+    ar = 0.5 * (zrr + zr)
+    ai = 0.5 * (-zri + zi)
+    br = 0.5 * (zri + zi)
+    bi = 0.5 * (zrr - zr)
+    return ar, ai, br, bi
 
 
 def realpack_fft(x: jax.Array, y: jax.Array, *, backend: str | None = None
@@ -218,10 +225,8 @@ def realpack_fft(x: jax.Array, y: jax.Array, *, backend: str | None = None
     """FFTs of two real sequences via one complex FFT (paper Eq. (10))."""
     z = x.astype(jnp.complex64) + 1j * y.astype(jnp.complex64)
     zf = fft(z, backend=backend)
-    zrev = jnp.roll(jnp.flip(zf, axis=-1), 1, axis=-1)
-    xk = 0.5 * (jnp.conj(zrev) + zf)
-    yk = 0.5j * (jnp.conj(zrev) - zf)
-    return xk, yk
+    ar, ai, br, bi = hermitian_split(jnp.real(zf), jnp.imag(zf))
+    return ar + 1j * ai, br + 1j * bi
 
 
 def _next_pow2(n: int) -> int:

@@ -10,13 +10,14 @@ Paper correspondences:
   makes the same observation about the GPU's element-wise multiply being
   memory-bound (§6, last paragraph) — fusion is the TPU-native counterpart.
 * Input-permutation cancellation: the paper skips the FFT/IFFT bit-reversal
-  permutations because they cancel across DFT.IDFT. Stockham autosort has no
-  explicit permutation to begin with; the property holds structurally.
-* Eq. (10) real packing: two real-coefficient transforms from one complex
-  FFT via z = a + i b, unpacked with conjugate symmetry. The paper's PIM
-  tricks map as: conjugate = sign flip on the imag plane; multiply by i =
-  plane swap + sign flip; divide by 2 = scalar multiply (PIM decrements the
-  exponent; the VPU just multiplies); Z_{n-k} = lane reversal + rotate-by-1.
+  permutations because they cancel across DFT.IDFT. The four-step kernels
+  (kernels/fft.py) have no permutation to begin with: the forward transform
+  leaves the spectrum as a (C, R) matrix and the inverse consumes exactly
+  that layout.
+* Real coefficients (paper Eq. (10)): the spectrum of a real row is
+  Hermitian, so only its first half is computed and multiplied, and the
+  inverse folds the mirror half back as 2*Re — half the matrix work of the
+  complex path per product.
 """
 from __future__ import annotations
 
@@ -24,133 +25,93 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.fft import (_fit_block, hermitian_split, plan_batch_block,
-                               stockham_stages, twiddle_table)
-
-
-def _polymul_complex_kernel(wr_ref, wi_ref, ar_ref, ai_ref, br_ref, bi_ref,
-                            cr_ref, ci_ref, *, n: int, radix: int):
-    wr = wr_ref[...]
-    wi = wi_ref[...]
-    ar = ar_ref[...].astype(jnp.float32)
-    ai = ai_ref[...].astype(jnp.float32)
-    br = br_ref[...].astype(jnp.float32)
-    bi = bi_ref[...].astype(jnp.float32)
-    far, fai = stockham_stages(ar, ai, wr, wi, n=n, inverse=False, radix=radix)
-    fbr, fbi = stockham_stages(br, bi, wr, wi, n=n, inverse=False, radix=radix)
-    pr = far * fbr - fai * fbi
-    pi = far * fbi + fai * fbr
-    # Inverse transform with the conjugated table: conj(FFT(conj(.)))/n.
-    cr, ci = stockham_stages(pr, -pi, wr, wi, n=n, inverse=False, radix=radix)
-    inv = 1.0 / n
-    cr_ref[...] = (cr * inv).astype(cr_ref.dtype)
-    ci_ref[...] = (-ci * inv).astype(ci_ref.dtype)
+from repro.kernels.fft import (_cmul, _fit_block, _origin, alternating_row,
+                               batched_call, for_each_row, four_step,
+                               four_step_back, irfft_row, pad_rows,
+                               plan_batch_block, resolve_interpret,
+                               rfft_row, row_tables, spectrum_tables, split)
 
 
-def _polymul_real_kernel(wr_ref, wi_ref, wir_ref, wii_ref, a_ref, b_ref,
-                         c_ref, *, n: int, radix: int):
-    """Real-coefficient polymul: ONE forward FFT per product (Eq. (10)
-    packing z = a + i b) and ONE inverse FFT per PAIR of products.
+def _polymul_complex_kernel(*refs, n: int):
+    *tabs, ar_ref, ai_ref, br_ref, bi_ref, cr_ref, ci_ref = refs
+    fwd, back = tabs[:6], tabs[6:]
 
-    The product spectrum P = A·B of two Hermitian spectra is exactly
-    Hermitian (``hermitian_split`` is component-exact under conjugation), so
-    IFFT(P) is real and two products can share an inverse transform:
-    Q = P_{2j} + i P_{2j+1}, c_{2j} = Re IFFT(Q), c_{2j+1} = Im IFFT(Q).
-    Butterfly work per product: 1 forward + 1/2 inverse = 1.5
-    complex-transform-equivalents vs the complex kernel's 3.
+    def row(b):
+        tf = [r[...] for r in fwd]
+        far, fai = four_step(ar_ref[b].astype(jnp.float32),
+                             ai_ref[b].astype(jnp.float32), tf)
+        fbr, fbi = four_step(br_ref[b].astype(jnp.float32),
+                             bi_ref[b].astype(jnp.float32), tf)
+        pr, pi = _cmul(far, fai, fbr, fbi)
+        cr, ci = four_step_back(pr, pi, [r[...] for r in back])
+        inv = 1.0 / n
+        cr_ref[b] = (cr * inv).astype(cr_ref.dtype)
+        ci_ref[b] = (ci * inv).astype(ci_ref.dtype)
+    for_each_row([ar_ref], row)
+
+
+def _polymul_real_kernel(*refs, n: int):
+    """Real-coefficient polymul: two half-spectrum forward transforms, the
+    product of the packed half spectra, one real inverse.
+
+    In the packed layout P[0] holds two real bins (DC and Nyquist), so its
+    product is component-wise; every other bin is a complex product.
     """
-    blk = a_ref.shape[0]
-    wr = wr_ref[...]
-    wi = wi_ref[...]
-    a = a_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
-    # z = a + i b ; Z = FFT(z); Hermitian split -> A = FFT(a), B = FFT(b)
-    zr, zi = stockham_stages(a, b, wr, wi, n=n, inverse=False, radix=radix)
-    far, fai, fbr, fbi = hermitian_split(zr, zi)
-    pr = far * fbr - fai * fbi
-    pi = far * fbi + fai * fbr
-    # Pair rows for the inverse: Q = P_even + i P_odd.
-    pr = pr.reshape(blk // 2, 2, n)
-    pi = pi.reshape(blk // 2, 2, n)
-    qr = pr[:, 0] - pi[:, 1]
-    qi = pi[:, 0] + pr[:, 1]
-    cr, ci = stockham_stages(qr, qi, wir_ref[...], wii_ref[...], n=n,
-                             inverse=True, radix=radix)
-    c_ref[...] = jnp.stack([cr, ci], axis=1).reshape(blk, n).astype(
-        c_ref.dtype)
+    *tabs, alt_ref, a_ref, b_ref, c_ref = refs
+    fwd, back = tabs[:6], tabs[6:]
+
+    def row(b):
+        tf = [r[...] for r in fwd]
+        alt = alt_ref[...]
+        far, fai = rfft_row(a_ref[b].astype(jnp.float32), tf, alt)
+        fbr, fbi = rfft_row(b_ref[b].astype(jnp.float32), tf, alt)
+        pr, pi = _cmul(far, fai, fbr, fbi)
+        at0 = _origin(pr.shape)
+        pr = jnp.where(at0, far * fbr, pr)
+        pi = jnp.where(at0, fai * fbi, pi)
+        c = irfft_row(pr, pi, [r[...] for r in back], alt, n)
+        c_ref[b] = c.astype(c_ref.dtype)
+    for_each_row([a_ref], row)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("radix", "interpret", "block_b"))
-def polymul_complex_planes(ar, ai, br, bi, *, radix: int = 2,
-                           interpret: bool = True, block_b: int | None = None):
+@functools.partial(jax.jit, static_argnames=("interpret", "block_b"))
+def polymul_complex_planes(ar, ai, br, bi, *, interpret: bool | None = None,
+                           block_b: int | None = None):
     """Circular (mod x^n - 1) product of complex coefficient vectors (B, n)."""
     assert ar.shape == ai.shape == br.shape == bi.shape and ar.ndim == 2
     b, n = ar.shape
-    # 3 transforms live; clamp to the actual batch (no padding past b).
+    interpret = resolve_interpret(interpret)
+    r_, c_ = split(n)
+    # 3 transforms and 6 planes per row: half the fft block.
     blk = block_b or _fit_block(max(1, plan_batch_block(n) // 2), b)
-    pad = (-b) % blk
-    if pad:
-        ar, ai, br, bi = (jnp.pad(v, ((0, pad), (0, 0))) for v in (ar, ai, br, bi))
-    bp = ar.shape[0]
-    wr_np, wi_np = twiddle_table(n)
-    kern = functools.partial(_polymul_complex_kernel, n=n, radix=radix)
-    bspec = pl.BlockSpec((blk, n), lambda i: (i, 0))
-    wspec = pl.BlockSpec((1, n), lambda i: (0, 0))
-    cr, ci = pl.pallas_call(
-        kern,
-        grid=(bp // blk,),
-        in_specs=[wspec, wspec, bspec, bspec, bspec, bspec],
-        out_specs=[bspec, bspec],
-        out_shape=[jax.ShapeDtypeStruct((bp, n), ar.dtype),
-                   jax.ShapeDtypeStruct((bp, n), ar.dtype)],
-        interpret=interpret,
-    )(jnp.asarray(wr_np), jnp.asarray(wi_np), ar, ai, br, bi)
-    if pad:
-        cr, ci = cr[:b], ci[:b]
-    return cr, ci
+    ops = pad_rows([v.reshape(b, r_, c_) for v in (ar, ai, br, bi)], blk)
+    out = jax.ShapeDtypeStruct(ops[0].shape, ar.dtype)
+    cr, ci = batched_call(
+        functools.partial(_polymul_complex_kernel, n=n),
+        [*row_tables(n), *spectrum_tables(n)], ops, [out, out], blk=blk,
+        interpret=interpret)
+    return cr[:b].reshape(b, n), ci[:b].reshape(b, n)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("radix", "interpret", "block_b"))
-def polymul_real_planes(a, b, *, radix: int = 2, interpret: bool = True,
+@functools.partial(jax.jit, static_argnames=("interpret", "block_b"))
+def polymul_real_planes(a, b, *, interpret: bool | None = None,
                         block_b: int | None = None):
-    """Circular product of REAL coefficient vectors (B, n) via Eq. (10).
-
-    Two forward transforms fold into one complex FFT per product, and two
-    products share each inverse transform (Hermitian pairing) — 1.5
-    FFT-equivalents per product instead of the complex path's 3 (the
-    paper's §5 optimization plus the batch-paired inverse, which is why the
-    real-polymul speedups exceed the FFT speedups). The halved working set
-    also buys the doubled real-mode batch block (twice the rows per VMEM
-    residency of ``polymul_complex_planes``).
-    """
+    """Circular product of REAL coefficient vectors (B, n) via the half
+    spectrum (paper Eq. (10) / §5): 0.5x the matrix work of
+    ``polymul_complex_planes`` per product, with the doubled real-mode batch
+    block the halved working set buys."""
     assert a.shape == b.shape and a.ndim == 2
     bsz, n = a.shape
-    blk = block_b or _fit_block(max(2, plan_batch_block(n, real=True) // 2),
-                                bsz, even=True)
-    assert blk % 2 == 0, f"paired inverse needs an even block, got {blk}"
-    pad = (-bsz) % blk
-    if pad:
-        a = jnp.pad(a, ((0, pad), (0, 0)))
-        b = jnp.pad(b, ((0, pad), (0, 0)))
-    bp = a.shape[0]
-    wr_np, wi_np = twiddle_table(n)
-    wir_np, wii_np = twiddle_table(n, inverse=True)
-    kern = functools.partial(_polymul_real_kernel, n=n, radix=radix)
-    bspec = pl.BlockSpec((blk, n), lambda i: (i, 0))
-    wspec = pl.BlockSpec((1, n), lambda i: (0, 0))
-    c = pl.pallas_call(
-        kern,
-        grid=(bp // blk,),
-        in_specs=[wspec, wspec, wspec, wspec, bspec, bspec],
-        out_specs=bspec,
-        out_shape=jax.ShapeDtypeStruct((bp, n), a.dtype),
-        interpret=interpret,
-    )(jnp.asarray(wr_np), jnp.asarray(wi_np), jnp.asarray(wir_np),
-      jnp.asarray(wii_np), a, b)
-    if pad:
-        c = c[:bsz]
-    return c
+    assert n >= 2, f"n={n} must be a power of two >= 2"
+    interpret = resolve_interpret(interpret)
+    r_, c_ = split(n)
+    blk = block_b or _fit_block(max(1, plan_batch_block(n, real=True) // 2),
+                                bsz)
+    ops = pad_rows([v.reshape(bsz, r_, c_) for v in (a, b)], blk)
+    out = jax.ShapeDtypeStruct(ops[0].shape, a.dtype)
+    (c,) = batched_call(
+        functools.partial(_polymul_real_kernel, n=n),
+        [*row_tables(n, half=True), *spectrum_tables(n, half=True),
+         alternating_row(n)], ops, [out], blk=blk, interpret=interpret)
+    return c[:bsz].reshape(bsz, n)

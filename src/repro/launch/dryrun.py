@@ -64,12 +64,8 @@ def collective_bytes(hlo_text: str) -> dict:
 
 
 def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() as a flat dict (older jax returns a
-    one-entry list of per-program dicts)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """compiled.cost_analysis() as a dict."""
+    return compiled.cost_analysis() or {}
 
 
 def _lower_cell(cfg, shape, mesh):

@@ -134,8 +134,11 @@ class BoundOp:
     def random_payload(self, rng: np.random.Generator):
         return self.spec.random_payload(self, rng)
 
-    def verify(self, payload, result: np.ndarray) -> None:
-        self.spec.verify(self, payload, result)
+    def verify(self, payload, result: np.ndarray) -> float:
+        """Check one served result against the op's numpy or big-int
+        oracle (raises AssertionError on a miss); returns the relative
+        error, 0.0 for the exact routes."""
+        return self.spec.verify(self, payload, result)
 
     def check_payload(self, payload) -> None:
         """Admission guard: reject non-finite float/complex operands with
@@ -175,7 +178,7 @@ class OpSpec:
     _bind: Callable[["OpSpec", int, OpContext, int], BoundOp]
     warmup_payload: Callable[[BoundOp, int], tuple]
     random_payload: Callable[[BoundOp, np.random.Generator], Any]
-    verify: Callable[[BoundOp, Any, np.ndarray], None]
+    verify: Callable[[BoundOp, Any, np.ndarray], float]
     #: batch-level ABFT check (ft/abft.py): ``integrity(bound, payloads,
     #: rows) -> IntegrityVerdict`` validating a whole result batch
     #: against its request payloads in O(n) per row — the gate the
@@ -272,11 +275,12 @@ def _rel_err(got: np.ndarray, want: np.ndarray) -> float:
 
 
 def _float_verify(want_of: Callable[[np.ndarray], np.ndarray], tol: float,
-                  bound: BoundOp, payload, result: np.ndarray) -> None:
+                  bound: BoundOp, payload, result: np.ndarray) -> float:
     want = want_of(payload) if bound.spec.arity == 1 else want_of(*payload)
     err = _rel_err(result, want)
     assert err < tol, (f"{bound.spec.name} route {bound.route} diverged "
                       f"from the numpy oracle: rel err {err:.2e} >= {tol}")
+    return err
 
 
 def _zeros(bound: BoundOp, batch: int) -> tuple:
@@ -391,7 +395,7 @@ register_op(
 
 
 # ---------------------------------------------------------------------------
-# rfft — real-Hermitian half-spectrum endpoint (two-for-one packed kernel)
+# rfft — real-Hermitian half-spectrum endpoint (half-spectrum kernel)
 # ---------------------------------------------------------------------------
 
 def _bind_rfft(spec: OpSpec, n: int, ctx: OpContext, batch: int) -> BoundOp:
@@ -420,7 +424,7 @@ def _bind_rfft(spec: OpSpec, n: int, ctx: OpContext, batch: int) -> BoundOp:
 
 register_op(
     name="rfft", arity=1,
-    summary="real-input half-spectrum FFT (two-for-one Hermitian packing)",
+    summary="real-input half-spectrum FFT (Hermitian symmetry)",
     uses_modulus_bits=False, uses_model_shards=False,
     _validate=_no_dist_route, _bind=_bind_rfft,
     warmup_payload=_zeros,
@@ -465,7 +469,7 @@ register_op(
 
 
 # ---------------------------------------------------------------------------
-# polymul-real — paired-inverse real product; distributed four-step route
+# polymul-real — half-spectrum real product; distributed four-step route
 # with model_shards > 1 (odd batches padded internally, docs/fourier.md)
 # ---------------------------------------------------------------------------
 
@@ -529,7 +533,7 @@ def _bind_polymul_real(spec: OpSpec, n: int, ctx: OpContext,
 
 register_op(
     name="polymul-real", arity=2,
-    summary="real circular product via the paired-inverse Hermitian fast "
+    summary="real circular product via the half-spectrum Hermitian fast "
             "path; --model-shards > 1 runs the distributed four-step tier",
     uses_modulus_bits=False, uses_model_shards=True,
     _validate=_validate_polymul_real, _bind=_bind_polymul_real,
@@ -641,7 +645,7 @@ def _random_mod_payload(bound: BoundOp, rng: np.random.Generator):
             rng.integers(0, q, bound.n).astype(np.uint32))
 
 
-def _verify_mod(bound: BoundOp, payload, result: np.ndarray) -> None:
+def _verify_mod(bound: BoundOp, payload, result: np.ndarray) -> float:
     a, b = payload
     if bound.rns is not None:
         from repro.core.ntt import rns_polymul_reference
@@ -653,6 +657,7 @@ def _verify_mod(bound: BoundOp, payload, result: np.ndarray) -> None:
                                   bound.ntt_params)
     assert (np.asarray(result) == want).all(), \
         f"{bound.route} is not bit-exact against the reference NTT"
+    return 0.0
 
 
 register_op(

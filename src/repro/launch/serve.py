@@ -53,6 +53,7 @@ import numpy as np
 
 from repro.configs.registry import get_config
 from repro.launch import ops as op_registry
+from repro.launch.cache import setup_compile_cache
 from repro.launch.engine import ServeEngine
 from repro.models import lm
 
@@ -509,6 +510,7 @@ def main(argv=None):
         ap.error("--inject-faults/--inject-stuck without --verify would "
                  "deliver corrupted results: chaos injection requires "
                  "the ABFT gate")
+    setup_compile_cache()
     try:
         if args.service == "fft":
             return run_fft_service(args)

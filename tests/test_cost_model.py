@@ -31,6 +31,7 @@ from repro.core.fft.planner import plan
 from repro.core.ntt import NTTParams
 from repro.core.pim import (FOURIERPIM_8, FP32, INT32, aritpim, fft_pim,
                             ntt_pim, polymul_pim)
+from repro.dist import compat
 
 CFG = FOURIERPIM_8
 
@@ -121,7 +122,7 @@ def test_xla_collective_bytes_match_live_ledger(workload, real):
     from repro.core.ntt import distributed as dntt
     from repro.dist import collectives
     n, batch, D = 1024, 4, 4
-    mesh = jax.sharding.AbstractMesh((("model", D),))
+    mesh = compat.abstract_mesh((D,), ("model",))
     if workload == "polymul-mod":
         build = dntt.make_sharded_ntt_polymul(
             mesh, NTTParams.make(n), axis_name="model", batch_axes=())

@@ -28,26 +28,26 @@ def _join(yr, yi):
 
 
 # ---------------------------------------------------------------------------
-# Shape / dtype / radix sweep vs oracle
+# Shape / dtype / batch-block sweep vs oracle
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("n", [8, 32, 128, 1024])
-@pytest.mark.parametrize("radix", [2, 4])
+@pytest.mark.parametrize("block_b", [None, 2])
 @pytest.mark.parametrize("batch", [1, 3])
-def test_fft_kernel_matches_numpy(rng, n, radix, batch):
+def test_fft_kernel_matches_numpy(rng, n, block_b, batch):
     x = _rand_complex(rng, (batch, n))
-    yr, yi = kfft.fft_planes(*_planes(x), radix=radix)
+    yr, yi = kfft.fft_planes(*_planes(x), block_b=block_b)
     want = np.fft.fft(x)
     np.testing.assert_allclose(_join(yr, yi), want,
                                rtol=1e-4, atol=1e-4 * np.sqrt(n))
 
 
 @pytest.mark.parametrize("n", [16, 256])
-@pytest.mark.parametrize("radix", [2, 4])
-def test_ifft_kernel_roundtrip(rng, n, radix):
+@pytest.mark.parametrize("block_b", [None, 2])
+def test_ifft_kernel_roundtrip(rng, n, block_b):
     x = _rand_complex(rng, (4, n))
-    yr, yi = kfft.fft_planes(*_planes(x), radix=radix)
-    zr, zi = kfft.fft_planes(yr, yi, inverse=True, radix=radix)
+    yr, yi = kfft.fft_planes(*_planes(x), block_b=block_b)
+    zr, zi = kfft.fft_planes(yr, yi, inverse=True, block_b=block_b)
     np.testing.assert_allclose(_join(zr, zi), x, rtol=1e-4, atol=1e-5 * n)
 
 
@@ -85,12 +85,12 @@ def test_oracles_agree(rng, oracle):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("n", [8, 64, 512])
-@pytest.mark.parametrize("radix", [2, 4])
-def test_polymul_complex_kernel(rng, n, radix):
+@pytest.mark.parametrize("block_b", [None, 2])
+def test_polymul_complex_kernel(rng, n, block_b):
     a = _rand_complex(rng, (3, n))
     b = _rand_complex(rng, (3, n))
     cr, ci = kpoly.polymul_complex_planes(*_planes(a), *_planes(b),
-                                          radix=radix)
+                                          block_b=block_b)
     want = np.fft.ifft(np.fft.fft(a) * np.fft.fft(b))
     np.testing.assert_allclose(_join(cr, ci), want, rtol=1e-3,
                                atol=1e-4 * n)
@@ -210,6 +210,60 @@ def test_pallas_kernel_equals_oracle_property(n, seed):
     yr, yi = kfft.fft_planes(*_planes(x))
     want = np.asarray(ref.dft(jnp.asarray(x, jnp.complex64)))
     np.testing.assert_allclose(_join(yr, yi), want, rtol=1e-3, atol=1e-3 * n)
+
+
+# ---------------------------------------------------------------------------
+# Kernel tables and the interpret rule
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n", [8, 64, 2048, 16384])
+def test_dft_tables_are_strided_slices_of_master_table(n):
+    """Every four-step table entry is bit-equal to the master table's
+    entry the old strided in-kernel slices read: F_R rows are the stride-C
+    slice W[::C] indexed by k*r mod R, F_C the stride-R slice, T the
+    leading entries of W itself."""
+    r_, c_ = kfft.split(n)
+    for inverse in (False, True):
+        w = kfft.twiddle_table(n, inverse=inverse)
+        tabs = kfft.dft_tables(n, inverse)
+        for plane, (fr, fc, t) in enumerate(
+                [(tabs[0], tabs[2], tabs[4]), (tabs[1], tabs[3], tabs[5])]):
+            wp = w[plane][0]
+            kr, kc = np.arange(r_), np.arange(c_)
+            assert np.array_equal(fr, wp[::c_][np.outer(kr, kr) % r_])
+            assert np.array_equal(fc, wp[::r_][np.outer(kc, kc) % c_])
+            assert np.array_equal(t, wp[np.outer(kr, kc)])
+            assert np.array_equal(fr[1], wp[::c_][:r_])
+
+
+def _kernel_entry_points():
+    from repro.core.ntt import rns
+    from repro.kernels import attention, ntt
+    return {
+        "fft_planes": kfft.fft_planes, "rfft_planes": kfft.rfft_planes,
+        "irfft_planes": kfft.irfft_planes,
+        "polymul_complex_planes": kpoly.polymul_complex_planes,
+        "polymul_real_planes": kpoly.polymul_real_planes,
+        "ntt_batched": ntt.ntt_batched, "ntt_polymul": ntt.ntt_polymul,
+        "rns_ntt_polymul": ntt.rns_ntt_polymul,
+        "rns_polymul": rns.rns_polymul,
+        "flash_attention": attention.flash_attention,
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_kernel_entry_points()))
+def test_kernel_entry_point_interpret_defaults_to_backend_rule(name):
+    """No public Pallas entry point defaults to the interpreter: the
+    default is None, resolved from the backend (compiled on a TPU)."""
+    import inspect
+    fn = _kernel_entry_points()[name]
+    assert inspect.signature(fn).parameters["interpret"].default is None
+
+
+def test_resolve_interpret_rule():
+    assert kfft.resolve_interpret(None) == (jax.default_backend() != "tpu")
+    assert kfft.resolve_interpret(False) is False
+    assert kfft.resolve_interpret(True) is True
 
 
 # ---------------------------------------------------------------------------

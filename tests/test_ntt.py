@@ -220,3 +220,31 @@ def test_kernel_equals_ref_property(n, seed):
     x = r.integers(0, p.q, size=(2, n)).astype(np.uint32)
     got = np.asarray(kntt.ntt_batched(jnp.asarray(x), p))
     assert (got == ref.ntt(x, p).astype(np.uint32)).all()
+
+
+@pytest.mark.parametrize("n", [8, 256, 4096])
+def test_stage_tables_are_strided_slices_of_master_table(n):
+    """Stage d's twiddle at positions i < d is the old strided slice
+    W[::n/(2d)][:d] of the master Montgomery table, bit for bit; the DIF
+    tables carry the same values at positions with bit d set and
+    Montgomery one elsewhere."""
+    from repro.core.ntt.distributed import _master_table
+    from repro.kernels.fft import split
+    p = _params(n)
+    r_, c_ = split(n)
+    n_rows = r_.bit_length() - 1
+    one = np.uint32(p.to_montgomery(np.ones(1, np.uint64))[0])
+    for base in (p.w, p.w_inv):
+        w = np.asarray(_master_table(p, base))[0]
+        dit = kntt.stage_tables(p, base, False)
+        dif = kntt.stage_tables(p, base, True)
+        for s_, d in enumerate(kntt._distances(n)):
+            if d >= c_:
+                t_dit, t_dif = dit[0][s_].ravel(), dif[0][s_].ravel()
+            else:
+                t_dit = np.tile(dit[1][s_ - n_rows].ravel(), r_)
+                t_dif = np.tile(dif[1][s_ - n_rows].ravel(), r_)
+            assert np.array_equal(t_dit[:d], w[::n // (2 * d)][:d])
+            upper = (np.arange(n) & d) != 0
+            assert np.array_equal(t_dif[upper], t_dit[upper])
+            assert (t_dif[~upper] == one).all()

@@ -1,11 +1,11 @@
-"""Real-Hermitian fast path: two-for-one packed rfft/irfft kernels, the
-paired-inverse real polymul, the planner's real tier, and the serve route.
+"""Real-Hermitian fast path: half-spectrum rfft/irfft kernels, the real
+polymul, the planner's real tier, and the serve route.
 
 Contract layers pinned here:
   * kernel parity: ``rfft_planes`` vs ``np.fft.rfft`` at fp32 tolerance,
     ``irfft(rfft(x)) == x`` round-trips, odd/even batch padding edges;
-  * the EXACT Hermitian symmetry of ``hermitian_split`` (bitwise ``==``) —
-    the property the paired inverse relies on;
+  * the EXACT Hermitian symmetry of ``hermitian_split`` (bitwise ``==``),
+    the two-for-one split of ``kops.realpack_fft``;
   * ``polymul_real`` vs the schoolbook circular product up to n = 4096;
   * planner: ``plan(n, b, real=True)`` returns the doubled batch block and
     the real tier; exact+real is rejected;
@@ -49,36 +49,35 @@ def _circular_schoolbook(a, b):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("n", [8, 64, 1024])
-@pytest.mark.parametrize("radix", [2, 4])
+@pytest.mark.parametrize("block_b", [4, 1])
 @pytest.mark.parametrize("batch", [1, 2, 5])
-def test_rfft_kernel_matches_numpy(rng, n, radix, batch):
+def test_rfft_kernel_matches_numpy(rng, n, block_b, batch):
     x = rng.standard_normal((batch, n)).astype(np.float32)
-    yr, yi = kfft.rfft_planes(jnp.asarray(x), radix=radix, block_b=4)
+    yr, yi = kfft.rfft_planes(jnp.asarray(x), block_b=block_b)
     assert yr.shape == yi.shape == (batch, n // 2)   # half-width planes
     np.testing.assert_allclose(_unpack_to_numpy(yr, yi), np.fft.rfft(x),
                                rtol=1e-4, atol=1e-4 * np.sqrt(n))
 
 
 @pytest.mark.parametrize("n", [16, 256])
-@pytest.mark.parametrize("radix", [2, 4])
+@pytest.mark.parametrize("block_b", [4, 1])
 @pytest.mark.parametrize("batch", [1, 3, 4])
-def test_irfft_rfft_roundtrip_kernel(rng, n, radix, batch):
-    """irfft(rfft(x)) == x, including odd batches through the even-block
+def test_irfft_rfft_roundtrip_kernel(rng, n, block_b, batch):
+    """irfft(rfft(x)) == x, including odd batches through the block
     padding path."""
     x = rng.standard_normal((batch, n)).astype(np.float32)
-    yr, yi = kfft.rfft_planes(jnp.asarray(x), radix=radix, block_b=4)
-    back = kfft.irfft_planes(yr, yi, radix=radix, block_b=4)
+    yr, yi = kfft.rfft_planes(jnp.asarray(x), block_b=block_b)
+    back = kfft.irfft_planes(yr, yi, block_b=block_b)
     np.testing.assert_allclose(np.asarray(back), x, rtol=1e-4, atol=1e-4 * n)
 
 
 def test_hermitian_split_exact_symmetry(rng):
     """The split spectra are EXACTLY Hermitian (bitwise), not just close:
-    each mirrored component is the same float expression. The paired
-    inverse in the polymul kernel is only valid because of this."""
+    each mirrored component is the same float expression."""
     n = 64
     zr = jnp.asarray(rng.standard_normal((3, n)), jnp.float32)
     zi = jnp.asarray(rng.standard_normal((3, n)), jnp.float32)
-    ar, ai, br, bi = (np.asarray(v) for v in kfft.hermitian_split(zr, zi))
+    ar, ai, br, bi = (np.asarray(v) for v in kops.hermitian_split(zr, zi))
     for sr, si in ((ar, ai), (br, bi)):
         mirror_r = np.roll(sr[:, ::-1], 1, axis=1)   # S_{n-k}.re
         mirror_i = np.roll(si[:, ::-1], 1, axis=1)
@@ -209,22 +208,20 @@ def test_planner_real_tier_doubled_block():
 
 
 def test_planner_real_tier_local_ceiling_matches_complex():
-    """The real tier's local-n ceiling equals the complex tier's: the
-    minimum schedulable block is a PAIR of real rows (= one full complex
-    row), so at the ceiling the mandatory 2-row block sits exactly at the
-    VMEM budget — doubling the ceiling would demand a 2x-budget block on
-    real hardware. (The batch BLOCK doubles; the ceiling does not.)"""
+    """The real tier's local-n ceiling equals the complex tier's: both are
+    the largest n whose kernels compile for v5e (tests/test_tpu_compile.py
+    compiles at the ceiling). At the ceiling the real block fits the VMEM
+    budget. (The batch BLOCK doubles; the ceiling does not.)"""
     from repro.core.fft import planner
-    from repro.kernels.fft import (VMEM_BUDGET_BYTES, _LIVE_FACTOR,
+    from repro.kernels.fft import (VMEM_BUDGET_BYTES, block_vmem_bytes,
                                    plan_batch_block)
     n_edge = planner._MAX_LOCAL_N_REAL
     assert n_edge == planner._MAX_LOCAL_N
     p = fft_core.plan(n_edge, 1, real=True, model_shards=4)
     assert p.tier == "local"
-    # the mandatory even block at the ceiling fits the budget exactly
     blk = plan_batch_block(n_edge, real=True)
     assert blk >= 2
-    assert blk * n_edge * 4 * _LIVE_FACTOR <= VMEM_BUDGET_BYTES
+    assert block_vmem_bytes(n_edge, blk, real=True) <= VMEM_BUDGET_BYTES
     assert fft_core.plan(2 * n_edge, 1, real=True,
                          model_shards=4).tier == "distributed"
     assert fft_core.plan(2 * planner._MAX_LOCAL_N, 1,

@@ -19,13 +19,14 @@ Dist half (subprocess, 8 virtual devices):
     engine process.
 """
 import os
+import pathlib
 
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
-from conftest import run_in_subprocess_devices
+from conftest import REPO, run_in_subprocess_devices
 from repro.launch import ops as op_registry
 from repro.launch import serve
 from repro.launch.engine import Backpressure, ServeEngine
@@ -316,3 +317,31 @@ assert 0 < lat["p50"] <= lat["p99"], lat
 print("OK")
 """, n_devices=8)
     assert "OK" in out
+
+
+_CACHE_SNIPPET = """
+import jax, jax.numpy as jnp
+from repro.launch.cache import setup_compile_cache
+path = setup_compile_cache()
+jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+print(path)
+print(jax.config.jax_compilation_cache_dir)
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_location(tmp_path, monkeypatch, from_env):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the only cache directory
+    and receives the entries; unset, the cache is <checkout>/.jax_cache."""
+    from repro.launch.cache import DEFAULT_DIR, ENV_VAR
+    if from_env:
+        monkeypatch.setenv(ENV_VAR, str(tmp_path))
+    else:
+        monkeypatch.delenv(ENV_VAR, raising=False)
+    out = run_in_subprocess_devices(_CACHE_SNIPPET, n_devices=1,
+                                    timeout=300).split()
+    want = str(tmp_path) if from_env else str(DEFAULT_DIR)
+    assert out[-2:] == [want, want]
+    assert DEFAULT_DIR.parent == pathlib.Path(REPO)
+    if from_env:
+        assert any(tmp_path.iterdir())
