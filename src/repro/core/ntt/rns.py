@@ -34,6 +34,7 @@ from typing import Iterator
 
 import numpy as np
 
+from repro import telemetry
 from repro.core.ntt.ref import (NTTParams, cyclic_polymul, is_prime,
                                 negacyclic_polymul)
 
@@ -270,6 +271,8 @@ def rns_polymul(a, b, rns: RNSParams, *, negacyclic: bool = True,
 
     Accepts (n,) or (B, n) coefficient arrays (ints or object-dtype big
     ints); returns the same shape as object-dtype residues in [0, Q).
+    Spans (``repro.telemetry``): ``rns.split``, ``rns.kernel`` (launch,
+    both transfers and the wait) and ``rns.crt``.
     """
     from repro.kernels.ntt import rns_ntt_polymul  # deferred: core -> kernels
     a_obj = _as_int_object(a)
@@ -280,11 +283,16 @@ def rns_polymul(a, b, rns: RNSParams, *, negacyclic: bool = True,
     squeeze = a_obj.ndim == 1
     if squeeze:
         a_obj, b_obj = a_obj[None], b_obj[None]
-    ar = to_rns(a_obj, rns).astype(np.uint32)       # residues < 2^30
-    br = to_rns(b_obj, rns).astype(np.uint32)
-    prods = np.asarray(rns_ntt_polymul(ar, br, rns, negacyclic=negacyclic,
-                                       interpret=interpret, block_b=block_b))
-    out = crt_to_modulus(prods.astype(np.uint64), rns)
+    with telemetry.span("rns.split"):
+        ar = to_rns(a_obj, rns).astype(np.uint32)   # residues < 2^30
+        br = to_rns(b_obj, rns).astype(np.uint32)
+    with telemetry.span("rns.kernel"):
+        prods = np.asarray(rns_ntt_polymul(ar, br, rns,
+                                           negacyclic=negacyclic,
+                                           interpret=interpret,
+                                           block_b=block_b))
+    with telemetry.span("rns.crt"):
+        out = crt_to_modulus(prods.astype(np.uint64), rns)
     return out[0] if squeeze else out
 
 

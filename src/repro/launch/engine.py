@@ -25,10 +25,15 @@ bucket. A single scheduler loop drains buckets with continuous batching:
     producers instead of growing host memory without bound.
 
 Metrics (docs/serving.md has the glossary): per-request latency
-(submit -> result materialized) percentiles p50/p90/p99, end-to-end and
-busy-only throughput, and per-bucket batch-size traces with utilization
-(mean dispatched batch / block cap) — the number that says whether traffic
-actually fills the arrays the paper's throughput claims assume.
+(offer to ``submit`` -> result materialized) percentiles p50/p90/p99,
+end-to-end and busy-only throughput, and per-bucket batch-size traces with
+utilization (mean dispatched batch / block cap) — the number that says
+whether traffic actually fills the arrays the paper's throughput claims
+assume. Each engine owns a :class:`~repro.telemetry.Recorder`
+(``engine.telemetry``): spans at every layer boundary it crosses
+(``serve.admit`` ... ``serve.deliver``, and the ``BoundOp`` and RNS spans
+beneath, recorded while ``run`` makes it current on its thread) and the
+queue-wait counter; ``stats()`` reports them.
 
 Fault tolerance (docs/fault_tolerance.md): the engine is preemption-safe.
 ``request_stop`` (the SIGTERM path in ``launch/serve.py``) stops admission
@@ -66,6 +71,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
+from repro import telemetry
 from repro.ft import checkpoint as ckpt_lib
 from repro.ft.watchdog import StepWatchdog, WatchdogConfig
 from repro.launch import ops as op_registry
@@ -96,7 +102,11 @@ class _Request:
     rid: int
     key: tuple[str, int]
     payload: Any
+    # admission, after any backpressure wait: scheduling, deadlines and
+    # queue wait run from here
     t_submit: float
+    # entry to ``submit``: the latency record runs from here
+    t_offer: float
     # absolute perf_counter() deadline; expired requests complete with a
     # structured error instead of a result (and never batch)
     deadline: float | None = None
@@ -241,6 +251,8 @@ class ServeEngine:
         self._stopping = False
         self.results: dict[int, np.ndarray] = {}
         self._latencies_s: list[float] = []
+        # this engine's spans and wait counters (``stats()["spans"]``)
+        self.telemetry = telemetry.Recorder()
         # Warm-restart carry-over (``from_snapshot`` fills these): lifetime
         # counters from before the restart, so the trajectory a deployment
         # reports survives preemption instead of resetting to zero.
@@ -317,43 +329,53 @@ class ServeEngine:
         structured ``deadline_exceeded`` error (``engine.errors[rid]``)
         instead of a result, and is excluded from the latency record.
         """
-        if deadline_s is not None and deadline_s <= 0:
-            raise ValueError(f"deadline_s={deadline_s} must be > 0")
-        if self._stopping:
-            raise EngineStopped(
-                "engine is draining (request_stop/SIGTERM); submit after "
-                "the warm restart")
-        bound = self.register(op, n)     # validates shape/route once
-        bound.check_payload(payload)     # reject NaN/Inf BEFORE it batches
-        deadline = None if timeout is None else time.perf_counter() + timeout
-        with self._cv:
-            while self._pending >= self.max_pending:
-                if self._stopping:
-                    raise EngineStopped(
-                        "engine is draining (request_stop/SIGTERM); "
-                        "submit after the warm restart")
-                if not block:
-                    raise Backpressure(
-                        f"queue full ({self._pending}/{self.max_pending} "
-                        f"pending); retry or shed load")
-                remaining = (None if deadline is None
-                             else deadline - time.perf_counter())
-                if remaining is not None and remaining <= 0:
-                    raise Backpressure(
-                        f"queue full after {timeout}s "
-                        f"({self._pending}/{self.max_pending} pending)")
-                self._cv.wait(remaining if remaining is not None else 0.1)
-            if rid is None:
-                rid = self._next_rid
-            self._next_rid = max(self._next_rid, rid + 1)
-            now = time.perf_counter()
-            self._buckets[bound.key].append(
-                _Request(rid, bound.key, payload, now,
-                         deadline=(None if deadline_s is None
-                                   else now + deadline_s)))
-            self._pending += 1
-            self._cv.notify_all()
-        return rid
+        t_offer = time.perf_counter()
+        with self.telemetry.span("serve.admit"):
+            if deadline_s is not None and deadline_s <= 0:
+                raise ValueError(f"deadline_s={deadline_s} must be > 0")
+            if self._stopping:
+                raise EngineStopped(
+                    "engine is draining (request_stop/SIGTERM); submit "
+                    "after the warm restart")
+            bound = self.register(op, n)    # validates shape/route once
+            bound.check_payload(payload)    # reject NaN/Inf BEFORE it batches
+            deadline = (None if timeout is None
+                        else time.perf_counter() + timeout)
+            with self._cv:
+                if self._pending >= self.max_pending:
+                    with self.telemetry.span("serve.backpressure"):
+                        self._wait_for_space(block, timeout, deadline)
+                if rid is None:
+                    rid = self._next_rid
+                self._next_rid = max(self._next_rid, rid + 1)
+                now = time.perf_counter()
+                self._buckets[bound.key].append(
+                    _Request(rid, bound.key, payload, now, t_offer,
+                             deadline=(None if deadline_s is None
+                                       else now + deadline_s)))
+                self._pending += 1
+                self._cv.notify_all()
+            return rid
+
+    def _wait_for_space(self, block: bool, timeout: float | None,
+                        deadline: float | None) -> None:
+        """Block until the bounded queue has room (caller holds ``_cv``)."""
+        while self._pending >= self.max_pending:
+            if self._stopping:
+                raise EngineStopped(
+                    "engine is draining (request_stop/SIGTERM); "
+                    "submit after the warm restart")
+            if not block:
+                raise Backpressure(
+                    f"queue full ({self._pending}/{self.max_pending} "
+                    f"pending); retry or shed load")
+            remaining = (None if deadline is None
+                         else deadline - time.perf_counter())
+            if remaining is not None and remaining <= 0:
+                raise Backpressure(
+                    f"queue full after {timeout}s "
+                    f"({self._pending}/{self.max_pending} pending)")
+            self._cv.wait(remaining if remaining is not None else 0.1)
 
     # -- scheduling ---------------------------------------------------------
 
@@ -388,9 +410,10 @@ class ServeEngine:
                                                   list[_Request]] | None:
         """Take up to ``max_batch`` requests from the non-empty bucket whose
         head has waited longest; None if nothing arrives within timeout."""
-        with self._cv:
+        with self.telemetry.span("serve.batch"), self._cv:
             if not any(self._buckets.values()):
-                self._cv.wait(timeout)
+                with self.telemetry.span("serve.wait"):
+                    self._cv.wait(timeout)
             self._sweep_expired_locked()
             ready = [(q[0].t_submit, key)
                      for key, q in self._buckets.items() if q]
@@ -402,6 +425,9 @@ class ServeEngine:
             reqs = [q.popleft() for _ in range(take)]
             self._pending -= take
             self._cv.notify_all()
+            now = time.perf_counter()
+            self.telemetry.count("queue_wait",
+                                 sum(now - r.t_submit for r in reqs), take)
             return key, reqs
 
     def _dispatch(self, key: tuple[str, int], reqs: list[_Request]):
@@ -475,18 +501,21 @@ class ServeEngine:
         """Materialize a dispatched batch: record results + latencies."""
         arr = self._bound[key].to_numpy(out)
         if self.verified:
-            arr = self._verified_rows(key, reqs, arr)
-        t_done = time.perf_counter()
-        assert arr.shape[0] == len(reqs), \
-            f"batch executed at {arr.shape[0]} rows for {len(reqs)} requests"
-        stats = self._bucket_stats[key]
-        for j, req in enumerate(reqs):
-            self.results[req.rid] = arr[j]
-            self._latencies_s.append(t_done - req.t_submit)
-        stats.served += len(reqs)
-        stats.batches += 1
-        stats.batch_sizes.append(len(reqs))
-        self._served += len(reqs)
+            with self.telemetry.span("serve.verify"):
+                arr = self._verified_rows(key, reqs, arr)
+        with self.telemetry.span("serve.deliver"):
+            t_done = time.perf_counter()
+            assert arr.shape[0] == len(reqs), \
+                f"batch executed at {arr.shape[0]} rows for {len(reqs)} " \
+                f"requests"
+            stats = self._bucket_stats[key]
+            for j, req in enumerate(reqs):
+                self.results[req.rid] = arr[j]
+                self._latencies_s.append(t_done - req.t_offer)
+            stats.served += len(reqs)
+            stats.batches += 1
+            stats.batch_sizes.append(len(reqs))
+            self._served += len(reqs)
 
     # -- the serve loop -----------------------------------------------------
 
@@ -512,16 +541,16 @@ class ServeEngine:
         One batch is kept in flight: batch k+1 is staged and dispatched
         before batch k is synced, so transfer and compute overlap. Each
         batch's service time (dispatch -> materialized) feeds the straggler
-        watchdog. Returns the stats dict (see ``stats``).
+        watchdog. The engine's recorder is current on this thread while it
+        serves, so the spans of ``BoundOp`` and the routes beneath land in
+        it. Returns the stats dict (see ``stats``).
         """
         target = total_requests
         t0 = time.perf_counter()
-        busy_s = 0.0
         inflight: tuple | None = None
 
-        def finish(flight) -> float:
+        def finish(flight) -> None:
             key, reqs, out, t_disp = flight
-            tb = time.perf_counter()
             self._resolve(key, reqs, out)
             t_done = time.perf_counter()
             self._batch_idx += 1
@@ -529,32 +558,31 @@ class ServeEngine:
             # observed service time, attributed to the bucket: the
             # measured side of predicted-vs-observed in stats()
             self._bucket_stats[key].service_s += t_done - t_disp
-            return t_done - tb
 
-        while self._served < target:
-            if self._stopping and self._pending == 0:
-                break   # drained: nothing left to admit or schedule
-            picked = self._pop_batch(
-                0.0 if self._stopping else self.collect_timeout_s)
-            if picked is None:
+        with self.telemetry.current():
+            while self._served < target:
+                if self._stopping and self._pending == 0:
+                    break   # drained: nothing left to admit or schedule
+                picked = self._pop_batch(
+                    0.0 if self._stopping else self.collect_timeout_s)
+                if picked is None:
+                    if inflight is not None:
+                        finish(inflight)
+                        inflight = None
+                    continue
+                key, reqs = picked
+                tb = time.perf_counter()
+                out = self._dispatch(key, reqs)
                 if inflight is not None:
-                    busy_s += finish(inflight)
-                    inflight = None
-                continue
-            key, reqs = picked
-            tb = time.perf_counter()
-            out = self._dispatch(key, reqs)
+                    finish(inflight)
+                inflight = (key, reqs, out, tb)
             if inflight is not None:
                 finish(inflight)
-            busy_s += time.perf_counter() - tb
-            inflight = (key, reqs, out, tb)
-        if inflight is not None:
-            busy_s += finish(inflight)
-        return self.stats(seconds=time.perf_counter() - t0, busy_s=busy_s)
+        return self.stats(seconds=time.perf_counter() - t0)
 
     # -- metrics ------------------------------------------------------------
 
-    def stats(self, *, seconds: float, busy_s: float) -> dict:
+    def stats(self, *, seconds: float) -> dict:
         # Percentiles over the bounded recent window (LATENCY_WINDOW):
         # lifetime inputs decay instead of accumulating across restarts.
         lat = np.asarray((self._prev_latencies_s
@@ -611,6 +639,16 @@ class ServeEngine:
                 entry["predicted_backend"] = best["backend_best"]
             buckets[f"{op}/n={n}"] = entry
         batches = sum(b.batches for b in self._bucket_stats.values())
+        recorded = self.telemetry.snapshot()
+        spans, counters = recorded["spans"], recorded["counters"]
+        # host seconds the serve loop spent dispatching, fetching and
+        # delivering batches (not queue collection)
+        compute_s = sum(spans[name][1] for name in
+                        ("serve.dispatch", "serve.fetch", "serve.deliver")
+                        if name in spans)
+        queued, queued_s = counters.get("queue_wait", (0, 0.0))
+        admits = spans.get("serve.admit", (0, 0.0, 0.0))[0]
+        blocked_s = spans.get("serve.backpressure", (0, 0.0, 0.0))[1]
         return {
             "served": self._served,
             "batches": batches,
@@ -621,9 +659,17 @@ class ServeEngine:
             "throughput_per_s": self._served / max(seconds, 1e-9),
             # busy-only rate: excludes queue-collection waits, so endpoint
             # comparisons reflect dispatch+compute, not the driver
-            "compute_seconds": busy_s,
-            "compute_throughput_per_s": self._served / max(busy_s, 1e-9),
+            "compute_seconds": compute_s,
+            "compute_throughput_per_s": self._served / max(compute_s, 1e-9),
             "latency_ms": latency_ms,
+            # mean per request of admission -> pop
+            "queue_wait_ms": 1e3 * queued_s / queued if queued else 0.0,
+            # mean per submit call of offer -> admission: the wait for
+            # queue space (serve.backpressure)
+            "admission_wait_ms": 1e3 * blocked_s / admits if admits else 0.0,
+            "spans": {name: {"count": c, "total_ms": 1e3 * total,
+                             "self_ms": 1e3 * own}
+                      for name, (c, total, own) in spans.items()},
             "buckets": buckets,
             # deployment-lifetime view: counters carried across warm
             # restarts (``from_snapshot``), so preemption does not reset

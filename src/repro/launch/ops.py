@@ -42,6 +42,8 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
+from repro import telemetry
+
 
 class OpConfigError(ValueError):
     """Invalid (op, n, modulus_bits, model_shards) combination, raised at
@@ -84,6 +86,11 @@ class BoundOp:
     ``fn`` takes the stacked operand arrays (``stack``'s output, one array
     per operand) at ANY batch size — tail batches run at their actual size;
     the kernels' ``_fit_block`` clamps the VMEM block instead of padding.
+
+    ``execute`` and ``to_numpy`` record spans (``repro.telemetry``) into
+    the recorder current on the calling thread: ``serve.dispatch`` over
+    ``serve.stack``, ``serve.h2d`` and ``serve.launch``; ``serve.fetch``
+    over ``serve.sync`` and ``serve.d2h``.
     """
     spec: "OpSpec"
     n: int
@@ -104,27 +111,30 @@ class BoundOp:
         """Host-side batch assembly: a list of per-request payloads ->
         the operand arrays ``fn`` consumes, at the list's actual length."""
         import jax.numpy as jnp
-        if self.spec.arity == 1:
-            rows = [np.asarray(p, self.payload_dtype) for p in payloads]
-            return (jnp.asarray(np.stack(rows)),)
-        cols = tuple(
-            np.stack([np.asarray(p[i], self.payload_dtype)
-                      for p in payloads])
-            for i in range(self.spec.arity))
+        with telemetry.span("serve.stack"):
+            cols = _stack_operands(self, payloads)
         if self.payload_dtype is object:      # RNS: stays host-side
             return cols
-        return tuple(jnp.asarray(c) for c in cols)
+        with telemetry.span("serve.h2d"):
+            return tuple(jnp.asarray(c) for c in cols)
 
     def execute(self, payloads: Sequence[Any]):
         """Dispatch one batch (async where the route is a jitted fn)."""
-        return self.fn(*self.stack(payloads))
+        with telemetry.span("serve.dispatch"):
+            operands = self.stack(payloads)
+            with telemetry.span("serve.launch"):
+                return self.fn(*operands)
 
     def to_numpy(self, out) -> np.ndarray:
         """Materialize a dispatched batch (blocks until ready)."""
         import jax
-        if self.payload_dtype is not object:
-            out = jax.block_until_ready(out)
-        return np.asarray(out)
+        with telemetry.span("serve.fetch"):
+            if self.payload_dtype is object:  # RNS: already on the host
+                return np.asarray(out)
+            with telemetry.span("serve.sync"):
+                out = jax.block_until_ready(out)
+            with telemetry.span("serve.d2h"):
+                return np.asarray(out)
 
     def warmup(self, batch: int) -> None:
         """Compile the route at the steady-state batch (deploy warmup)."""
@@ -308,8 +318,8 @@ def _circular_complex(a: np.ndarray, b: np.ndarray) -> np.ndarray:
 
 def _stack_operands(bound: BoundOp,
                     payloads: Sequence[Any]) -> tuple[np.ndarray, ...]:
-    """Host-side numpy stacking for the integrity checks (mirrors
-    ``BoundOp.stack`` without the device transfer)."""
+    """Host-side numpy stacking: ``BoundOp.stack`` before the device
+    transfer, and the operands of the integrity checks."""
     if bound.spec.arity == 1:
         return (np.stack([np.asarray(p, bound.payload_dtype)
                           for p in payloads]),)

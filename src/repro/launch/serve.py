@@ -249,7 +249,7 @@ def run_engine_service(args) -> dict:
                                            on_evict=_on_evict)
         print(f"[serve:engine] warm restart #{engine.restarts} from "
               f"{args.snapshot_dir} "
-              f"(lifetime served: {engine.stats(seconds=1, busy_s=1)['lifetime']['served']})")
+              f"(lifetime served: {engine.stats(seconds=1)['lifetime']['served']})")
     else:
         engine = ServeEngine(max_batch=args.batch,
                              max_pending=args.max_pending,
