@@ -3,6 +3,7 @@
 Public surface:
   fft / ifft / polymul / realpack_fft / fft_causal_conv   (kernels.ops)
   rfft / irfft / polymul_real                             (real fast path)
+  fft_planes / rfft_planes / polymul_planes               (float32 planes)
   packed_to_halfspec / halfspec_to_packed                 (layout converters)
   fft_distributed / make_sharded_fft / make_sharded_polymul (four-step)
   rfft_distributed / irfft_distributed / polymul_real_distributed
@@ -10,9 +11,10 @@ Public surface:
   four_step_collective_stats                               (byte ledger form)
   plan / FFTPlan                                           (planner)
 """
-from repro.kernels.ops import (fft, fft_causal_conv, halfspec_to_packed,
-                               ifft, irfft, packed_to_halfspec, polymul,
-                               polymul_real, realpack_fft, rfft)
+from repro.kernels.ops import (fft, fft_causal_conv, fft_planes,
+                               halfspec_to_packed, ifft, irfft,
+                               packed_to_halfspec, polymul, polymul_planes,
+                               polymul_real, realpack_fft, rfft, rfft_planes)
 from repro.core.fft.distributed import (fft_distributed,
                                         four_step_collective_stats,
                                         irfft_distributed, make_sharded_fft,
@@ -27,6 +29,7 @@ from repro.core.fft.planner import FFTPlan, plan
 __all__ = [
     "fft", "ifft", "rfft", "irfft", "polymul", "polymul_real",
     "realpack_fft", "fft_causal_conv",
+    "fft_planes", "rfft_planes", "polymul_planes",
     "packed_to_halfspec", "halfspec_to_packed",
     "fft_distributed", "make_sharded_fft", "make_sharded_polymul",
     "rfft_distributed", "irfft_distributed", "polymul_real_distributed",

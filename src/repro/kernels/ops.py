@@ -36,17 +36,35 @@ def _as2d(x):
     return x.reshape(-1, n), x.shape[:-1]
 
 
-def fft(x: jax.Array, *, inverse: bool = False,
-        backend: str | None = None) -> jax.Array:
-    """Batched FFT of a complex array (..., n)."""
+def join(re: jax.Array, im: jax.Array) -> jax.Array:
+    """Float32 planes -> complex64, the same numbers bit for bit.
+
+    ``fft``, ``rfft`` and the complex ``polymul`` are their ``*_planes``
+    function followed by this join, so a caller that takes the planes
+    instead (the serving routes, which return them to the host as float32
+    words) holds the complex result's own parts.
+    """
+    return jax.lax.complex(re, im)
+
+
+def fft_planes(x: jax.Array, *, inverse: bool = False,
+               backend: str | None = None) -> tuple[jax.Array, jax.Array]:
+    """``fft`` as float32 planes ``(re, im)``, each (..., n)."""
     backend = backend or _auto_backend()
     if backend == "xla":
-        return _ref.fft_stockham(x, inverse=inverse)
+        y = _ref.fft_stockham(x, inverse=inverse)
+        return jnp.real(y), jnp.imag(y)
     x2, lead = _as2d(x)
     xr = jnp.real(x2).astype(jnp.float32)
     xi = jnp.imag(x2).astype(jnp.float32)
     yr, yi = _kfft.fft_planes(xr, xi, inverse=inverse)
-    return (yr + 1j * yi).astype(jnp.complex64).reshape(*lead, x.shape[-1])
+    return yr.reshape(*lead, x.shape[-1]), yi.reshape(*lead, x.shape[-1])
+
+
+def fft(x: jax.Array, *, inverse: bool = False,
+        backend: str | None = None) -> jax.Array:
+    """Batched FFT of a complex array (..., n)."""
+    return join(*fft_planes(x, inverse=inverse, backend=backend))
 
 
 def ifft(x: jax.Array, **kw) -> jax.Array:
@@ -62,6 +80,15 @@ def ifft(x: jax.Array, **kw) -> jax.Array:
 # without the O(n) repack — the layout that never leaves HBM at full width.
 # ---------------------------------------------------------------------------
 
+def _halfspec_planes(yr: jax.Array, yi: jax.Array
+                     ) -> tuple[jax.Array, jax.Array]:
+    """Packed-Nyquist planes (..., n/2) -> numpy-layout planes (..., n/2+1)."""
+    zero = jnp.zeros_like(yr[..., :1])
+    re = jnp.concatenate([yr, yi[..., :1]], axis=-1)
+    im = jnp.concatenate([zero, yi[..., 1:], zero], axis=-1)
+    return re, im
+
+
 def packed_to_halfspec(yr: jax.Array, yi: jax.Array) -> jax.Array:
     """Packed-Nyquist planes (..., n/2) -> numpy-layout (..., n/2+1).
 
@@ -69,10 +96,7 @@ def packed_to_halfspec(yr: jax.Array, yi: jax.Array) -> jax.Array:
     emits the same packed layout as the local kernels, and its callers
     repack with this converter (it is the single layout definition).
     """
-    zero = jnp.zeros_like(yr[..., :1])
-    re = jnp.concatenate([yr, yi[..., :1]], axis=-1)
-    im = jnp.concatenate([zero, yi[..., 1:], zero], axis=-1)
-    return (re + 1j * im).astype(jnp.complex64)
+    return join(*_halfspec_planes(yr, yi))
 
 
 def halfspec_to_packed(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -85,10 +109,26 @@ def halfspec_to_packed(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     return pr, pi
 
 
-# Pre-rename aliases (the converters became public with the distributed
-# real tier).
-_packed_to_halfspec = packed_to_halfspec
-_halfspec_to_packed = halfspec_to_packed
+def rfft_planes(x: jax.Array, *, backend: str | None = None,
+                packed: bool = False) -> tuple[jax.Array, jax.Array]:
+    """``rfft`` as float32 planes ``(re, im)``: numpy's (..., n/2+1)
+    layout, or the kernel's packed-Nyquist (..., n/2) with ``packed=True``.
+    """
+    if jnp.iscomplexobj(x):
+        raise TypeError(f"rfft needs real input, got {x.dtype}")
+    n = x.shape[-1]
+    backend = backend or _auto_backend()
+    if backend == "xla":
+        full = _ref.fft_stockham(x.astype(jnp.complex64))
+        half = full[..., :n // 2 + 1]
+        if packed:
+            return halfspec_to_packed(half)
+        return jnp.real(half), jnp.imag(half)
+    x2, lead = _as2d(x)
+    yr, yi = _kfft.rfft_planes(x2.astype(jnp.float32))
+    yr = yr.reshape(*lead, n // 2)
+    yi = yi.reshape(*lead, n // 2)
+    return (yr, yi) if packed else _halfspec_planes(yr, yi)
 
 
 def rfft(x: jax.Array, *, backend: str | None = None,
@@ -100,19 +140,8 @@ def rfft(x: jax.Array, *, backend: str | None = None,
     route runs the half-spectrum kernel: half the matrix work and half the
     HBM traffic of ``fft`` on real input.
     """
-    if jnp.iscomplexobj(x):
-        raise TypeError(f"rfft needs real input, got {x.dtype}")
-    n = x.shape[-1]
-    backend = backend or _auto_backend()
-    if backend == "xla":
-        full = _ref.fft_stockham(x.astype(jnp.complex64))
-        half = full[..., :n // 2 + 1]
-        return _halfspec_to_packed(half) if packed else half
-    x2, lead = _as2d(x)
-    yr, yi = _kfft.rfft_planes(x2.astype(jnp.float32))
-    yr = yr.reshape(*lead, n // 2)
-    yi = yi.reshape(*lead, n // 2)
-    return (yr, yi) if packed else _packed_to_halfspec(yr, yi)
+    planes = rfft_planes(x, backend=backend, packed=packed)
+    return planes if packed else join(*planes)
 
 
 def irfft(x, *, backend: str | None = None,
@@ -128,11 +157,11 @@ def irfft(x, *, backend: str | None = None,
         pr = jnp.asarray(pr, jnp.float32)
         pi = jnp.asarray(pi, jnp.float32)
     else:
-        pr, pi = _halfspec_to_packed(x)
+        pr, pi = halfspec_to_packed(x)
     n = 2 * pr.shape[-1]
     backend = backend or _auto_backend()
     if backend == "xla":
-        half = _packed_to_halfspec(pr, pi)
+        half = packed_to_halfspec(pr, pi)
         tail = jnp.conj(jnp.flip(half[..., 1:-1], axis=-1))
         full = jnp.concatenate([half, tail], axis=-1)
         return jnp.real(_ref.fft_stockham(full, inverse=True)).astype(
@@ -159,6 +188,45 @@ def polymul_real(a: jax.Array, b: jax.Array, *, mode: str = "linear",
     return polymul(a, b, mode=mode, backend=backend, block_b=block_b)
 
 
+def _padded(a: jax.Array, b: jax.Array, mode: str):
+    """Operands of a ``mode`` product and its length: 'linear' zero-pads
+    to 2n (paper fn. 4), 'circular' keeps n."""
+    assert a.shape == b.shape
+    n = a.shape[-1]
+    if mode == "linear":
+        pads = [(0, 0)] * (a.ndim - 1) + [(0, n)]
+        return jnp.pad(a, pads), jnp.pad(b, pads), 2 * n
+    if mode != "circular":
+        raise ValueError(f"unknown mode {mode!r}")
+    return a, b, n
+
+
+def _xla_product(a: jax.Array, b: jax.Array) -> jax.Array:
+    fa = _ref.fft_stockham(a.astype(jnp.complex64))
+    fb = _ref.fft_stockham(b.astype(jnp.complex64))
+    return _ref.fft_stockham(fa * fb, inverse=True)
+
+
+def polymul_planes(a: jax.Array, b: jax.Array, *, mode: str = "linear",
+                   backend: str | None = None,
+                   block_b: int | None = None
+                   ) -> tuple[jax.Array, jax.Array]:
+    """The complex product of ``polymul`` as float32 planes ``(re, im)``
+    (the three-transform path, whatever the operands' dtypes)."""
+    a, b, n = _padded(a, b, mode)
+    backend = backend or _auto_backend()
+    if backend == "xla":
+        c = _xla_product(a, b)
+        return jnp.real(c), jnp.imag(c)
+    a2, lead = _as2d(a)
+    b2, _ = _as2d(b)
+    cr, ci = _kpoly.polymul_complex_planes(
+        jnp.real(a2).astype(jnp.float32), jnp.imag(a2).astype(jnp.float32),
+        jnp.real(b2).astype(jnp.float32), jnp.imag(b2).astype(jnp.float32),
+        block_b=block_b)
+    return cr.reshape(*lead, n), ci.reshape(*lead, n)
+
+
 def polymul(a: jax.Array, b: jax.Array, *, mode: str = "linear",
             backend: str | None = None,
             block_b: int | None = None) -> jax.Array:
@@ -171,36 +239,18 @@ def polymul(a: jax.Array, b: jax.Array, *, mode: str = "linear",
     Real inputs dispatch to the Eq. (10) half-spectrum path; complex inputs
     use the three-transform path.
     """
-    assert a.shape == b.shape
-    n = a.shape[-1]
-    if mode == "linear":
-        pads = [(0, 0)] * (a.ndim - 1) + [(0, n)]
-        a = jnp.pad(a, pads)
-        b = jnp.pad(b, pads)
-        n = 2 * n
-    elif mode != "circular":
-        raise ValueError(f"unknown mode {mode!r}")
+    if jnp.iscomplexobj(a) or jnp.iscomplexobj(b):
+        return join(*polymul_planes(a, b, mode=mode, backend=backend,
+                                    block_b=block_b))
+    a, b, n = _padded(a, b, mode)
     backend = backend or _auto_backend()
-    real_in = not jnp.iscomplexobj(a) and not jnp.iscomplexobj(b)
-
     if backend == "xla":
-        fa = _ref.fft_stockham(a.astype(jnp.complex64))
-        fb = _ref.fft_stockham(b.astype(jnp.complex64))
-        c = _ref.fft_stockham(fa * fb, inverse=True)
-        return jnp.real(c).astype(jnp.float32) if real_in else c
-
+        return jnp.real(_xla_product(a, b)).astype(jnp.float32)
     a2, lead = _as2d(a)
     b2, _ = _as2d(b)
-    if real_in:
-        c = _kpoly.polymul_real_planes(a2.astype(jnp.float32),
-                                       b2.astype(jnp.float32),
-                                       block_b=block_b)
-        return c.reshape(*lead, n)
-    cr, ci = _kpoly.polymul_complex_planes(
-        jnp.real(a2).astype(jnp.float32), jnp.imag(a2).astype(jnp.float32),
-        jnp.real(b2).astype(jnp.float32), jnp.imag(b2).astype(jnp.float32),
-        block_b=block_b)
-    return (cr + 1j * ci).astype(jnp.complex64).reshape(*lead, n)
+    c = _kpoly.polymul_real_planes(a2.astype(jnp.float32),
+                                   b2.astype(jnp.float32), block_b=block_b)
+    return c.reshape(*lead, n)
 
 
 def hermitian_split(zr, zi):

@@ -183,7 +183,8 @@ class _FaultInjector:
                 row[j] += (1.0 + float(np.abs(row).max())) * 3.0
             else:
                 row[j] = row.dtype.type(int(row[j]) ^ 1)
-        return arr
+        # hand back what the route returns: its float32 words, if it has them
+        return arr if self.bound.result_dtype is None else arr.view(np.float32)
 
 
 class ServeEngine:

@@ -261,5 +261,7 @@ def test_serve_rfft_route(rng):
     svc = FFTService(128, 4, "rfft")
     assert svc.plan.real and svc.route == "rfft-real"
     x = rng.standard_normal((4, 128)).astype(np.float32)
-    got = np.asarray(svc._fn(jnp.asarray(x)))
+    # the route returns float32 words; ``to_numpy`` views them as complex
+    got = svc.bound.to_numpy(svc._fn(jnp.asarray(x)))
+    assert got.dtype == np.complex64 and got.shape == (4, 65)
     np.testing.assert_allclose(got, np.fft.rfft(x), rtol=1e-3, atol=1e-3)

@@ -6,6 +6,8 @@ Unit half:
     from the registry;
   * registry parity: every op served through the engine bit-matches the
     direct BoundOp call on the same payloads (the old single-op path);
+  * complex results come back as float32 words and reach the host as the
+    public kernels' complex64 result, bit for bit;
   * mixed-op / mixed-n bucketing correctness against the numpy oracles;
   * tail batches execute at their ACTUAL size (never padded to the block);
   * latency percentiles are monotone (p50 <= p90 <= p99 <= max);
@@ -21,12 +23,16 @@ Dist half (subprocess, 8 virtual devices):
 import os
 import pathlib
 
+import functools
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from conftest import REPO, run_in_subprocess_devices
+from repro.kernels import ops as kops
 from repro.launch import ops as op_registry
 from repro.launch import serve
 from repro.launch.engine import Backpressure, ServeEngine
@@ -121,6 +127,50 @@ def test_engine_parity_with_direct_dispatch(op, kw, rng):
         else:
             np.testing.assert_array_equal(got, want)
         svc.bound.verify(payloads[rid], got)
+
+
+# ---------------------------------------------------------------------------
+# Complex results: float32 words on the wire, complex64 on the host
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("n", [64, 256])
+@pytest.mark.parametrize("op,public,width", [
+    ("fft", kops.fft, lambda n: n),
+    ("rfft", kops.rfft, lambda n: n // 2 + 1),
+    ("polymul", functools.partial(kops.polymul, mode="circular"),
+     lambda n: n),
+], ids=["fft", "rfft", "polymul"])
+def test_complex_results_come_back_as_words(op, public, width, n, backend,
+                                            monkeypatch, rng):
+    """A complex route's device result is float32 words, (re, im)
+    interleaved; ``to_numpy`` turns them into the complex64 batch the
+    public kernel function gives, bit for bit, at the same shape, and the
+    engine delivers those rows."""
+    monkeypatch.setenv("REPRO_FFT_BACKEND", backend)
+    batch = 5
+    engine = ServeEngine(max_batch=batch, max_pending=16)
+    bound = engine.register(op, n)
+    assert bound.result_dtype == np.complex64
+    payloads = [bound.random_payload(rng) for _ in range(batch)]
+    out = bound.execute(payloads)
+    assert out.dtype == jnp.float32
+    assert out.shape == (batch, 2 * width(n))
+    got = bound.to_numpy(out)
+    assert got.dtype == np.complex64 and got.shape == (batch, width(n))
+    operands = tuple(jnp.asarray(c)
+                     for c in op_registry._stack_operands(bound, payloads))
+    want = np.asarray(jax.jit(functools.partial(public, backend=backend))(
+        *(o.astype(jnp.complex64) if op == "polymul" else o
+          for o in operands)))
+    assert want.dtype == np.complex64 and want.shape == got.shape
+    assert np.array_equal(got, want)
+    for rid, p in enumerate(payloads):
+        engine.submit(op, n, p, rid=rid)
+    engine.run(batch)
+    for rid in range(batch):
+        assert engine.results[rid].dtype == np.complex64
+        assert np.array_equal(engine.results[rid], want[rid]), rid
 
 
 # ---------------------------------------------------------------------------

@@ -3,7 +3,8 @@
   * self time is a span's duration less its children's on the same thread;
   * a served run's counts: one ``serve.admit`` per submit, one
     ``serve.dispatch`` / ``serve.fetch`` / ``serve.deliver`` per batch, the
-    RNS route's ``rns.*`` once per batch;
+    RNS route's ``rns.*`` once per batch, ``serve.join`` once per batch of
+    a complex-result route and never on the others;
   * the latency record starts at the offer, so a request blocked by
     ``max_pending`` waits at least its admission wait;
   * under ``jax.profiler.trace`` the spans land in the host plane, as many
@@ -151,7 +152,7 @@ def test_core_does_not_import_launch():
 
 BATCH_SPANS = ("serve.dispatch", "serve.stack", "serve.h2d",
                "serve.launch", "serve.fetch", "serve.sync", "serve.d2h",
-               "serve.deliver")
+               "serve.join", "serve.deliver")
 
 
 @pytest.mark.parametrize("requests,max_batch", [(10, 4), (8, 8), (5, 1)])
@@ -207,6 +208,29 @@ def test_rns_route_spans_once_per_batch():
     assert rns <= launch["total_ms"]
     assert launch["self_ms"] == pytest.approx(launch["total_ms"] - rns,
                                               abs=1e-6)
+
+
+def test_join_span_only_on_complex_results(rng):
+    """``serve.join`` (the host's view of float32 words as complex64) is
+    recorded once per batch of a complex-result route, and not for the
+    float32 (polymul-real) or object (RNS polymul-mod) routes."""
+    engine = ServeEngine(max_batch=2, max_pending=8, modulus_bits=60)
+    served = 0
+    for op in ("fft", "polymul-real", "polymul-mod"):    # one batch each
+        bound = engine.register(op, 64)
+        for _ in range(2):
+            engine.submit(op, 64, bound.random_payload(rng), rid=served)
+            served += 1
+        spans = engine.run(served)["spans"]
+        assert spans["serve.join"]["count"] == 1, op
+        assert spans["serve.fetch"]["count"] == served // 2, op
+    assert bound.route == "polymul-mod-rns"
+    assert spans["serve.d2h"]["count"] == 2     # the RNS route has none
+    fetch = spans["serve.fetch"]
+    inner = sum(spans[n]["total_ms"]
+                for n in ("serve.sync", "serve.d2h", "serve.join"))
+    assert fetch["self_ms"] == pytest.approx(fetch["total_ms"] - inner,
+                                             abs=1e-6)
 
 
 def test_queue_wait_counts_time_before_the_pop(rng):
