@@ -21,7 +21,8 @@ only ever returns plans the kernels accept), scores each candidate on
 both backends (PIM cost twin and XLA roofline), and returns the
 predicted-cheapest candidate plus a machine-readable breakdown — every
 pruned candidate carries the NAME of the constraint that pruned it
-(the ``n1 = D`` four-step cap, ``D^2 | n`` tiling, the VMEM ceiling),
+(the ``n1 = D`` four-step cap, ``D^2 | n`` tiling, the VMEM ceiling, the
+hbm tier's one device and real product),
 so a non-executable request fails with the reason, not a bare error.
 
 Accounting conventions (shared by the smoke bench's "measured" side so
@@ -75,6 +76,10 @@ from repro.core.pim.polymul_pim import (
 PEAK_FLOPS = 197e12        # bf16 FLOPs/s per chip
 HBM_BW = 819e9             # bytes/s per chip
 LINK_BW = 50e9             # bytes/s per ICI link
+#: HBM passes of a product on the hbm tier, against the local tier's one:
+#: the outer forward pass, the inner forward rows, the paired product, the
+#: inner inverse rows and the outer inverse pass (core/fft/hbm.py)
+HBM_TIER_PASSES = 5
 
 #: Workloads the chooser understands — exactly the ``OpSpec`` registry
 #: names (launch/ops.py), so serve buckets can ask for costs verbatim.
@@ -91,7 +96,7 @@ class TierCost:
     backend. ``total_s`` is the comparison key; the component terms and
     raw PIM cycle / collective byte counts ride along so tests can pin
     them against simulator counters and ledger bytes."""
-    tier: str               # "local" | "distributed"
+    tier: str               # "local" | "hbm" | "distributed"
     backend: str            # "pim" | "xla"
     real: bool
     exact: bool
@@ -152,6 +157,27 @@ def dist_prune_reason(workload: str, n: int, n_devices: int, *,
         return (f"distributed real tier: the ordered rfft's half-width "
                 f"ordering all-to-all needs 2*D^2 | n: n={n}, "
                 f"2*D^2={2 * d2}")
+    return None
+
+
+def hbm_prune_reason(workload: str, n: int, n_devices: int, *,
+                     real: bool) -> str | None:
+    """XLA hbm tier: one device, a row above the VMEM ceiling, and the
+    four-step real product (the only op it implements)."""
+    from repro.core.fft import planner
+    if workload != "polymul-real":
+        return (f"hbm tier: only polymul-real has an HBM-resident "
+                f"four-step (got {workload!r})")
+    if not real:
+        return ("hbm tier: real packing only (products pair two-for-one "
+                "through the packed transform)")
+    if n <= planner._MAX_LOCAL_N_REAL:
+        return (f"hbm tier: n={n} is within the VMEM-resident ceiling "
+                f"_MAX_LOCAL_N={planner._MAX_LOCAL_N_REAL} (the local "
+                f"kernel makes one HBM pass)")
+    if n_devices > 1:
+        return (f"hbm tier: one device only; model_shards={n_devices} > 1 "
+                f"shards the sequence (distributed tier)")
     return None
 
 
@@ -267,13 +293,14 @@ def xla_cost(workload: str, n: int, batch: int, *, tier: str,
         ops = {"fft": 2, "rfft": 2}.get(workload, 3)
         flops += max(batch, 1) * ops * 4.0 * n
         nbytes += max(batch, 1) * n * 8
+    coll = 0
     if tier == "distributed":
         flops /= n_devices
         nbytes /= n_devices
         coll = _xla_collective_bytes(workload, n, max(batch, 1),
                                      n_devices, real=real)
-    else:
-        coll = 0
+    elif tier == "hbm":
+        nbytes *= HBM_TIER_PASSES
     t_comp = flops / PEAK_FLOPS
     t_mem = nbytes / HBM_BW
     t_coll = coll / LINK_BW
@@ -468,7 +495,7 @@ def _packings(workload: str) -> list[bool]:
 
 def workload_cost(workload: str, n: int, batch: int, *,
                   n_devices: int = 1,
-                  tiers: tuple[str, ...] = ("local", "distributed"),
+                  tiers: tuple[str, ...] = ("local", "distributed", "hbm"),
                   packings: list[bool] | None = None,
                   verified: bool = False, pim_ok: bool = True) -> dict:
     """Score every executable (tier, packing) candidate on both backends.
@@ -506,6 +533,8 @@ def workload_cost(workload: str, n: int, batch: int, *,
                      else _packings(workload)):
             if tier == "local":
                 reason = local_prune_reason(workload, n)
+            elif tier == "hbm":
+                reason = hbm_prune_reason(workload, n, n_devices, real=real)
             else:
                 reason = dist_prune_reason(workload, n, n_devices,
                                            real=real)
@@ -524,6 +553,9 @@ def workload_cost(workload: str, n: int, batch: int, *,
             elif tier == "local":
                 pim_bad = pim_local_infeasible(
                     _pim_workload(workload, real), n)
+            elif tier == "hbm":
+                pim_bad = ("pim hbm: the crossbar model has no "
+                           "HBM-resident four-step")
             else:
                 pim_bad = pim_dist_infeasible(n, n_devices)
             if pim_bad is None:

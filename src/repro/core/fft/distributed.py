@@ -339,6 +339,31 @@ def irfft_distributed(p: jax.Array, *, axis_name: str = "model",
     return x.astype(jnp.float32)
 
 
+def zorder_real_product(zz: jax.Array, mirror, at_k1_zero) -> jax.Array:
+    """The paired product spectrum Q = P0 + i P1 of two real products,
+    from the Z-order spectra ``zz`` (..., B, w) of their packed rows
+    z = a + i b: P = A*B = (Z^2 - conj(Z^2_{n-k})) / 4i, and with
+    S = Z0^2 + i Z1^2, W = Z0^2 - i Z1^2, Q = (S - conj(W_{n-k})) / 4i.
+
+    Z-order bin k = k1 + n1*k2 sits in the block of k1 at column k2; its
+    mirror n - k sits in the block of (n1 - k1) mod n1, at column
+    n2 - 1 - k2, or (n2 - k2) mod n2 where k1 = 0. ``mirror(w)`` returns,
+    in ``w``'s layout, each block's mirror block (a ppermute across devices
+    here, a row gather in ``core.fft.hbm``); ``at_k1_zero`` broadcasts to
+    True over the k1 = 0 block, whose reversal wraps (bin 0 is its own
+    mirror). Pairs are rows (2j, 2j+1) of the B axis; the result is
+    (..., B/2, w).
+    """
+    z2 = zz * zz
+    e, o = _split_even_odd(z2)
+    s = e + 1j * o
+    w = e - 1j * o
+    flip = jnp.flip(mirror(w), axis=-1)
+    wrap = jnp.concatenate([flip[..., -1:], flip[..., :-1]], axis=-1)
+    wr = jnp.where(at_k1_zero, wrap, flip)
+    return -0.25j * (s - jnp.conj(wr))
+
+
 def polymul_real_distributed(a: jax.Array, b: jax.Array, *,
                              axis_name: str = "model", n_devices: int,
                              backend: str | None = None) -> jax.Array:
@@ -364,17 +389,9 @@ def polymul_real_distributed(a: jax.Array, b: jax.Array, *,
     z = a.astype(jnp.complex64) + 1j * b.astype(jnp.complex64)
     zz = fft_distributed(z, axis_name=axis_name, n_devices=D, ordered=False,
                          backend=backend)
-    z2 = zz * zz
-    e, o = _split_even_odd(z2)
-    s = e + 1j * o
-    w = e - 1j * o
-    wm = collectives.ppermute(w, axis_name, _mirror_perm(D))
-    flip = jnp.flip(wm, axis=-1)
-    # Full-block mirror: device 0's reversal wraps (bin 0 is its own
-    # mirror), everyone else's is the pure flip of the peer block.
-    wrap = jnp.concatenate([flip[..., -1:], flip[..., :-1]], axis=-1)
-    wr = jnp.where(idx == 0, wrap, flip)
-    q = -0.25j * (s - jnp.conj(wr))
+    q = zorder_real_product(
+        zz, lambda w: collectives.ppermute(w, axis_name, _mirror_perm(D)),
+        idx == 0)
     c = fft_distributed(q, axis_name=axis_name, n_devices=D, inverse=True,
                         _in_zorder=True, backend=backend)
     out = _interleave_rows(jnp.real(c), jnp.imag(c))

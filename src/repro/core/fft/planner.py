@@ -1,9 +1,17 @@
 """Kernel/distribution planner for Fourier ops.
 
 Given (batch, n, mesh) decide:
-  * execution tier: single-device Pallas kernel (batch-sharded) vs.
-    distributed four-step FFT (sequence-sharded over the model axis);
-  * kernel config: batch block (VMEM budget).
+  * execution tier, one of three float tiers:
+      - ``local``: one VMEM-resident Pallas kernel per row (n up to
+        ``_MAX_LOCAL_N``), batch-sharded;
+      - ``hbm``: one device, n above that ceiling, real products only: the
+        four-step with the (n1, n2) matrix in HBM, column passes in HBM and
+        rows of n2 <= the ceiling through the local kernel
+        (``core.fft.hbm``);
+      - ``distributed``: the four-step FFT sequence-sharded over the model
+        axis (``model_shards > 1``);
+  * kernel config: batch block (VMEM budget), and the hbm tier's inner
+    length.
 
 The decision mirrors the paper's configuration ladder (§4.3-4.5): the
 r/2r-configurations are "fits in one array" (-> our single-kernel tier, batch
@@ -20,7 +28,7 @@ from repro.kernels.fft import plan_batch_block
 
 @dataclasses.dataclass(frozen=True)
 class FFTPlan:
-    tier: str           # "local" | "distributed"
+    tier: str           # "local" | "hbm" | "distributed"
     #: butterfly radix; only the distributed tiers' local XLA stages read
     #: it (the local Pallas kernels are radix-free, docs/fourier.md)
     radix: int
@@ -43,6 +51,8 @@ class FFTPlan:
     #: hand-built plans with the same execution config compare equal
     #: (the serve engine keys buckets on plans).
     cost: dict | None = dataclasses.field(default=None, compare=False)
+    #: hbm tier: the row length n2 of the inner VMEM kernel (n1 = n / n2)
+    inner_n: int = 0
 
     def describe(self) -> str:
         if self.exact:
@@ -56,6 +66,11 @@ class FFTPlan:
                     else "four-step DFT on the MXU")
             return (f"local Pallas {kind} kernel, {algo}, "
                     f"batch block {self.block_b} (VMEM-resident)")
+        if self.tier == "hbm":
+            return (f"HBM-resident four-step {kind} on one device: column "
+                    f"DFTs and twiddles in HBM passes, rows of "
+                    f"{self.inner_n} through the local kernel at batch "
+                    f"block {self.block_b}")
         return (f"four-step distributed {kind} over {self.seq_shards} "
                 f"devices, radix-{self.radix} local stages")
 
@@ -69,6 +84,23 @@ class FFTPlan:
 _MAX_LOCAL_N = 1 << 16
 _MAX_LOCAL_N_EXACT = 1 << 16
 _MAX_LOCAL_N_REAL = _MAX_LOCAL_N
+# The hbm tier's inner row length, chosen by measuring a batch of 16
+# products at n = 2^19 on a TPU v5e at each split (device busy 2.66 ms at
+# 2^14, 2.84 at 2^15, 3.51 at 2^13, 3.82 at 2^16; PERF.md), and its fewest
+# rows n1: one sublane tile of the column pass.
+_HBM_INNER_N = 1 << 14
+_HBM_MIN_ROWS = 8
+
+
+def hbm_inner_n(n: int) -> int:
+    """Row length of the hbm tier's inner kernel for an n-point row."""
+    return min(_HBM_INNER_N, _MAX_LOCAL_N, n // _HBM_MIN_ROWS)
+
+
+def _hbm_plan(n: int, cost: dict | None = None) -> FFTPlan:
+    inner = hbm_inner_n(n)
+    return FFTPlan(tier="hbm", radix=4, block_b=plan_batch_block(inner),
+                   seq_shards=1, real=True, cost=cost, inner_n=inner)
 
 
 def plan(n: int, batch: int, *, model_shards: int = 1,
@@ -89,17 +121,20 @@ def plan(n: int, batch: int, *, model_shards: int = 1,
     serving case) to the half-spectrum tier: the rfft/irfft kernels and
     the real ``polymul_real`` with the DOUBLED batch block
     (``plan_batch_block(n, real=True)``) the halved per-row footprint buys.
-    The local-n ceiling matches the complex tier. Mutually exclusive with
-    ``exact``
-    (residues are not packed).
+    The local-n ceiling matches the complex tier. Above it, on one device
+    (``model_shards == 1``), a real plan is the ``hbm`` tier: the
+    four-step real product with its (n1, n2) matrix in HBM
+    (``core.fft.hbm``; only polymul-real binds it). A complex plan above
+    the ceiling needs ``model_shards > 1``: with one device it raises.
+    Mutually exclusive with ``exact`` (residues are not packed).
     ``force_distributed=True`` pins the distributed tier even where the
     policy would keep the sequence local (serve's explicit --model-shards
     request) — shape validation still applies, so the returned plan is
     the one actually executable, not a hand-built record.
     ``workload=`` switches to AUTO mode (docs/planner.md): the cost model
     in ``core.cost`` scores every executable (tier, packing) candidate —
-    local vs four-step, real vs complex packing, PIM vs XLA backend — and
-    the predicted-cheapest one comes back with the full breakdown on
+    local, hbm or four-step; real or complex packing; PIM or XLA backend —
+    and the predicted-cheapest one comes back with the full breakdown on
     ``FFTPlan.cost``. Explicit knobs still win: ``real=True`` pins the
     packing, ``force_distributed=True`` pins the tier, and the legacy
     no-workload call is untouched. A workload with no executable
@@ -144,11 +179,12 @@ def plan(n: int, batch: int, *, model_shards: int = 1,
     # describes what executes, not the local kernel's preference.
     radix = 4 if (n.bit_length() - 1) >= 2 else 2
     if real:
-        if not force_distributed and (n <= _MAX_LOCAL_N_REAL
-                                      or model_shards == 1):
+        if not force_distributed and n <= _MAX_LOCAL_N_REAL:
             return FFTPlan(tier="local", radix=radix,
                            block_b=plan_batch_block(n, real=True),
                            seq_shards=1, real=True)
+        if model_shards == 1:
+            return _hbm_plan(n)
         # Distributed real tier: the four-step path runs the packed complex
         # transform on z = a + i b per row PAIR with the Hermitian split
         # performed per shard before the ordering all-to-all, so the
@@ -164,9 +200,15 @@ def plan(n: int, batch: int, *, model_shards: int = 1,
         _check_dist_shape(n, model_shards, real=False)
         return FFTPlan(tier="distributed", radix=2, block_b=1,
                        seq_shards=model_shards, real=True)
-    if not force_distributed and (n <= _MAX_LOCAL_N or model_shards == 1):
+    if not force_distributed and n <= _MAX_LOCAL_N:
         return FFTPlan(tier="local", radix=radix,
                        block_b=plan_batch_block(n), seq_shards=1)
+    if model_shards == 1:
+        raise ValueError(
+            f"no single-device tier for a complex transform of n={n}: it "
+            f"exceeds the VMEM-resident ceiling _MAX_LOCAL_N={_MAX_LOCAL_N}"
+            f" and the hbm tier serves real products only; pass "
+            f"model_shards > 1")
     _check_dist_shape(n, model_shards, real=False)
     return FFTPlan(tier="distributed", radix=2, block_b=1,
                    seq_shards=model_shards)
@@ -200,7 +242,7 @@ def _plan_auto(n: int, batch: int, workload: str, model_shards: int, *,
                          f"{workload!r}: only 'rfft'/'polymul-real' "
                          f"have a real-packed route")
     tiers = (("distributed",) if force_distributed
-             else ("local", "distributed"))
+             else ("local", "distributed", "hbm"))
     packings = [True] if real else None
     breakdown = workload_cost(workload, n, batch, n_devices=model_shards,
                               tiers=tiers, packings=packings,
@@ -223,6 +265,8 @@ def _plan_auto(n: int, batch: int, workload: str, model_shards: int, *,
         return FFTPlan(tier="local", radix=radix, block_b=block,
                        seq_shards=1, exact=wl_exact, real=best["real"],
                        cost=breakdown)
+    if best["tier"] == "hbm":
+        return _hbm_plan(n, cost=breakdown)
     return FFTPlan(tier="distributed", radix=2, block_b=1,
                    seq_shards=model_shards, exact=wl_exact,
                    real=best["real"], cost=breakdown)

@@ -671,6 +671,10 @@ class ServeEngine:
             "spans": {name: {"count": c, "total_ms": 1e3 * total,
                              "self_ms": 1e3 * own}
                       for name, (c, total, own) in spans.items()},
+            # every counter of the recorder: queue_wait (requests), and on
+            # the hbm tier hbm.batches and hbm.outer_passes
+            "counters": {name: {"count": c, "total_ms": 1e3 * total}
+                         for name, (c, total) in counters.items()},
             "buckets": buckets,
             # deployment-lifetime view: counters carried across warm
             # restarts (``from_snapshot``), so preemption does not reset

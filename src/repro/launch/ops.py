@@ -457,6 +457,10 @@ def _bind_rfft(spec: OpSpec, n: int, ctx: OpContext, batch: int) -> BoundOp:
                     fft_core.fft_planes(x.astype(jnp.complex64)))))
     else:
         plan = _plan_or_config_error(n=n, batch=batch, real=True)
+    if plan.tier == "hbm":
+        raise OpConfigError(
+            f"rfft at n={n} exceeds the VMEM-resident ceiling on one "
+            f"device; the HBM-resident tier serves polymul-real only")
     return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan, route="rfft-real",
                    payload_dtype=np.float32,
                    **_as_words(lambda x: fft_core.rfft_planes(x)))
@@ -508,9 +512,23 @@ register_op(
 
 
 # ---------------------------------------------------------------------------
-# polymul-real — half-spectrum real product; distributed four-step route
-# with model_shards > 1 (odd batches padded internally, docs/fourier.md)
+# polymul-real — half-spectrum real product; above the VMEM ceiling the
+# HBM-resident four-step on one device, the distributed four-step with
+# model_shards > 1 (odd batches padded internally, docs/fourier.md)
 # ---------------------------------------------------------------------------
+
+def _hbm_route(plan) -> Callable[..., Any]:
+    """The hbm tier's batch fn: ``core.fft.hbm.polymul_real_hbm`` at the
+    plan's inner length, counting each batch and its outer HBM passes
+    (``hbm.batches``, ``hbm.outer_passes``) into the current recorder."""
+    from repro.core.fft import hbm
+
+    def fn(a, b):
+        telemetry.count("hbm.batches")
+        telemetry.count("hbm.outer_passes", n=hbm.OUTER_PASSES)
+        return hbm.polymul_real_hbm(a, b, inner_n=plan.inner_n)
+    return fn
+
 
 def _validate_polymul_real(spec: OpSpec, n: int, ctx: OpContext) -> None:
     if ctx.auto:
@@ -552,6 +570,10 @@ def _bind_polymul_real(spec: OpSpec, n: int, ctx: OpContext,
             route="polymul-real-distributed",
             fn=jax.jit(dfft.make_sharded_polymul_real(mesh, batch_axes=())),
             payload_dtype=np.float32, mesh=mesh)
+    if plan.tier == "hbm":
+        return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan,
+                       route="polymul-real-hbm", fn=_hbm_route(plan),
+                       payload_dtype=np.float32)
     if not plan.real:
         # Complex-packing fallback (auto only): full-width product on
         # cast-up operands, real part back — the route the cost model
@@ -573,7 +595,8 @@ def _bind_polymul_real(spec: OpSpec, n: int, ctx: OpContext,
 register_op(
     name="polymul-real", arity=2,
     summary="real circular product via the half-spectrum Hermitian fast "
-            "path; --model-shards > 1 runs the distributed four-step tier",
+            "path; above the VMEM ceiling the HBM-resident four-step on one "
+            "device; --model-shards > 1 runs the distributed four-step tier",
     uses_modulus_bits=False, uses_model_shards=True,
     _validate=_validate_polymul_real, _bind=_bind_polymul_real,
     warmup_payload=_zeros,

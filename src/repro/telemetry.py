@@ -15,7 +15,9 @@ RNS route in ``core/``) by being made current on a thread:
 current on the calling thread, or only annotates when there is none. A
 component that owns a recorder, such as ``ServeEngine``, calls
 ``recorder.span`` directly. Counters (``Recorder.count``) add up waits
-that no single block of code covers, such as a request's time in a queue.
+that no single block of code covers, such as a request's time in a queue,
+and events, such as the batches a route ran; ``count`` adds to the
+recorder current on the calling thread.
 
 Importing this module does not import jax; the first span does.
 """
@@ -129,3 +131,11 @@ class Recorder:
 def span(name: str) -> Span:
     """A span recorded into the recorder current on this thread, if any."""
     return Span(name, getattr(_local, "recorder", None))
+
+
+def count(name: str, seconds: float = 0.0, n: int = 1) -> None:
+    """Add ``n`` events to counter ``name`` of the recorder current on
+    this thread, if any."""
+    recorder = getattr(_local, "recorder", None)
+    if recorder is not None:
+        recorder.count(name, seconds, n)

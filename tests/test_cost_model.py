@@ -187,6 +187,38 @@ def test_prune_reasons_name_their_constraint():
                                             real=True)
 
 
+@pytest.mark.parametrize("workload,n,D,real,name", [
+    ("fft", 2 ** 19, 1, False, "only polymul-real"),
+    ("polymul-real", 2 ** 19, 1, False, "real packing only"),
+    ("polymul-real", 2 ** 16, 1, True, "_MAX_LOCAL_N"),
+    ("polymul-real", 2 ** 19, 4, True, "model_shards=4 > 1"),
+])
+def test_hbm_prune_reasons_name_their_constraint(workload, n, D, real, name):
+    reason = cost_lib.hbm_prune_reason(workload, n, D, real=real)
+    assert reason.startswith("hbm tier:") and name in reason
+
+
+def test_hbm_tier_is_offered_above_the_ceiling_on_one_device():
+    assert cost_lib.hbm_prune_reason("polymul-real", 2 ** 19, 1,
+                                     real=True) is None
+    b = workload_cost("polymul-real", 2 ** 19, 16)
+    assert [(c["tier"], c["real"]) for c in b["candidates"]] == [("hbm",
+                                                                 True)]
+    hbm = b["candidates"][0]["backends"]
+    assert "HBM-resident" in hbm["pim"]["infeasible"]
+    local = xla_cost("polymul-real", 2 ** 19, 16, tier="local", real=True)
+    assert hbm["xla"]["t_memory_s"] == pytest.approx(
+        cost_lib.HBM_TIER_PASSES * local.t_memory_s)
+    p = plan(2 ** 19, 16, workload="polymul-real")
+    assert p.tier == "hbm" and p.real and p.seq_shards == 1
+    assert p.inner_n == planner.hbm_inner_n(2 ** 19) and p.cost is not None
+    # with more devices the sequence is sharded, as before
+    assert plan(2 ** 19, 16, workload="polymul-real",
+                model_shards=4).tier == "distributed"
+    # at the ceiling the fused local kernel stays the choice
+    assert plan(2 ** 14, 64, workload="polymul-real").tier == "local"
+
+
 def test_pim_infeasibility_names_its_constraint():
     assert pim_local_infeasible("fft", 2048) is None
     bad = pim_local_infeasible("fft", 65536)

@@ -228,6 +228,29 @@ def test_planner_real_tier_local_ceiling_matches_complex():
                          model_shards=4).tier == "distributed"
 
 
+def test_planner_real_tier_above_the_ceiling_on_one_device_is_hbm():
+    """Above the VMEM ceiling one device no longer gets a local plan whose
+    kernel cannot compile: a real plan is the hbm tier (rows of at most
+    the ceiling, at least eight of them), a complex plan raises."""
+    from repro.core.fft import planner
+    for n in (2 * planner._MAX_LOCAL_N, 1 << 19, 1 << 22):
+        p = fft_core.plan(n, 16, real=True)
+        assert p.tier == "hbm" and p.real and p.seq_shards == 1
+        assert p.inner_n <= planner._MAX_LOCAL_N
+        assert n // p.inner_n >= planner._HBM_MIN_ROWS
+        assert p.block_b == kfft.plan_batch_block(p.inner_n)
+        assert "HBM-resident" in p.describe()
+    with pytest.raises(ValueError, match="_MAX_LOCAL_N"):
+        fft_core.plan(1 << 19, 16)
+    assert fft_core.plan(planner._MAX_LOCAL_N, 1, real=True).tier == "local"
+
+
+def test_rfft_above_the_ceiling_on_one_device_is_refused():
+    from repro.launch.ops import OpConfigError, get_op
+    with pytest.raises(OpConfigError, match="polymul-real only"):
+        get_op("rfft").bind(1 << 19)
+
+
 def test_planner_rejects_exact_real_combo():
     with pytest.raises(ValueError):
         fft_core.plan(1024, batch=8, exact=True, real=True)

@@ -116,6 +116,27 @@ def test_ntt_compiles_at_planner_ceiling(one_chip):
                    interpret=False)
 
 
+def test_hbm_polymul_real_route_compiles_at_its_deployment(one_chip):
+    """The gw-n524288-closed deployment: n = 2^19 (256 s at 2048 Hz) real
+    products at the engine's batch of 16 through the hbm tier, Pallas
+    kernels compiled; each of its launches fits the VMEM budget."""
+    from repro.core.fft import hbm
+    n, batch = 1 << 19, 16
+    p = planner.plan(n, batch, real=True)
+    assert p.tier == "hbm"
+    x = _spec((batch, n), jnp.float32, one_chip)
+    text = hbm.polymul_real_hbm.lower(
+        x, x, inner_n=p.inner_n, interpret=False).compile().as_text()
+    for name in ("tpu_custom_call", "hbm_outer_fwd", "hbm_outer_inv",
+                 "fft_planes"):
+        assert name in text, name
+    n1 = n // p.inner_n
+    assert hbm.outer_vmem_bytes(n, n1) <= kfft.VMEM_BUDGET_BYTES
+    inner_block = kfft._fit_block(p.block_b, batch * n1)
+    assert kfft.block_vmem_bytes(p.inner_n, inner_block) \
+        <= kfft.VMEM_BUDGET_BYTES
+
+
 def test_planned_blocks_fit_the_vmem_budget():
     """Every planned block at the ceiling and at the engine's n fits the
     budget the kernels are planned against."""
