@@ -8,7 +8,8 @@ One chip: one ``ServeEngine`` (the engine ``repro.launch.serve --service
 engine`` runs) serves fft, rfft, polymul, polymul-real and polymul-mod at
 n = 16384, the paper's largest sweep length, with a 64-row batch; a second
 engine serves polymul-mod on the RNS route at the HomomorphicEncryption.org
-128-bit point (N = 8192, log Q = 218, 17 limbs). Every bucket warms up
+128-bit point (N = 8192, log Q = 218: Q is 8 limb primes, and the route
+works in Q's own basis). Every bucket warms up
 (compile time is reported as set-up), serves full batches, and has served
 results checked by the registry's numpy or big-int oracle; every bucket's
 compiled program must hold a Pallas kernel (``tpu_custom_call``).
@@ -51,10 +52,9 @@ def _kernel_text(bound, batch: int) -> str:
     import jax.numpy as jnp
     if bound.rns is not None:        # host CRT around one Pallas launch
         from repro.kernels.ntt import rns_ntt_polymul
-        spec = jax.ShapeDtypeStruct((bound.rns.k, batch, bound.n),
-                                    jnp.uint32)
-        return rns_ntt_polymul.lower(spec, spec, bound.rns).compile() \
-            .as_text()
+        basis = bound.rns.own_basis or bound.rns    # what rns_polymul runs
+        spec = jax.ShapeDtypeStruct((basis.k, batch, bound.n), jnp.uint32)
+        return rns_ntt_polymul.lower(spec, spec, basis).compile().as_text()
     zeros = bound.spec.warmup_payload(bound, batch)
     return jax.jit(bound.fn).lower(*zeros).compile().as_text()
 
@@ -115,7 +115,8 @@ def one_chip(rng) -> int:
                       modulus_bits=RNS_BITS)
     reports += _serve(rns, [("polymul-mod", RNS_N)], 64, rng,
                       kernel_check=True)
-    limbs = rns.bound("polymul-mod", RNS_N).rns.k
+    bound_rns = rns.bound("polymul-mod", RNS_N).rns
+    limbs = (bound_rns.own_basis or bound_rns).k
     _log(f"rns route: N={RNS_N} log Q={RNS_BITS} limbs={limbs}")
     _print(reports)
     missing = [r["op"] for r in reports if not r["tpu_custom_call"]]

@@ -8,15 +8,25 @@ pairwise-coprime 30-bit NTT-friendly limb primes, polynomial products run
 per limb through the *existing* NTT stack — reference, Pallas kernel
 (``kernels.ntt.rns_ntt_polymul``, all limbs in one launch), or PIM cost
 model (``core.pim.ntt_pim.pim_rns_polymul``) — and the Chinese Remainder
-Theorem reconstructs the exact integer result, which reduces mod Q.
+Theorem gives the ring product mod Q.
 
-Correctness bound: a negacyclic coefficient of a·b with |a_i|, |b_i| < Q is
-an alternating sum of at most n products, so its magnitude is < n·Q².  The
-limb set is chosen with M = prod q_i > 2·n·Q², which makes the centered CRT
-lift exact; reducing that integer mod Q is then the true ring product in
-Z_Q[x]/(x^n ± 1).  Q itself needs no structure at all (it may be even,
-composite, or a product of scheme primes) — only the limbs must be coprime,
-and distinct primes always are.
+Two working bases, each exact for its own reason:
+
+  * Q's own basis (``RNSParams.own_basis``), used by ``rns_polymul``
+    whenever Q is a product of distinct limb primes (what
+    ``RNSParams.make(modulus_bits=...)`` builds, and what a full-RNS FHE
+    modulus chain is).  Each limb computes every coefficient of a·b exactly
+    mod its prime q_i | Q, so the CRT of those residues over Q = prod q_i
+    *is* the coefficient mod Q: no lift, no extra limbs.  Inputs need not
+    even be reduced mod Q.
+  * The extended basis (the full limb set), used otherwise and by the
+    oracles.  A negacyclic coefficient of a·b with |a_i|, |b_i| < Q is an
+    alternating sum of at most n products, so its magnitude is < n·Q².
+    The limb set is chosen with M = prod q_i > 2·n·Q², which makes the
+    centered CRT lift the exact integer; reducing it mod Q is then the
+    true ring product in Z_Q[x]/(x^n ± 1).  Q itself needs no structure
+    (it may be even, composite, or have factors outside the limb set) —
+    only the limbs must be coprime, and distinct primes always are.
 
 Two reconstruction paths, both from the same Garner mixed-radix digits
 (digit arithmetic is entirely mod q_i < 2^30, so it vectorizes in uint64):
@@ -101,6 +111,25 @@ class RNSParams:
                 f"Q~2^{modulus.bit_length()}")
         return cls(n=n, modulus=modulus,
                    limbs=tuple(NTTParams.make(n, q=p) for p in limbs))
+
+    @functools.cached_property
+    def own_basis(self) -> "RNSParams | None":
+        """The limbs whose primes divide Q, as parameters of their own, when
+        Q is their product (each prime once); None when Q has any other
+        factor. Products mod Q need only these limbs and no lift (module
+        docstring). Cached, so every access gives the same hashable object
+        (the kernel's jit and its table cache key on it)."""
+        rest = self.modulus
+        own = []
+        for p in self.limbs:
+            if rest % p.q == 0:
+                own.append(p)
+                rest //= p.q
+        if rest != 1:
+            return None
+        if len(own) == self.k:
+            return self
+        return RNSParams(n=self.n, modulus=self.modulus, limbs=tuple(own))
 
     @property
     def k(self) -> int:
@@ -208,13 +237,17 @@ def crt_reconstruct_u64(residues, rns: RNSParams) -> np.ndarray:
 
 
 def crt_to_modulus(residues, rns: RNSParams) -> np.ndarray:
-    """Centered CRT lift reduced into [0, Q): the exact ring coefficient.
+    """The exact ring coefficient in [0, Q) from its limb residues.
 
-    The lift maps [0, M) onto (-M/2, M/2]; with M > 2·n·Q² that interval
-    contains the true (possibly negative) convolution coefficient, so the
-    final ``% Q`` is exact integer arithmetic, not a modeling choice.
+    On Q's own basis (M == Q) the CRT value in [0, Q) is already the
+    answer. Otherwise the centered lift maps [0, M) onto (-M/2, M/2]; with
+    M > 2·n·Q² that interval contains the true (possibly negative)
+    convolution coefficient, so the final ``% Q`` is exact integer
+    arithmetic, not a modeling choice.
     """
     raw = crt_reconstruct(residues, rns)
+    if rns.limb_product == rns.modulus:
+        return raw
     half = rns.limb_product // 2
     centered = np.where(raw > half, raw - rns.limb_product, raw)
     return centered % rns.modulus
@@ -267,12 +300,15 @@ def rns_polymul(a, b, rns: RNSParams, *, negacyclic: bool = True,
                 block_b: int | None = None
                 ) -> np.ndarray:
     """Exact product mod (x^n ± 1, Q) through the fused Pallas kernel: one
-    launch for all k limbs (``kernels.ntt.rns_ntt_polymul``), then CRT.
+    launch for all limbs (``kernels.ntt.rns_ntt_polymul``), then CRT.
 
+    Works in Q's own basis (``rns.own_basis``) when Q factors over the limb
+    primes, else in the extended basis with the centered lift.
     Accepts (n,) or (B, n) coefficient arrays (ints or object-dtype big
     ints); returns the same shape as object-dtype residues in [0, Q).
     Spans (``repro.telemetry``): ``rns.split``, ``rns.kernel`` (launch,
-    both transfers and the wait) and ``rns.crt``.
+    both transfers and the wait) and ``rns.crt``; counter ``rns.own_basis``,
+    one per call on Q's own basis.
     """
     from repro.kernels.ntt import rns_ntt_polymul  # deferred: core -> kernels
     a_obj = _as_int_object(a)
@@ -283,16 +319,21 @@ def rns_polymul(a, b, rns: RNSParams, *, negacyclic: bool = True,
     squeeze = a_obj.ndim == 1
     if squeeze:
         a_obj, b_obj = a_obj[None], b_obj[None]
+    basis = rns.own_basis
+    if basis is None:
+        basis = rns
+    else:
+        telemetry.count("rns.own_basis")
     with telemetry.span("rns.split"):
-        ar = to_rns(a_obj, rns).astype(np.uint32)   # residues < 2^30
-        br = to_rns(b_obj, rns).astype(np.uint32)
+        ar = to_rns(a_obj, basis).astype(np.uint32)   # residues < 2^30
+        br = to_rns(b_obj, basis).astype(np.uint32)
     with telemetry.span("rns.kernel"):
-        prods = np.asarray(rns_ntt_polymul(ar, br, rns,
+        prods = np.asarray(rns_ntt_polymul(ar, br, basis,
                                            negacyclic=negacyclic,
                                            interpret=interpret,
                                            block_b=block_b))
     with telemetry.span("rns.crt"):
-        out = crt_to_modulus(prods.astype(np.uint64), rns)
+        out = crt_to_modulus(prods.astype(np.uint64), basis)
     return out[0] if squeeze else out
 
 

@@ -23,7 +23,8 @@ request payload without recomputing the op:
                 mod every p | Q and each factor has its own 2n-th root
                 psi_p. Scheme-style Q (built by ``RNSParams.make(
                 modulus_bits=...)``) is a product of the limb primes, so
-                the factors are recovered from ``rns.limbs`` directly; a
+                the factors are the limbs of Q's own basis
+                (``rns.own_basis``); a
                 modulus that does not factor over its limbs raises
                 :class:`ABFTUnsupportedModulus` at bind time, not at
                 check time.
@@ -194,25 +195,19 @@ def check_polymul_mod(a, b, r, params) -> IntegrityVerdict:
         detail=f"q={params.q}")
 
 
-@functools.lru_cache(maxsize=32)
 def check_limbs_for(rns) -> tuple:
-    """The NTTParams of the prime factors of Q, recovered from the RNS
-    working-limb set (``RNSParams.make(modulus_bits=...)`` builds Q as a
-    product of a prefix of those limbs). Raises
+    """The NTTParams of the prime factors of Q: the limbs of Q's own basis
+    (``rns.own_basis``; ``RNSParams.make(modulus_bits=...)`` builds Q as a
+    product of a prefix of the working limbs). Raises
     :class:`ABFTUnsupportedModulus` when Q has any other factor."""
-    q = rns.modulus
-    out = []
-    for limb in rns.limbs:
-        if q % limb.q == 0:
-            out.append(limb)
-            q //= limb.q
-        if q == 1:
-            return tuple(out)
-    raise ABFTUnsupportedModulus(
-        f"RNS modulus Q~2^{rns.modulus.bit_length()} does not factor over "
-        f"its limb primes (remainder ~2^{q.bit_length()}); the per-factor "
-        f"eval-at-psi check needs a scheme-style Q = product of NTT limb "
-        f"primes — rebuild the route with RNSParams.make(modulus_bits=...)")
+    own = rns.own_basis
+    if own is None:
+        raise ABFTUnsupportedModulus(
+            f"RNS modulus Q~2^{rns.modulus.bit_length()} does not factor "
+            f"over its limb primes; the per-factor eval-at-psi check needs "
+            f"a scheme-style Q = product of NTT limb primes — rebuild the "
+            f"route with RNSParams.make(modulus_bits=...)")
+    return own.limbs
 
 
 def check_polymul_rns(a, b, r, rns) -> IntegrityVerdict:

@@ -202,6 +202,126 @@ def test_rns_scalar_prefetch_bit_exact(rng, negacyclic):
 
 
 # ---------------------------------------------------------------------------
+# Q's own basis: products mod Q on Q's primes alone, no lift
+# ---------------------------------------------------------------------------
+
+OWN_BITS = [60, 120, 210]
+
+
+def _foreign_moduli(n):
+    """Moduli with a factor outside the limb set: a Mersenne prime, a limb
+    prime squared, a limb prime times 3."""
+    q0 = next(rns.ntt_limb_primes(n))
+    return [(1 << 61) - 1, q0 * q0, 3 * q0]
+
+
+@pytest.mark.parametrize("bits", OWN_BITS)
+def test_own_basis_is_qs_primes(bits):
+    """make(modulus_bits=...) builds Q from limb primes: its own basis is
+    those limbs, their product is Q, and every access gives one object."""
+    r = _rns(64, bits)
+    own = r.own_basis
+    assert own is not None and own is r.own_basis
+    assert own.limb_product == own.modulus == r.modulus
+    assert own.n == r.n and own.limbs == r.limbs[:own.k] and own.k < r.k
+    assert own.own_basis is own
+
+
+@pytest.mark.parametrize("negacyclic", [True, False])
+@pytest.mark.parametrize("bits", OWN_BITS)
+def test_rns_polymul_on_own_basis_matches_oracles(rng, bits, negacyclic):
+    """rns_polymul (own basis, kernel) == big-int schoolbook == the
+    extended-basis reference with its centered lift."""
+    n = 64
+    r = _rns(n, bits)
+    a = np.stack([rns.random_poly(rng, n, r.modulus) for _ in range(2)])
+    b = np.stack([rns.random_poly(rng, n, r.modulus) for _ in range(2)])
+    got = rns.rns_polymul(a, b, r, negacyclic=negacyclic)
+    for i in range(2):
+        want = rns.schoolbook_polymul_mod(a[i], b[i], r.modulus,
+                                          negacyclic=negacyclic)
+        assert (got[i] == want).all()
+        mid = rns.rns_polymul_reference(a[i], b[i], r,
+                                        negacyclic=negacyclic)
+        assert (mid == want).all()
+
+
+@pytest.mark.parametrize("which", range(3))
+@pytest.mark.parametrize("negacyclic", [True, False])
+def test_foreign_factor_keeps_the_extended_basis(rng, which, negacyclic):
+    """A Q with a factor outside the limb set has no own basis; the
+    extended path (centered lift over M > 2nQ^2) still matches the oracle,
+    and the own-basis counter stays silent."""
+    from repro import telemetry
+    n = 64
+    r = rns.RNSParams.make(n, modulus=_foreign_moduli(n)[which])
+    assert r.own_basis is None
+    a = rns.random_poly(rng, n, r.modulus)
+    b = rns.random_poly(rng, n, r.modulus)
+    rec = telemetry.Recorder()
+    with rec.current():
+        got = rns.rns_polymul(a, b, r, negacyclic=negacyclic)
+    want = rns.schoolbook_polymul_mod(a, b, r.modulus, negacyclic=negacyclic)
+    assert (got == want).all()
+    assert "rns.own_basis" not in rec.snapshot()["counters"]
+    assert rec.snapshot()["spans"]["rns.crt"][0] == 1
+
+
+@pytest.mark.parametrize("bits", OWN_BITS)
+def test_crt_to_modulus_on_own_basis_is_crt_reconstruct(rng, bits):
+    """On a basis whose product is Q, the CRT value is the residue mod Q:
+    crt_to_modulus returns crt_reconstruct's value, and it round-trips
+    any x in [0, Q)."""
+    n = 64
+    own = _rns(n, bits).own_basis
+    x = np.stack([rns.random_poly(rng, n, own.modulus) for _ in range(3)])
+    res = rns.to_rns(x, own)
+    got = rns.crt_to_modulus(res, own)
+    assert (got == rns.crt_reconstruct(res, own)).all()
+    assert (got == x).all()
+
+
+@pytest.mark.parametrize("bits", OWN_BITS)
+def test_own_basis_counts_one_per_batch(rng, bits):
+    """Under a Recorder, ``rns.own_basis`` counts each rns_polymul call that
+    ran in Q's own basis, next to the spans of the same calls."""
+    from repro import telemetry
+    n = 64
+    r = _rns(n, bits)
+    rec = telemetry.Recorder()
+    calls = 3
+    with rec.current():
+        for _ in range(calls):
+            a = np.stack([rns.random_poly(rng, n, r.modulus)
+                          for _ in range(2)])
+            rns.rns_polymul(a, a, r)
+    snap = rec.snapshot()
+    assert snap["counters"]["rns.own_basis"][0] == calls
+    assert snap["spans"]["rns.split"][0] == calls
+
+
+def test_engine_rns_route_runs_in_own_basis():
+    """The served RNS route counts ``rns.own_basis`` once per dispatched
+    batch in ``stats()["counters"]``, and its results match the registry's
+    extended-basis oracle."""
+    from repro.launch.engine import ServeEngine
+    engine = ServeEngine(max_batch=2, max_pending=8, modulus_bits=120)
+    bound = engine.register("polymul-mod", 64)
+    assert bound.route == "polymul-mod-rns"
+    assert bound.rns.own_basis is not None
+    rng = np.random.default_rng(2**31 + 16)
+    payloads = [bound.random_payload(rng) for _ in range(3)]
+    for rid, p in enumerate(payloads):
+        engine.submit("polymul-mod", 64, p, rid=rid)
+    stats = engine.run(3)
+    assert (stats["counters"]["rns.own_basis"]["count"]
+            == stats["spans"]["serve.dispatch"]["count"] == 2)
+    for rid, (a, b) in enumerate(payloads):
+        want = rns.rns_polymul_reference(a, b, bound.rns)
+        assert (engine.results[rid] == want).all()
+
+
+# ---------------------------------------------------------------------------
 # Cross-stack differential: float FFT vs exact NTT
 # ---------------------------------------------------------------------------
 

@@ -98,6 +98,15 @@ def test_rns_ntt_polymul_compiles_for_v5e(one_chip, n, bits):
     _assert_kernel(kntt.rns_ntt_polymul, a, a, rns, interpret=False)
 
 
+def test_rns_own_basis_compiles_for_v5e(one_chip):
+    """The kernel the he128 cell serves: N=8192, 210-bit Q, in Q's own
+    basis (its 7 primes), at the cell's batch of 16."""
+    rns = RNSParams.make(8192, modulus_bits=210).own_basis
+    assert rns.k == 7
+    a = _spec((rns.k, 16, rns.n), jnp.uint32, one_chip)
+    _assert_kernel(kntt.rns_ntt_polymul, a, a, rns, interpret=False)
+
+
 def test_fft_compiles_at_planner_ceiling(one_chip):
     """_MAX_LOCAL_N is a compile fact: the float kernels compile at the
     local ceiling at their planned block."""
