@@ -21,10 +21,11 @@ import os
 
 from benchmarks.runlib import emit
 from repro.configs.registry import SHAPES, get_config
-# Single source of truth for the machine model lives with the serving
-# cost model (repro.core.cost) so the planner and this roofline can
-# never drift apart; re-exported here for the existing callers.
-from repro.core.cost import HBM_BW, LINK_BW, PEAK_FLOPS  # noqa: F401
+# Machine model of one v5e-class chip (benchmarks/perf_iter.py reads
+# these too).
+PEAK_FLOPS = 197e12        # bf16 FLOPs/s per chip
+HBM_BW = 819e9             # bytes/s per chip
+LINK_BW = 50e9             # bytes/s per ICI link
 
 
 def collective_term_from_ledger(led) -> float:

@@ -163,209 +163,6 @@ def serve_engine_smoke(requests: int = 36, max_batch: int = 8) -> dict:
     }
 
 
-def auto_plan_agreement_smoke() -> dict:
-    """Predicted-best vs measured-best tier agreement across the planner
-    grid — the acceptance gate for the cost-model-driven auto planner
-    (docs/planner.md).
-
-    For every grid point, ``plan(n, batch, workload=...)``'s choice is
-    re-derived from MEASURED quantities:
-
-      * PIM cycle counts come from live ``CrossbarSim`` runs (the closed
-        forms the model uses are asserted equal to the counters first);
-      * distributed collective bytes come from a live ``dist.collectives``
-        ledger trace of the actual sharded builders — every closed-form
-        term is linear in the per-device block n/D, so the single-device
-        trace divided by D IS the per-device traffic at D (asserted
-        divisible);
-      * the XLA on-chip roofline terms are shared by both sides (there is
-        no hardware to measure in CI), so the comparison is decided by
-        the measured cycles and bytes.
-
-    The measured totals re-run the planner's argmin (same tie-break);
-    predicted (tier, packing) must equal measured (tier, packing) on
-    EVERY point. The agreement rate lands in BENCH_fourier.json and is
-    ratcheted by benchmarks/trajectory.py (direction: max, i.e. 1.0
-    forever)."""
-    import numpy as np
-    import jax
-    import jax.numpy as jnp
-
-    from benchmarks.runlib import emit
-    from repro.core import cost as cost_lib
-    from repro.core.fft import distributed as dfft
-    from repro.core.fft.planner import plan
-    from repro.core.ntt import NTTParams
-    from repro.core.ntt import distributed as dntt
-    from repro.core.pim import (FOURIERPIM_8, FP32, INT32, aritpim,
-                                fft_pim, ntt_pim, polymul_pim)
-    from repro.dist import collectives, compat
-
-    cfg = FOURIERPIM_8
-    rng = np.random.default_rng(0)
-    unpack = fft_pim.realpack_unpack_cycles(cfg, FP32)
-
-    def sim_local_cycles(wl, n, batch):
-        """Measured side of ``cost.pim_local_unit_cycles``: run the
-        CrossbarSim and read the counter. ``wl`` is the effective PIM
-        workload (complex fallbacks already mapped to fft/polymul)."""
-        if wl == "fft":
-            z = rng.standard_normal(n) + 1j * rng.standard_normal(n)
-            return fft_pim.pim_fft(z, cfg, FP32).counters.cycles
-        if wl == "rfft":
-            return fft_pim.pim_rfft(rng.standard_normal(n),
-                                    rng.standard_normal(n),
-                                    cfg, FP32).counters.cycles
-        if wl == "polymul":
-            a = rng.standard_normal(n) + 1j * rng.standard_normal(n)
-            b = rng.standard_normal(n) + 1j * rng.standard_normal(n)
-            return polymul_pim.pim_polymul(a, b, cfg, FP32).counters.cycles
-        if wl == "polymul-real":
-            a = rng.standard_normal((batch, n))
-            b = rng.standard_normal((batch, n))
-            return polymul_pim.pim_polymul_real(a, b, cfg,
-                                                FP32).counters.cycles
-        params = NTTParams.make(n)
-        a = rng.integers(0, params.q, n).astype(np.uint32)
-        b = rng.integers(0, params.q, n).astype(np.uint32)
-        return ntt_pim.pim_ntt_polymul(a, b, params, cfg,
-                                       INT32).counters.cycles
-
-    def sim_dist_cycles(wl, n, D):
-        """Measured side of ``cost.pim_dist_unit_cycles``: per-shard
-        transform cycles from the distributed sims; the polymul
-        compositions substitute the measured transform into the model's
-        own (analytic) glue, mirroring the closed forms exactly."""
-        if wl == "polymul-mod":
-            params = NTTParams.make(n)
-            x = rng.integers(0, params.q, n).astype(np.uint32)
-            ntt_meas = ntt_pim.pim_ntt_distributed(
-                x, params, D, cfg, INT32).latency_cycles
-            return 3 * ntt_meas + 4 * aritpim.mod_mul_cycles(INT32)
-        r = fft_pim.pim_rfft_distributed(rng.standard_normal(n),
-                                         rng.standard_normal(n),
-                                         D, cfg, FP32)
-        rfft_meas = max(c.cycles for c in r.shard_counters)
-        fft_meas = rfft_meas - unpack     # counter before the split charge
-        if wl == "fft":
-            return fft_meas
-        if wl == "rfft":
-            return rfft_meas
-        if wl == "polymul":
-            return 3 * fft_meas + aritpim.complex_mul_cycles(FP32)
-        assert wl == "polymul-real", wl
-        return (3 * fft_meas + 2 * unpack
-                + 2 * aritpim.complex_mul_cycles(FP32))
-
-    def traced_dist_bytes(wl, n, batch, D, real):
-        """Live ledger bytes of the actual sharded builder for one
-        distributed candidate, traced at the REAL shard count on an
-        AbstractMesh (no devices needed for a ``lower()`` trace, so the
-        single-CPU smoke can measure the D=64 tier it plans for)."""
-        mesh = compat.abstract_mesh((D,), ("model",))
-        if wl == "polymul-mod":
-            params = NTTParams.make(n)
-            build = dntt.make_sharded_ntt_polymul(
-                mesh, params, axis_name="model", batch_axes=())
-            spec = jax.ShapeDtypeStruct((batch, n), jnp.uint32)
-            args_ = (spec, spec)
-        elif wl == "rfft" and real:
-            build = dfft.make_sharded_rfft(mesh, batch_axes=())
-            args_ = (jax.ShapeDtypeStruct((batch, n), jnp.float32),)
-        elif wl == "polymul-real" and real:
-            build = dfft.make_sharded_polymul_real(mesh, batch_axes=())
-            spec = jax.ShapeDtypeStruct((batch, n), jnp.float32)
-            args_ = (spec, spec)
-        elif wl in ("polymul", "polymul-real"):
-            build = dfft.make_sharded_polymul(mesh, batch_axes=())
-            spec = jax.ShapeDtypeStruct((batch, n), jnp.complex64)
-            args_ = (spec, spec)
-        else:                      # fft, or the rfft complex fallback
-            build = dfft.make_sharded_fft(mesh, batch_axes=())
-            args_ = (jax.ShapeDtypeStruct((batch, n), jnp.complex64),)
-        with collectives.ledger() as led:
-            jax.jit(build).lower(*args_)
-        return (led.bytes_by_kind["all-to-all"]
-                + led.bytes_by_kind["ppermute"])
-
-    GRID = [
-        # (workload, n, batch, D). Local wins the small shapes at D=8;
-        # n=8192 = D*1024 additionally exercises the PIM four-step closed
-        # forms (the n1 = D cap is satisfied); at n=65536 over D=64 the
-        # aggregate bandwidth pays for the all-to-alls and the four-step
-        # tier must win the argmin.
-        ("fft", 4096, 8, 8), ("rfft", 4096, 8, 8),
-        ("polymul", 4096, 8, 8), ("polymul-real", 4096, 8, 8),
-        ("polymul-mod", 4096, 8, 8),
-        ("fft", 8192, 8, 8), ("rfft", 8192, 8, 8),
-        ("polymul-mod", 8192, 8, 8),
-        ("fft", 65536, 64, 64), ("rfft", 65536, 64, 64),
-        ("polymul-real", 65536, 64, 64), ("polymul-mod", 65536, 64, 64),
-    ]
-    points = []
-    agree = 0
-    for wl, n, batch, D in GRID:
-        p = plan(n, batch, workload=wl, model_shards=D)
-        best = p.cost["best"]
-        measured = []
-        for c in p.cost["candidates"]:
-            xla = c["backends"]["xla"]
-            if c["tier"] == "distributed":
-                mb = traced_dist_bytes(wl, n, batch, D, c["real"])
-                assert mb == xla["collective_bytes"], \
-                    (wl, n, D, c["real"], mb, xla["collective_bytes"])
-                t_cand = (max(xla["t_compute_s"], xla["t_memory_s"])
-                          + mb / cost_lib.LINK_BW)
-            else:
-                t_cand = xla["total_s"]
-            pim = c["backends"]["pim"]
-            if "infeasible" not in pim:
-                wl_pim = cost_lib._pim_workload(wl, c["real"])
-                if c["tier"] == "local":
-                    mc = sim_local_cycles(wl_pim, n, batch)
-                else:
-                    mc = sim_dist_cycles(wl_pim, n, D)
-                assert mc == pim["pim_cycles"], \
-                    (wl, n, D, c["tier"], c["real"], mc, pim["pim_cycles"])
-                # measured cycles through the model's own cycle->seconds
-                # conversion (linear in cycles, so this is substitution,
-                # not approximation)
-                t_pim = (pim["t_compute_s"] * (mc / pim["pim_cycles"])
-                         + pim["t_collective_s"])
-                t_cand = min(t_cand, t_pim)
-            measured.append((t_cand, c["tier"] != "local",
-                             not c["real"], c))
-        measured.sort(key=lambda m: m[:3])   # the planner's tie-break
-        m_best = measured[0][3]
-        ok = ((m_best["tier"], m_best["real"])
-              == (best["tier"], best["real"]))
-        agree += ok
-        points.append({"workload": wl, "n": n, "batch": batch, "D": D,
-                       "predicted": {"tier": best["tier"],
-                                     "real": best["real"],
-                                     "backend": best["backend_best"]},
-                       "measured_tier": m_best["tier"],
-                       "measured_real": m_best["real"],
-                       "agree": bool(ok)})
-        emit(f"smoke/auto_plan/{wl}/n={n}/D={D}", 0.0,
-             f"predicted={best['tier']};measured={m_best['tier']}"
-             f";backend={best['backend_best']};agree={bool(ok)}")
-
-    # A grid point with NO executable candidate must fail naming every
-    # pruning constraint, not with a bare error (the serve layer surfaces
-    # this message verbatim as a 400).
-    try:
-        plan(2 ** 20, 4, workload="fft", model_shards=3)
-    except ValueError as e:
-        msg = str(e)
-        assert "_MAX_LOCAL_N" in msg and "D^2 | n" in msg, msg
-    else:
-        raise AssertionError("plan() accepted an unexecutable grid point")
-
-    return {"op": "auto-plan-agreement", "grid_points": len(GRID),
-            "agreement": agree / len(GRID), "points": points}
-
-
 REAL_COMPLEX_CYCLE_GATE = 0.65  # per-product simulated-cycle ratio ceiling
 # Distributed real tier: total interconnect bytes (all-to-all + the
 # conjugate-bin ppermute) vs the complex distributed path, per product /
@@ -514,10 +311,9 @@ def bench_fourier_smoke(path: str = "BENCH_fourier.json") -> dict:
 
     # ABFT verified-mode overhead: for every checkable workload, the
     # simulated cycles of one integrity check (charged on a live sim —
-    # asserted equal to the closed form, so the planner prices exactly
-    # what the sim counts) over the batch=2 transform it verifies.
+    # asserted equal to its closed form) over the closed-form cycles of
+    # the unit it verifies (a batch of two for the real ops).
     # Deterministic, ratcheted, and absolutely gated at ABFT_OVERHEAD_GATE.
-    from repro.core import cost as cost_lib
     from repro.core.pim import INT32, CrossbarSim
     from repro.ft import abft
     abft_ratios = {}
@@ -527,9 +323,9 @@ def bench_fourier_smoke(path: str = "BENCH_fourier.json") -> dict:
             sim = CrossbarSim(FOURIERPIM_8, spec)
             abft.charge_check(sim, wl, n)
             check = sim.ctr.cycles
-            assert check == cost_lib.abft_check_cycles(wl, n), \
+            assert check == abft.check_cycles(wl, n, FOURIERPIM_8, spec), \
                 f"{wl}/n={n}: sim-charged check diverged from closed form"
-            base = cost_lib.pim_local_unit_cycles(wl, n, batch=2)
+            base = abft.unit_cycles(wl, n, FOURIERPIM_8, spec)
             abft_ratios[f"{wl}/n={n}"] = check / base
             emit(f"smoke/abft_overhead/{wl}/n={n}", 0.0,
                  f"ratio={check / base:.3f};gate<={ABFT_OVERHEAD_GATE}")
@@ -541,12 +337,6 @@ def bench_fourier_smoke(path: str = "BENCH_fourier.json") -> dict:
     # shortfall or oracle mismatch fails the smoke).
     serve_record = serve_engine_smoke()
     records.append(serve_record)
-
-    # Auto-tiering planner: predicted-best tier must equal the tier the
-    # measured quantities (sim counters + live ledger bytes) pick, on
-    # every grid point (docs/planner.md). The rate is ratcheted.
-    auto_record = auto_plan_agreement_smoke()
-    records.append(auto_record)
 
     # Invariant linter: zero findings over the tree, rule count ratcheted
     # (a dropped rule is a silently-unenforced contract).
@@ -564,7 +354,6 @@ def bench_fourier_smoke(path: str = "BENCH_fourier.json") -> dict:
     fresh = {"real_complex_cycle_ratio": ratios,
              "dist_real_complex_byte_ratio": dist_ratios,
              "abft_overhead_ratio": abft_ratios,
-             "auto_plan": auto_record,
              "static_analysis": static_analysis,
              "records": records}
     violations = trajectory.compare(baseline, fresh) if baseline else []
@@ -576,7 +365,6 @@ def bench_fourier_smoke(path: str = "BENCH_fourier.json") -> dict:
     # speedup is 1.5-2x; the deterministic regression gates are the ratio
     # gates above, so this only catches a grossly slower real path).
     wallclock_ok = us_real < 1.15 * us_cplx
-    auto_ok = auto_record["agreement"] == 1.0
     sa_ok = sa_record["findings"] == 0
     out = {
         "schema": "bench_fourier/v1",
@@ -585,7 +373,6 @@ def bench_fourier_smoke(path: str = "BENCH_fourier.json") -> dict:
         "real_complex_cycle_ratio": ratios,
         "dist_real_complex_byte_ratio": dist_ratios,
         "abft_overhead_ratio": abft_ratios,
-        "auto_plan": auto_record,
         "static_analysis": static_analysis,
         "serve": {"p50_ms": serve_record["serve_p50_ms"],
                   "p99_ms": serve_record["serve_p99_ms"],
@@ -599,13 +386,12 @@ def bench_fourier_smoke(path: str = "BENCH_fourier.json") -> dict:
                  "dist_byte_ratio_pass": bytes_ok,
                  "abft_overhead_pass": abft_ok,
                  "wallclock_pass": wallclock_ok,
-                 "auto_plan_agreement_pass": auto_ok,
                  "static_analysis_pass": sa_ok,
                  "ratchet_slack": trajectory.RATCHET_SLACK,
                  "trajectory_pass": not violations,
                  "trajectory_violations": violations,
                  "pass": (cycle_ok and bytes_ok and abft_ok
-                          and wallclock_ok and auto_ok and sa_ok
+                          and wallclock_ok and sa_ok
                           and not violations)},
     }
     out["history"] = trajectory.extend_history(baseline, out)
@@ -624,9 +410,6 @@ def bench_fourier_smoke(path: str = "BENCH_fourier.json") -> dict:
     assert wallclock_ok, \
         f"real path grossly slower than complex in interpret mode: " \
         f"{us_real:.0f}us vs {us_cplx:.0f}us"
-    assert auto_ok, \
-        "auto planner predicted-best tier disagrees with the measured " \
-        f"best on some grid point: {auto_record['points']}"
     assert sa_ok, \
         "invariant linter found contract violations:\n  " + \
         "\n  ".join(sa_record["messages"])

@@ -69,11 +69,6 @@ def deterministic_metrics(bench: dict) -> dict[str, tuple[float, str]]:
         # simulated ABFT check cycles / verified transform cycles: a rise
         # means integrity got more expensive relative to the work it guards
         out[f"abft_overhead_ratio/{key}"] = (float(v), "min")
-    ap = bench.get("auto_plan") or {}
-    if "agreement" in ap:
-        # predicted-vs-measured tier agreement of the auto planner:
-        # pinned at 1.0 — any drop is a cost-model rot, not noise.
-        out["auto_plan_agreement"] = (float(ap["agreement"]), "max")
     sa = bench.get("static_analysis") or {}
     if "rule_count" in sa:
         # active invariant-linter rules (repro.analysis): rules may be

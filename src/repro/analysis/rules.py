@@ -380,14 +380,14 @@ class DurableWriteRule(Rule):
 
 
 class BarePlanLiteralRule(Rule):
-    """Hand-built FFTPlan(...) literals outside planner.py/cost.py — PR 5:
+    """Hand-built FFTPlan(...) literals outside planner.py — PR 5:
     serve carried literal plans that silently skipped planner validation
     (the exact tier's shard checks among them); the fix routes every forced
     plan through ``plan(..., force_distributed=True)`` so the constraints
-    fire. Only the planner and the cost model may construct FFTPlan."""
+    fire. Only the planner may construct FFTPlan."""
 
     id = "bare-plan-literal"
-    _ALLOWED = ("repro/core/fft/planner.py", "repro/core/cost.py")
+    _ALLOWED = ("repro/core/fft/planner.py",)
 
     def check(self, ctx) -> Iterable[RawFinding]:
         if ctx.path.endswith(self._ALLOWED):

@@ -1,23 +1,32 @@
-"""Kernel/distribution planner for Fourier ops.
+"""Execution planner for the Fourier ops: the rules that pick a tier.
 
-Given (batch, n, mesh) decide:
-  * execution tier, one of three float tiers:
-      - ``local``: one VMEM-resident Pallas kernel per row (n up to
-        ``_MAX_LOCAL_N``), batch-sharded;
-      - ``hbm``: one device, n above that ceiling, real products only: the
-        four-step with the (n1, n2) matrix in HBM, column passes in HBM and
-        rows of n2 <= the ceiling through the local kernel
-        (``core.fft.hbm``);
-      - ``distributed``: the four-step FFT sequence-sharded over the model
-        axis (``model_shards > 1``);
-  * kernel config: batch block (VMEM budget), and the hbm tier's inner
-    length.
+``plan(n, batch, ...)`` returns the ``FFTPlan`` a route executes. The
+rules, in order (docs/planner.md is the same table, and
+``tests/test_routes.py`` pins it through the op registry):
 
-The decision mirrors the paper's configuration ladder (§4.3-4.5): the
-r/2r-configurations are "fits in one array" (-> our single-kernel tier, batch
-across crossbars ↔ batch across devices), the 2r-beta configuration is
-"sequence spans multiple column units" (-> our four-step tier across devices,
-with the all-to-all playing the role of the inter-unit column swaps).
+  * ``n`` must be a power of two and ``batch`` non-negative; ``exact``
+    and ``real`` are mutually exclusive.
+  * ``local``: one VMEM-resident Pallas kernel per row while n is at most
+    the tier's ceiling (``_MAX_LOCAL_N``, ``_MAX_LOCAL_N_REAL``,
+    ``_MAX_LOCAL_N_EXACT``, all 2^16) and ``force_distributed`` is off.
+    The batch block comes from the VMEM budget (doubled for real rows).
+  * ``hbm``: one device (``model_shards == 1``), a real plan above the
+    ceiling: the four-step real product with its (n1, n2) matrix in HBM
+    and rows of ``hbm_inner_n(n)`` through the local kernel
+    (``core.fft.hbm``; only polymul-real binds it).
+  * ``distributed``: ``model_shards > 1`` above the ceiling, or whenever
+    ``force_distributed`` asks for it: the four-step decomposition
+    sequence-sharded over the model axis, which needs D^2 | n.
+  * Anything else raises ``ValueError`` naming the constraint: a complex
+    or exact plan above the ceiling on one device, or a shard count that
+    does not tile n.
+
+The tiers mirror the paper's configuration ladder (§4.3-4.5): the
+r/2r-configurations are "fits in one array" (-> the single-kernel tier,
+batch across crossbars ↔ batch across devices), the 2r-beta configuration
+is "sequence spans multiple column units" (-> the four-step tier across
+devices, with the all-to-all playing the role of the inter-unit column
+swaps).
 """
 from __future__ import annotations
 
@@ -29,9 +38,6 @@ from repro.kernels.fft import plan_batch_block
 @dataclasses.dataclass(frozen=True)
 class FFTPlan:
     tier: str           # "local" | "hbm" | "distributed"
-    #: butterfly radix; only the distributed tiers' local XLA stages read
-    #: it (the local Pallas kernels are radix-free, docs/fourier.md)
-    radix: int
     block_b: int        # batch block per kernel invocation (local tier)
     seq_shards: int     # model-axis shards of the sequence (distributed tier)
     #: exact modular route: transforms dispatch to the NTT kernel
@@ -45,12 +51,6 @@ class FFTPlan:
     #: traffic of the complex tier on real input, with the doubled batch
     #: block the halved working set buys (docs/fourier.md).
     real: bool = False
-    #: machine-readable cost breakdown from ``core.cost.workload_cost``
-    #: when the plan was auto-chosen (``plan(..., workload=...)``); None
-    #: for explicit-knob plans. Excluded from eq/hash so auto plans and
-    #: hand-built plans with the same execution config compare equal
-    #: (the serve engine keys buckets on plans).
-    cost: dict | None = dataclasses.field(default=None, compare=False)
     #: hbm tier: the row length n2 of the inner VMEM kernel (n1 = n / n2)
     inner_n: int = 0
 
@@ -72,7 +72,7 @@ class FFTPlan:
                     f"{self.inner_n} through the local kernel at batch "
                     f"block {self.block_b}")
         return (f"four-step distributed {kind} over {self.seq_shards} "
-                f"devices, radix-{self.radix} local stages")
+                f"devices")
 
 
 # Local ceilings are compile facts for v5e (tests/test_tpu_compile.py
@@ -97,57 +97,19 @@ def hbm_inner_n(n: int) -> int:
     return min(_HBM_INNER_N, _MAX_LOCAL_N, n // _HBM_MIN_ROWS)
 
 
-def _hbm_plan(n: int, cost: dict | None = None) -> FFTPlan:
-    inner = hbm_inner_n(n)
-    return FFTPlan(tier="hbm", radix=4, block_b=plan_batch_block(inner),
-                   seq_shards=1, real=True, cost=cost, inner_n=inner)
-
-
 def plan(n: int, batch: int, *, model_shards: int = 1,
          exact: bool = False, real: bool = False,
-         force_distributed: bool = False,
-         workload: str | None = None,
-         verified: bool = False, pim_ok: bool = True) -> FFTPlan:
-    """Execution plan for a batch of n-point transforms.
+         force_distributed: bool = False) -> FFTPlan:
+    """Execution plan for a batch of n-point transforms (rules above).
 
-    ``exact=True`` routes to the modular-NTT tier (uint32 residues, radix-2
-    only — the Montgomery butterfly has no radix-4 shortcut worth the lane
-    pressure): the local Pallas kernel (``kernels.ntt``) while a sequence
-    fits VMEM, else the four-step distributed decomposition
-    (``core.ntt.distributed``) with per-shard roots
-    (``NTTParams.subparams``) and ledger-accounted all-to-alls — the plan
-    comes back with ``seq_shards > 1`` and ``exact=True``.
-    ``real=True`` routes real-coefficient workloads (the paper's polymul
-    serving case) to the half-spectrum tier: the rfft/irfft kernels and
-    the real ``polymul_real`` with the DOUBLED batch block
-    (``plan_batch_block(n, real=True)``) the halved per-row footprint buys.
-    The local-n ceiling matches the complex tier. Above it, on one device
-    (``model_shards == 1``), a real plan is the ``hbm`` tier: the
-    four-step real product with its (n1, n2) matrix in HBM
-    (``core.fft.hbm``; only polymul-real binds it). A complex plan above
-    the ceiling needs ``model_shards > 1``: with one device it raises.
-    Mutually exclusive with ``exact`` (residues are not packed).
-    ``force_distributed=True`` pins the distributed tier even where the
-    policy would keep the sequence local (serve's explicit --model-shards
-    request) — shape validation still applies, so the returned plan is
-    the one actually executable, not a hand-built record.
-    ``workload=`` switches to AUTO mode (docs/planner.md): the cost model
-    in ``core.cost`` scores every executable (tier, packing) candidate —
-    local, hbm or four-step; real or complex packing; PIM or XLA backend —
-    and the predicted-cheapest one comes back with the full breakdown on
-    ``FFTPlan.cost``. Explicit knobs still win: ``real=True`` pins the
-    packing, ``force_distributed=True`` pins the tier, and the legacy
-    no-workload call is untouched. A workload with no executable
-    candidate raises ValueError naming every pruned candidate's
-    constraint (VMEM ceiling, ``D^2 | n`` tiling, ``2*D^2 | n`` for the
-    ordered real tier) instead of a bare error.
-    ``verified=True`` (auto mode only) prices the ABFT integrity check
-    (``core.cost.abft_check_cycles``) into every candidate on both
-    backends; ``pim_ok=False`` plans with the PIM backend off the table —
-    the circuit-breaker re-bind of a quarantined serve bucket
-    (docs/fault_tolerance.md).
-    Raises ValueError on non-power-of-two n so misuse fails loudly instead
-    of silently mis-planning (asserts vanish under ``python -O``).
+    ``exact=True`` plans the modular NTT tier (uint32 residues): the local
+    Pallas kernel (``kernels.ntt``) or the distributed four-step NTT
+    (``core.ntt.distributed``) with per-shard roots. ``real=True`` plans
+    the half-spectrum tier with the doubled batch block
+    (``plan_batch_block(n, real=True)``). ``force_distributed=True`` pins
+    the distributed tier where the rules would keep the sequence local
+    (serving's ``--model-shards`` request); the shape checks still apply,
+    so the returned plan is the one that executes.
     """
     if n <= 0 or n & (n - 1):
         raise ValueError(f"n={n} must be a power of two")
@@ -158,135 +120,45 @@ def plan(n: int, batch: int, *, model_shards: int = 1,
                          "mutually exclusive")
     if force_distributed and model_shards == 1:
         raise ValueError("force_distributed needs model_shards > 1")
-    if workload is not None:
-        return _plan_auto(n, batch, workload, model_shards,
-                          exact=exact, real=real,
-                          force_distributed=force_distributed,
-                          verified=verified, pim_ok=pim_ok)
     if exact:
-        if not force_distributed and (n <= _MAX_LOCAL_N_EXACT
-                                      or model_shards == 1):
-            return FFTPlan(tier="local", radix=2,
-                           block_b=plan_batch_block(n), seq_shards=1,
-                           exact=True)
-        # The four-step NTT tiles identically to the float path (D^2 | n).
-        _check_dist_shape(n, model_shards, real=False)
-        return FFTPlan(tier="distributed", radix=2, block_b=1,
-                       seq_shards=model_shards, exact=True)
-    # Local tier: radix-4 halves the sweep count when n allows it. The
-    # DISTRIBUTED tiers run their local stages through the XLA Stockham
-    # (kops.fft, radix 2), so their plans record radix=2 — the plan
-    # describes what executes, not the local kernel's preference.
-    radix = 4 if (n.bit_length() - 1) >= 2 else 2
-    if real:
-        if not force_distributed and n <= _MAX_LOCAL_N_REAL:
-            return FFTPlan(tier="local", radix=radix,
-                           block_b=plan_batch_block(n, real=True),
-                           seq_shards=1, real=True)
-        if model_shards == 1:
-            return _hbm_plan(n)
-        # Distributed real tier: the four-step path runs the packed complex
-        # transform on z = a + i b per row PAIR with the Hermitian split
-        # performed per shard before the ordering all-to-all, so the
-        # half-spectrum crosses the interconnect at half the complex width
-        # (core/fft/distributed.py rfft_distributed; ~0.58x the complex
-        # tier's collective bytes — docs/fourier.md §distributed).
-        # Validated at the tier's common requirement (D^2 | n, the
-        # transposes + twiddle tiling); the ordered-rfft half-width
-        # all-to-all additionally needs 2*D^2 | n, enforced where it is an
-        # op property, not a tier property: check_four_step_shape(real=
-        # ordered) in the kernel-layer wrappers. polymul_real only needs
-        # D^2 | n, so the plan must not reject shapes it can execute.
-        _check_dist_shape(n, model_shards, real=False)
-        return FFTPlan(tier="distributed", radix=2, block_b=1,
-                       seq_shards=model_shards, real=True)
-    if not force_distributed and n <= _MAX_LOCAL_N:
-        return FFTPlan(tier="local", radix=radix,
-                       block_b=plan_batch_block(n), seq_shards=1)
+        ceiling, name = _MAX_LOCAL_N_EXACT, "_MAX_LOCAL_N_EXACT"
+    elif real:
+        ceiling, name = _MAX_LOCAL_N_REAL, "_MAX_LOCAL_N_REAL"
+    else:
+        ceiling, name = _MAX_LOCAL_N, "_MAX_LOCAL_N"
+    if not force_distributed and n <= ceiling:
+        return FFTPlan(tier="local",
+                       block_b=plan_batch_block(n, real=real),
+                       seq_shards=1, exact=exact, real=real)
     if model_shards == 1:
+        if real:
+            inner = hbm_inner_n(n)
+            return FFTPlan(tier="hbm", block_b=plan_batch_block(inner),
+                           seq_shards=1, real=True, inner_n=inner)
+        kind = "an exact" if exact else "a complex"
         raise ValueError(
-            f"no single-device tier for a complex transform of n={n}: it "
-            f"exceeds the VMEM-resident ceiling _MAX_LOCAL_N={_MAX_LOCAL_N}"
-            f" and the hbm tier serves real products only; pass "
-            f"model_shards > 1")
-    _check_dist_shape(n, model_shards, real=False)
-    return FFTPlan(tier="distributed", radix=2, block_b=1,
-                   seq_shards=model_shards)
+            f"no single-device tier for {kind} transform of n={n}: it "
+            f"exceeds the VMEM-resident ceiling {name}={ceiling} and the "
+            f"hbm tier serves real products only; pass model_shards > 1")
+    # The four-step tiles D^2 | n for every kind. The distributed real
+    # tier's ordered rfft additionally needs 2*D^2 | n for its half-width
+    # all-to-all; that is an op property, enforced where the op runs
+    # (check_four_step_shape(real=ordered) in the kernel-layer wrappers),
+    # since polymul_real only needs D^2 | n.
+    _check_dist_shape(n, model_shards)
+    return FFTPlan(tier="distributed", block_b=1, seq_shards=model_shards,
+                   exact=exact, real=real)
 
 
-def _plan_auto(n: int, batch: int, workload: str, model_shards: int, *,
-               exact: bool, real: bool, force_distributed: bool,
-               verified: bool = False, pim_ok: bool = True) -> FFTPlan:
-    """Cost-model-driven tier choice (docs/planner.md).
-
-    The candidate space is every (tier, packing) pair the XLA kernels can
-    execute for ``workload``; ``core.cost.workload_cost`` scores each on
-    both backends and this returns the predicted-cheapest as a normal
-    executable ``FFTPlan`` with the breakdown attached. Explicit knobs
-    narrow the space rather than being ignored: ``real=True`` keeps only
-    real-packed candidates, ``force_distributed=True`` only distributed
-    ones. ``exact=`` must agree with the workload — the modular route is
-    a workload property (``polymul-mod``), not a packing choice.
-    """
-    from repro.core.cost import WORKLOADS, workload_cost
-    if workload not in WORKLOADS:
-        raise ValueError(f"unknown workload {workload!r}; "
-                         f"expected one of {WORKLOADS}")
-    wl_exact = workload == "polymul-mod"
-    if exact and not wl_exact:
-        raise ValueError(f"exact=True conflicts with workload="
-                         f"{workload!r}: the exact mod-q route is the "
-                         f"'polymul-mod' workload")
-    if real and workload not in ("rfft", "polymul-real"):
-        raise ValueError(f"real=True conflicts with workload="
-                         f"{workload!r}: only 'rfft'/'polymul-real' "
-                         f"have a real-packed route")
-    tiers = (("distributed",) if force_distributed
-             else ("local", "distributed", "hbm"))
-    packings = [True] if real else None
-    breakdown = workload_cost(workload, n, batch, n_devices=model_shards,
-                              tiers=tiers, packings=packings,
-                              verified=verified, pim_ok=pim_ok)
-    best = breakdown["best"]
-    if best is None:
-        lines = [
-            f"  - tier={p['tier']}"
-            f"{', real-packed' if p['real'] else ''}: {p['reason']}"
-            for p in breakdown["pruned"]]
-        raise ValueError(
-            f"no executable tier for workload={workload!r} n={n} over "
-            f"{model_shards} shard(s); every candidate was pruned:\n"
-            + "\n".join(lines))
-    if best["tier"] == "local":
-        radix = (2 if wl_exact
-                 else (4 if (n.bit_length() - 1) >= 2 else 2))
-        block = (plan_batch_block(n, real=True) if best["real"]
-                 else plan_batch_block(n))
-        return FFTPlan(tier="local", radix=radix, block_b=block,
-                       seq_shards=1, exact=wl_exact, real=best["real"],
-                       cost=breakdown)
-    if best["tier"] == "hbm":
-        return _hbm_plan(n, cost=breakdown)
-    return FFTPlan(tier="distributed", radix=2, block_b=1,
-                   seq_shards=model_shards, exact=wl_exact,
-                   real=best["real"], cost=breakdown)
-
-
-def _check_dist_shape(n: int, model_shards: int, *, real: bool) -> None:
-    """Reject shapes the four-step decomposition cannot tile.
-
-    The distributed tier needs D^2 | n (2*D^2 | n for the real tier's
-    half-width ordering all-to-all). Such a shape cannot be re-tiered
-    locally either — the planner only reaches here when n exceeds the
-    local VMEM ceiling — so mis-sized shard counts fail at plan time
-    instead of surfacing as truncated twiddle blocks mid-trace
+def _check_dist_shape(n: int, model_shards: int) -> None:
+    """Reject shapes the four-step decomposition cannot tile (D^2 | n),
+    at plan time, instead of as truncated twiddle blocks mid-trace
     (``core.fft.distributed.check_four_step_shape`` is the same guard at
-    the kernel layer).
-    """
+    the kernel layer)."""
     from repro.core.fft.distributed import check_four_step_shape
     try:
-        check_four_step_shape(n, model_shards, real=real)
+        check_four_step_shape(n, model_shards)
     except ValueError as e:
         raise ValueError(
-            f"cannot plan a distributed {'real ' if real else ''}FFT for "
-            f"n={n} over {model_shards} shards: {e}") from e
+            f"cannot plan a distributed FFT for n={n} over {model_shards} "
+            f"shards: {e}") from e

@@ -45,9 +45,9 @@ from ONE schedule (:func:`_schedule`) — the column-parallel layout: the
 per-element multiplies are vectored column ops over the resident rows, the
 sum is a log-depth reduction tree, never a serial Horner sweep (a serial
 eval would cost ~n modmuls and dwarf the transform it is checking).
-``core.cost.abft_check_cycles`` re-exports the closed form so
-``plan(..., verified=True)`` prices the overhead, and the
-counter-parity gate (tests/test_abft.py) pins charged == closed-form.
+The counter-parity gate (tests/test_abft.py) pins charged == closed-form,
+and the overhead gate holds the check under a quarter of the closed-form
+cycles of the unit it verifies (:func:`unit_cycles`).
 """
 from __future__ import annotations
 
@@ -324,6 +324,20 @@ def check_cycles(workload: str, n: int, cfg: PIMConfig, spec) -> int:
         else:                            # ("twiddle", count)
             total += entry[1]
     return total
+
+
+def unit_cycles(workload: str, n: int, cfg: PIMConfig, spec) -> int:
+    """Closed-form latency cycles (``core.pim``) of the unit one check
+    verifies: one transform (fft), one packed run of two real rows (rfft),
+    one product (polymul, polymul-mod), a pair of real products sharing
+    their inverse (polymul-real)."""
+    from repro.core.pim import fft_pim, ntt_pim, polymul_pim
+    forms = {"fft": fft_pim.fft_latency_cycles,
+             "rfft": fft_pim.rfft_latency_cycles,
+             "polymul": polymul_pim.polymul_latency_cycles,
+             "polymul-real": polymul_pim.polymul_real_pair_latency_cycles,
+             "polymul-mod": ntt_pim.ntt_polymul_latency_cycles}
+    return forms[workload](n, cfg, spec)
 
 
 def charge_check(sim, workload: str, n: int) -> None:

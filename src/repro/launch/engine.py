@@ -51,13 +51,12 @@ documents (checkpoint -> resize -> restore).
 Integrity (``verified=True``): every deliverable batch passes its op's
 ABFT check (``ft/abft.py``) before any client sees a result. A failed
 check triggers bounded re-execution with exponential backoff; when the
-retry budget is exhausted the bucket's circuit breaker trips — it
-re-binds on a ``pim_ok=False`` context (cost model plans the PIM backend
-as infeasible), the simulated crossbar array behind it is quarantined to
-a spare, and the batch re-runs on the clean route. ``--inject-faults``
-chaos testing drives this path deterministically via a seeded
-:class:`~repro.core.pim.FaultModel` whose per-bucket injectors corrupt
-delivered rows. Per-request deadlines (``submit(..., deadline_s=...)``)
+retry budget is exhausted the bucket's circuit breaker trips — the
+bucket's fault injector is unwrapped, the simulated crossbar array behind
+it is quarantined to a spare, and the batch re-runs on the clean route.
+``--inject-faults`` chaos testing drives this path deterministically via
+a seeded :class:`~repro.core.pim.FaultModel` whose per-bucket injectors
+corrupt delivered rows. Per-request deadlines (``submit(..., deadline_s=...)``)
 complete expired requests with a structured timeout error instead of a
 result; expired requests never enter the latency record.
 """
@@ -118,8 +117,7 @@ class _BucketStats:
     batches: int = 0
     batch_sizes: list = dataclasses.field(default_factory=list)
     # accumulated dispatch -> materialized seconds of this bucket's
-    # batches: the OBSERVED side of the cost model's predicted-vs-observed
-    # comparison (docs/planner.md)
+    # batches
     service_s: float = 0.0
     # deadline-expired requests swept from this bucket's queue
     expired: int = 0
@@ -200,7 +198,6 @@ class ServeEngine:
 
     def __init__(self, *, max_batch: int = 64, max_pending: int = 1024,
                  modulus_bits: int | None = None, model_shards: int = 1,
-                 auto: bool = False,
                  collect_timeout_s: float = 0.05,
                  watchdog_cfg: Optional[WatchdogConfig] = None,
                  on_evict: Optional[Callable[["ServeEngine", int], None]]
@@ -222,14 +219,13 @@ class ServeEngine:
         self.max_batch = max_batch
         self.max_pending = max_pending
         self.collect_timeout_s = collect_timeout_s
-        # auto=True: each bucket's bind lets the cost model pick the tier
-        # and packing (plan(workload=...)); explicit-knob binding otherwise.
         self.ctx = op_registry.OpContext(modulus_bits=modulus_bits,
                                          model_shards=model_shards,
-                                         auto=auto, verified=verified)
+                                         verified=verified)
         # ABFT recovery knobs (docs/fault_tolerance.md): detected
         # corruption -> up to retry_cap re-executions with exponential
-        # backoff -> circuit breaker (XLA re-bind + array quarantine).
+        # backoff -> circuit breaker (injector unwrapped + array
+        # quarantine).
         self.verified = verified
         self.fault_model = fault_model
         self.retry_cap = retry_cap
@@ -290,8 +286,7 @@ class ServeEngine:
         with self._bind_lock:
             if key not in self._bound:
                 spec = op_registry.get_op(op)
-                bound = spec.bind(n, self.ctx, batch=self.max_batch,
-                                  strict=strict)
+                bound = spec.bind(n, self.ctx, strict=strict)
                 if self.fault_model is not None:
                     # one virtual crossbar array per bucket, assigned
                     # round-robin over the model's array space
@@ -441,9 +436,9 @@ class ServeEngine:
         """ABFT gate for one deliverable batch: check, then on detected
         corruption re-execute up to ``retry_cap`` times with exponential
         backoff; exhausted retries trip the bucket's circuit breaker
-        (XLA re-bind + array quarantine) and re-run once on the clean
-        route. Raises RuntimeError only if even the fallback route fails
-        its check — no corrupted batch is ever delivered."""
+        (injector unwrapped + array quarantine) and re-run once on the
+        clean route. Raises RuntimeError only if even the fallback route
+        fails its check — no corrupted batch is ever delivered."""
         bound = self._bound[key]
         payloads = [r.payload for r in reqs]
         bs = self._bucket_stats[key]
@@ -475,26 +470,20 @@ class ServeEngine:
         return arr
 
     def _trip_breaker(self, key: tuple[str, int]):
-        """Open the bucket's circuit breaker: re-bind on a ``pim_ok=
-        False`` context (the cost model marks the PIM backend infeasible
-        for this bucket from now on) and quarantine the bucket's
-        simulated array to a spare. The new bound is CLEAN — no fault
-        injector wraps it."""
-        op, n = key
+        """Open the bucket's circuit breaker: restore the bucket's
+        unwrapped ``fn``, so no fault injector runs for it from now on,
+        and quarantine its simulated array to a spare."""
+        bound = self._bound[key]
         with self._bind_lock:
-            ctx = dataclasses.replace(self.ctx, pim_ok=False)
-            spec = op_registry.get_op(op)
-            bound = spec.bind(n, ctx, batch=self.max_batch,
-                              strict=self._strict[key])
-            self._bound[key] = bound
             self._breaker_open.add(key)
             inj = self._injectors.pop(key, None)
-            if inj is not None and self.fault_model is not None:
+            if inj is not None:
+                bound.fn = inj.inner
                 from repro.core.pim.faults import SparesExhausted
                 try:
                     self.fault_model.quarantine(inj.array_id)
                 except SparesExhausted:
-                    pass    # breaker still isolates the bucket via re-bind
+                    pass    # the unwrapped fn still isolates the bucket
         return bound
 
     def _resolve(self, key: tuple[str, int], reqs: list[_Request],
@@ -601,13 +590,12 @@ class ServeEngine:
         for key, bs in self._bucket_stats.items():
             op, n = key
             sizes = bs.batch_sizes
-            bound = self._bound[key]
-            entry = {
+            buckets[f"{op}/n={n}"] = {
                 "op": op, "n": n, "served": bs.served,
                 "lifetime_served": (self._prev_bucket_served.get(
                     f"{op}/{n}", 0) + bs.served),
                 "batches": bs.batches,
-                "route": bound.route,
+                "route": self._bound[key].route,
                 "max_block": self.max_batch,
                 "mean_batch": (sum(sizes) / len(sizes)) if sizes else 0.0,
                 # fill of the continuous-batching block: 1.0 means every
@@ -630,15 +618,6 @@ class ServeEngine:
                     "breaker_open": key in self._breaker_open,
                 },
             }
-            cost = getattr(bound.plan, "cost", None)
-            if cost is not None and cost.get("best") is not None:
-                best = cost["best"]
-                # the bind-time batch hint the plan was costed at
-                per = max(1, cost.get("batch") or 1)
-                entry["predicted_s_per_req"] = best["total_s"] / per
-                entry["predicted_tier"] = best["tier"]
-                entry["predicted_backend"] = best["backend_best"]
-            buckets[f"{op}/n={n}"] = entry
         batches = sum(b.batches for b in self._bucket_stats.values())
         recorded = self.telemetry.snapshot()
         spans, counters = recorded["spans"], recorded["counters"]
@@ -720,7 +699,6 @@ class ServeEngine:
                        "collect_timeout_s": self.collect_timeout_s,
                        "modulus_bits": self.ctx.modulus_bits,
                        "model_shards": self.ctx.model_shards,
-                       "auto": self.ctx.auto,
                        "verified": self.verified},
             "buckets": [{"op": op, "n": n, "strict": self._strict[(op, n)]}
                         for op, n in self._bound],
@@ -774,7 +752,6 @@ class ServeEngine:
             modulus_bits=eng_cfg["modulus_bits"],
             model_shards=(eng_cfg["model_shards"] if model_shards is None
                           else model_shards),
-            auto=bool(eng_cfg.get("auto", False)),
             verified=bool(eng_cfg.get("verified", False)),
             watchdog_cfg=watchdog_cfg, on_evict=on_evict)
         for b in extra["buckets"]:

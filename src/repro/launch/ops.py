@@ -14,14 +14,16 @@ The OpSpec contract (docs/serving.md):
 
   * ``arity``            — payload operands per request (1 = transform,
                            2 = product); the engine stacks them host-side.
-  * ``bind(n, ctx)``     — validate the ``(op, n, modulus_bits,
-                           model_shards)`` combination (raising
-                           :class:`OpConfigError`, a ``ValueError``
-                           subclass, with the registry's own message — no
-                           deep ``ValueError`` from three layers down) and
-                           build the executable route: planner plan,
-                           route tag, jitted batch fn, and any NTT/RNS
-                           params or mesh the route needs.
+  * ``route(n, ctx)``    — the op's one route decision: validate the
+                           ``(op, n, modulus_bits, model_shards)``
+                           combination (raising :class:`OpConfigError`, a
+                           ``ValueError`` subclass, with the registry's own
+                           message — no deep ``ValueError`` from three
+                           layers down) and return ``(plan, route)``,
+                           building no mesh and no jit.
+  * ``bind(n, ctx)``     — the route decision, then the executable route:
+                           jitted batch fn, and any NTT/RNS params or mesh
+                           the route needs.
   * ``warmup`` payload   — zeros of the route's payload dtype, so deploy
                            warmup compiles the steady-state shape.
   * ``random_payload``   — the honest traffic generator (complex payloads
@@ -56,27 +58,13 @@ class OpContext:
     """Process-level route parameterization shared by every op of a serve
     process (CLI flags); ``OpSpec.narrow`` strips the knobs an op ignores.
 
-    ``auto=True`` hands tier/packing choice to the cost model
-    (docs/planner.md): ``model_shards`` becomes the AVAILABLE device
-    count rather than a demand, and each bind asks
-    ``plan(n, batch, workload=<op name>, ...)`` for the predicted-cheapest
-    executable route. Strict knob validation is unchanged — knobs an op
-    cannot consume are still rejected, auto only picks among routes the
-    op really has.
-
-    ``verified=True`` binds the route with ABFT integrity pricing
-    (docs/fault_tolerance.md): auto plans carry the check overhead in
-    their cost breakdown, and the RNS route proves its modulus is
-    checkable (factors over the limb primes) at bind time. ``pim_ok=
-    False`` is the circuit-breaker context: the cost model plans with
-    the PIM backend marked infeasible, the re-bind a serve bucket gets
-    after its (simulated) crossbar array is quarantined. Both apply to
-    every op, so ``narrow`` preserves them."""
+    ``verified=True`` binds the route for ABFT-checked serving
+    (docs/fault_tolerance.md): the RNS route proves at bind time that its
+    modulus is checkable (factors over the limb primes). It applies to
+    every op, so ``narrow`` preserves it."""
     modulus_bits: int | None = None
     model_shards: int = 1
-    auto: bool = False
     verified: bool = False
-    pim_ok: bool = True
 
 
 @dataclasses.dataclass
@@ -196,8 +184,9 @@ class OpSpec:
     summary: str
     uses_modulus_bits: bool
     uses_model_shards: bool
-    _validate: Callable[["OpSpec", int, OpContext], None]
-    _bind: Callable[["OpSpec", int, OpContext, int], BoundOp]
+    #: the op's route decision: ``(spec, n, ctx) -> (plan, route)``
+    _route: Callable[["OpSpec", int, OpContext], tuple[Any, str]]
+    _bind: Callable[["OpSpec", int, OpContext, Any, str], BoundOp]
     warmup_payload: Callable[[BoundOp, int], tuple]
     random_payload: Callable[[BoundOp, np.random.Generator], Any]
     verify: Callable[[BoundOp, Any, np.ndarray], float]
@@ -207,8 +196,11 @@ class OpSpec:
     #: verified serve engine runs before delivering any result.
     integrity: Callable[[BoundOp, Sequence[Any], np.ndarray], Any] = None
 
-    def validate(self, n: int, ctx: OpContext = OpContext()) -> None:
-        """Raise :class:`OpConfigError` unless (n, ctx) is serveable."""
+    def route(self, n: int, ctx: OpContext = OpContext()
+              ) -> tuple[Any, str]:
+        """The plan and route tag (n, ctx) binds to; raises
+        :class:`OpConfigError` unless (n, ctx) is serveable. Pure: it
+        builds no mesh and no jit."""
         if ctx.modulus_bits is not None and not self.uses_modulus_bits:
             raise OpConfigError(
                 f"--modulus-bits applies to "
@@ -219,7 +211,11 @@ class OpSpec:
                 f"--model-shards applies to "
                 f"{', '.join(ops_using('model_shards'))}; "
                 f"op {self.name!r} has no distributed route")
-        self._validate(self, n, ctx)
+        return self._route(self, n, ctx)
+
+    def validate(self, n: int, ctx: OpContext = OpContext()) -> None:
+        """Raise :class:`OpConfigError` unless (n, ctx) is serveable."""
+        self.route(n, ctx)
 
     def narrow(self, ctx: OpContext) -> OpContext:
         """Strip the knobs this op ignores — the mixed engine resolves one
@@ -227,11 +223,11 @@ class OpSpec:
         return OpContext(
             modulus_bits=ctx.modulus_bits if self.uses_modulus_bits else None,
             model_shards=ctx.model_shards if self.uses_model_shards else 1,
-            auto=ctx.auto, verified=ctx.verified, pim_ok=ctx.pim_ok)
+            verified=ctx.verified)
 
     def bind(self, n: int, ctx: OpContext = OpContext(), *,
-             batch: int = 0, strict: bool = True) -> BoundOp:
-        """Validate and resolve the executable route.
+             strict: bool = True) -> BoundOp:
+        """Decide the route and build it.
 
         ``strict=True`` (the single-op CLI path) rejects knobs this op
         does not consume; ``strict=False`` narrows them away first (the
@@ -239,8 +235,8 @@ class OpSpec:
         """
         if not strict:
             ctx = self.narrow(ctx)
-        self.validate(n, ctx)
-        return self._bind(self, n, ctx, batch)
+        plan, route = self.route(n, ctx)
+        return self._bind(self, n, ctx, plan, route)
 
 
 _REGISTRY: dict[str, OpSpec] = {}
@@ -391,16 +387,16 @@ def _as_words(planes: Callable[..., tuple]) -> dict:
     return {"fn": jax.jit(words), "result_dtype": np.complex64}
 
 
-def _no_dist_route(spec: OpSpec, n: int, ctx: OpContext) -> None:
-    pass
-
-
-def _plan_or_config_error(**kw):
-    """Run the planner, lifting its ValueError into the registry's own
-    error type so callers see one failure surface."""
+def _plan(n: int, ctx: OpContext, **kind):
+    """Run the planner for (n, ctx), lifting its ValueError into the
+    registry's own error type so callers see one failure surface.
+    ``model_shards > 1`` demands the distributed tier (``force_distributed``);
+    the ops without a distributed route never get here with it."""
     from repro.core import fft as fft_core
+    if ctx.model_shards > 1:
+        kind.update(model_shards=ctx.model_shards, force_distributed=True)
     try:
-        return fft_core.plan(**kw)
+        return fft_core.plan(n, 0, **kind)
     except ValueError as e:
         raise OpConfigError(str(e)) from e
 
@@ -409,15 +405,14 @@ def _plan_or_config_error(**kw):
 # fft — complex transform endpoint
 # ---------------------------------------------------------------------------
 
-def _bind_fft(spec: OpSpec, n: int, ctx: OpContext, batch: int) -> BoundOp:
+def _route_fft(spec: OpSpec, n: int, ctx: OpContext) -> tuple[Any, str]:
+    return _plan(n, ctx), "fft"
+
+
+def _bind_fft(spec: OpSpec, n: int, ctx: OpContext, plan,
+              route: str) -> BoundOp:
     from repro.core import fft as fft_core
-    if ctx.auto:
-        plan = _plan_or_config_error(n=n, batch=batch, workload="fft",
-                                     verified=ctx.verified,
-                                     pim_ok=ctx.pim_ok)
-    else:
-        plan = _plan_or_config_error(n=n, batch=batch)
-    return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan, route="fft",
+    return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan, route=route,
                    payload_dtype=np.complex64,
                    **_as_words(lambda x: fft_core.fft_planes(x)))
 
@@ -426,7 +421,7 @@ register_op(
     name="fft", arity=1,
     summary="batched complex FFT (local Pallas/XLA tier)",
     uses_modulus_bits=False, uses_model_shards=False,
-    _validate=_no_dist_route, _bind=_bind_fft,
+    _route=_route_fft, _bind=_bind_fft,
     warmup_payload=_zeros,
     random_payload=lambda b, rng: _cnormal(rng, b.n),
     verify=functools.partial(_float_verify, np.fft.fft, 1e-3),
@@ -438,30 +433,19 @@ register_op(
 # rfft — real-Hermitian half-spectrum endpoint (half-spectrum kernel)
 # ---------------------------------------------------------------------------
 
-def _bind_rfft(spec: OpSpec, n: int, ctx: OpContext, batch: int) -> BoundOp:
-    import jax.numpy as jnp
-    from repro.core import fft as fft_core
-    if ctx.auto:
-        plan = _plan_or_config_error(n=n, batch=batch, workload="rfft",
-                                     verified=ctx.verified,
-                                     pim_ok=ctx.pim_ok)
-        if not plan.real:
-            # Cost model preferred complex packing (only reachable where
-            # the real route is pruned): cast up, full transform, keep
-            # the half spectrum — same payload/result contract.
-            return BoundOp(
-                spec=spec, n=n, ctx=ctx, plan=plan, route="rfft-complex",
-                payload_dtype=np.float32,
-                **_as_words(lambda x: tuple(
-                    p[..., :n // 2 + 1] for p in
-                    fft_core.fft_planes(x.astype(jnp.complex64)))))
-    else:
-        plan = _plan_or_config_error(n=n, batch=batch, real=True)
+def _route_rfft(spec: OpSpec, n: int, ctx: OpContext) -> tuple[Any, str]:
+    plan = _plan(n, ctx, real=True)
     if plan.tier == "hbm":
         raise OpConfigError(
             f"rfft at n={n} exceeds the VMEM-resident ceiling on one "
             f"device; the HBM-resident tier serves polymul-real only")
-    return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan, route="rfft-real",
+    return plan, "rfft-real"
+
+
+def _bind_rfft(spec: OpSpec, n: int, ctx: OpContext, plan,
+               route: str) -> BoundOp:
+    from repro.core import fft as fft_core
+    return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan, route=route,
                    payload_dtype=np.float32,
                    **_as_words(lambda x: fft_core.rfft_planes(x)))
 
@@ -470,7 +454,7 @@ register_op(
     name="rfft", arity=1,
     summary="real-input half-spectrum FFT (Hermitian symmetry)",
     uses_modulus_bits=False, uses_model_shards=False,
-    _validate=_no_dist_route, _bind=_bind_rfft,
+    _route=_route_rfft, _bind=_bind_rfft,
     warmup_payload=_zeros,
     random_payload=lambda b, rng: rng.standard_normal(b.n).astype(np.float32),
     verify=functools.partial(_float_verify, np.fft.rfft, 1e-3),
@@ -482,17 +466,16 @@ register_op(
 # polymul — complex circular product (three-transform path)
 # ---------------------------------------------------------------------------
 
-def _bind_polymul(spec: OpSpec, n: int, ctx: OpContext, batch: int) -> BoundOp:
+def _route_polymul(spec: OpSpec, n: int, ctx: OpContext) -> tuple[Any, str]:
+    return _plan(n, ctx), "polymul"
+
+
+def _bind_polymul(spec: OpSpec, n: int, ctx: OpContext, plan,
+                  route: str) -> BoundOp:
     import jax.numpy as jnp
     from repro.core import fft as fft_core
-    if ctx.auto:
-        plan = _plan_or_config_error(n=n, batch=batch, workload="polymul",
-                                     verified=ctx.verified,
-                                     pim_ok=ctx.pim_ok)
-    else:
-        plan = _plan_or_config_error(n=n, batch=batch)
     return BoundOp(
-        spec=spec, n=n, ctx=ctx, plan=plan, route="polymul",
+        spec=spec, n=n, ctx=ctx, plan=plan, route=route,
         payload_dtype=np.complex64,
         **_as_words(lambda a, b: fft_core.polymul_planes(
             a.astype(jnp.complex64), b.astype(jnp.complex64),
@@ -503,7 +486,7 @@ register_op(
     name="polymul", arity=2,
     summary="complex circular polynomial product (convolution theorem)",
     uses_modulus_bits=False, uses_model_shards=False,
-    _validate=_no_dist_route, _bind=_bind_polymul,
+    _route=_route_polymul, _bind=_bind_polymul,
     warmup_payload=_zeros,
     random_payload=lambda b, rng: (_cnormal(rng, b.n), _cnormal(rng, b.n)),
     verify=functools.partial(_float_verify, _circular_complex, 1e-3),
@@ -516,6 +499,17 @@ register_op(
 # HBM-resident four-step on one device, the distributed four-step with
 # model_shards > 1 (odd batches padded internally, docs/fourier.md)
 # ---------------------------------------------------------------------------
+
+_POLYMUL_REAL_ROUTES = {"local": "polymul-real-packed",
+                        "hbm": "polymul-real-hbm",
+                        "distributed": "polymul-real-distributed"}
+
+
+def _route_polymul_real(spec: OpSpec, n: int,
+                        ctx: OpContext) -> tuple[Any, str]:
+    plan = _plan(n, ctx, real=True)
+    return plan, _POLYMUL_REAL_ROUTES[plan.tier]
+
 
 def _hbm_route(plan) -> Callable[..., Any]:
     """The hbm tier's batch fn: ``core.fft.hbm.polymul_real_hbm`` at the
@@ -530,63 +524,22 @@ def _hbm_route(plan) -> Callable[..., Any]:
     return fn
 
 
-def _validate_polymul_real(spec: OpSpec, n: int, ctx: OpContext) -> None:
-    if ctx.auto:
-        # Auto mode: model_shards is the AVAILABLE device count; the
-        # chooser may keep the sequence local, so only fail when no
-        # candidate at all is executable (the planner's pruned-list
-        # error names each constraint).
-        _plan_or_config_error(n=n, batch=0, workload="polymul-real",
-                              model_shards=ctx.model_shards,
-                              verified=ctx.verified, pim_ok=ctx.pim_ok)
-    elif ctx.model_shards > 1:
-        _plan_or_config_error(n=n, batch=0, real=True,
-                              model_shards=ctx.model_shards,
-                              force_distributed=True)
-
-
-def _bind_polymul_real(spec: OpSpec, n: int, ctx: OpContext,
-                       batch: int) -> BoundOp:
+def _bind_polymul_real(spec: OpSpec, n: int, ctx: OpContext, plan,
+                       route: str) -> BoundOp:
     import jax
-    import jax.numpy as jnp
     from repro.core import fft as fft_core
-    if ctx.auto:
-        plan = _plan_or_config_error(n=n, batch=batch,
-                                     workload="polymul-real",
-                                     model_shards=ctx.model_shards,
-                                     verified=ctx.verified,
-                                     pim_ok=ctx.pim_ok)
-    elif ctx.model_shards > 1:
-        plan = _plan_or_config_error(n=n, batch=batch, real=True,
-                                     model_shards=ctx.model_shards,
-                                     force_distributed=True)
-    else:
-        plan = _plan_or_config_error(n=n, batch=batch, real=True)
-    if plan.tier == "distributed":
+    if route == "polymul-real-distributed":
         from repro.core.fft import distributed as dfft
         mesh = jax.make_mesh((ctx.model_shards,), ("model",))
         return BoundOp(
-            spec=spec, n=n, ctx=ctx, plan=plan,
-            route="polymul-real-distributed",
+            spec=spec, n=n, ctx=ctx, plan=plan, route=route,
             fn=jax.jit(dfft.make_sharded_polymul_real(mesh, batch_axes=())),
             payload_dtype=np.float32, mesh=mesh)
-    if plan.tier == "hbm":
-        return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan,
-                       route="polymul-real-hbm", fn=_hbm_route(plan),
-                       payload_dtype=np.float32)
-    if not plan.real:
-        # Complex-packing fallback (auto only): full-width product on
-        # cast-up operands, real part back — the route the cost model
-        # priced as the "complex" packing candidate.
-        return BoundOp(
-            spec=spec, n=n, ctx=ctx, plan=plan,
-            route="polymul-real-complex",
-            fn=jax.jit(lambda a, b: fft_core.polymul(
-                a.astype(jnp.complex64), b.astype(jnp.complex64),
-                mode="circular").real),
-            payload_dtype=np.float32)
+    if route == "polymul-real-hbm":
+        return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan, route=route,
+                       fn=_hbm_route(plan), payload_dtype=np.float32)
     return BoundOp(
-        spec=spec, n=n, ctx=ctx, plan=plan, route="polymul-real-packed",
+        spec=spec, n=n, ctx=ctx, plan=plan, route=route,
         fn=jax.jit(lambda a, b: fft_core.polymul_real(a, b,
                                                       mode="circular")),
         payload_dtype=np.float32)
@@ -598,7 +551,7 @@ register_op(
             "path; above the VMEM ceiling the HBM-resident four-step on one "
             "device; --model-shards > 1 runs the distributed four-step tier",
     uses_modulus_bits=False, uses_model_shards=True,
-    _validate=_validate_polymul_real, _bind=_bind_polymul_real,
+    _route=_route_polymul_real, _bind=_bind_polymul_real,
     warmup_payload=_zeros,
     random_payload=lambda b, rng: (
         rng.standard_normal(b.n).astype(np.float32),
@@ -614,26 +567,18 @@ register_op(
 # distributed four-step NTT (model_shards > 1, single-limb only)
 # ---------------------------------------------------------------------------
 
-def _validate_polymul_mod(spec: OpSpec, n: int, ctx: OpContext) -> None:
+def _route_polymul_mod(spec: OpSpec, n: int,
+                       ctx: OpContext) -> tuple[Any, str]:
     bits = ctx.modulus_bits
-    if bits is not None and bits > 30 and ctx.model_shards > 1:
+    rns = bits is not None and bits > 30
+    if rns and ctx.model_shards > 1:
         raise OpConfigError(
             "distributed polymul-mod is single-limb: RNS "
             "(modulus_bits > 30) shards limbs, not the sequence — drop "
             "--model-shards or use modulus_bits <= 30")
-    if ctx.auto:
-        # RNS shards limbs, not the sequence: the chooser only sees the
-        # local tier for multi-limb moduli.
-        shards = 1 if (bits is not None and bits > 30) else ctx.model_shards
-        _plan_or_config_error(n=n, batch=0, workload="polymul-mod",
-                              model_shards=shards,
-                              verified=ctx.verified, pim_ok=ctx.pim_ok)
-    elif ctx.model_shards > 1:
-        _plan_or_config_error(n=n, batch=0, exact=True,
-                              model_shards=ctx.model_shards,
-                              force_distributed=True)
+    plan = _plan(n, ctx, exact=True)
     try:
-        if bits is not None and bits > 30:
+        if rns:
             from repro.core.ntt import RNSParams
             RNSParams.make(n, modulus_bits=bits)
         else:
@@ -642,35 +587,25 @@ def _validate_polymul_mod(spec: OpSpec, n: int, ctx: OpContext) -> None:
     except ValueError as e:
         raise OpConfigError(
             f"no NTT modulus for n={n}, modulus_bits={bits}: {e}") from e
-
-
-def _bind_polymul_mod(spec: OpSpec, n: int, ctx: OpContext,
-                      batch: int) -> BoundOp:
-    bits = ctx.modulus_bits
-    rns_route = bits is not None and bits > 30
-    if ctx.auto:
-        plan = _plan_or_config_error(
-            n=n, batch=batch, workload="polymul-mod",
-            model_shards=1 if rns_route else ctx.model_shards,
-            verified=ctx.verified, pim_ok=ctx.pim_ok)
-    elif ctx.model_shards > 1:
-        plan = _plan_or_config_error(n=n, batch=batch, exact=True,
-                                     model_shards=ctx.model_shards,
-                                     force_distributed=True)
-    else:
-        plan = _plan_or_config_error(n=n, batch=batch, exact=True)
     if plan.tier == "distributed":
+        return plan, "polymul-mod-distributed"
+    return plan, "polymul-mod-rns" if rns else "polymul-mod-single"
+
+
+def _bind_polymul_mod(spec: OpSpec, n: int, ctx: OpContext, plan,
+                      route: str) -> BoundOp:
+    bits = ctx.modulus_bits
+    if route == "polymul-mod-distributed":
         import jax
         from repro.core.ntt import NTTParams
         from repro.core.ntt import distributed as dntt
         params = NTTParams.make(n, bits=30 if bits is None else bits)
         mesh = jax.make_mesh((ctx.model_shards,), ("data",))
         return BoundOp(
-            spec=spec, n=n, ctx=ctx, plan=plan,
-            route="polymul-mod-distributed",
+            spec=spec, n=n, ctx=ctx, plan=plan, route=route,
             fn=jax.jit(dntt.make_sharded_ntt_polymul(mesh, params)),
             payload_dtype=np.uint32, ntt_params=params, mesh=mesh)
-    if bits is not None and bits > 30:
+    if route == "polymul-mod-rns":
         from repro.core.ntt import RNSParams, rns_polymul
         rns = RNSParams.make(n, modulus_bits=bits)
         if ctx.verified:
@@ -684,15 +619,13 @@ def _bind_polymul_mod(spec: OpSpec, n: int, ctx: OpContext,
                 raise OpConfigError(
                     f"verified polymul-mod (RNS) bind rejected: {e}"
                 ) from e
-        return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan,
-                       route="polymul-mod-rns",
+        return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan, route=route,
                        fn=functools.partial(rns_polymul, rns=rns),
                        payload_dtype=object, rns=rns)
     from repro.core.ntt import NTTParams
     from repro.kernels import ntt as kntt
     params = NTTParams.make(n, bits=30 if bits is None else bits)
-    return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan,
-                   route="polymul-mod-single",
+    return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan, route=route,
                    fn=functools.partial(kntt.ntt_polymul, params=params),
                    payload_dtype=np.uint32, ntt_params=params)
 
@@ -728,7 +661,7 @@ register_op(
             "routes through multi-limb RNS/CRT, --model-shards > 1 the "
             "distributed four-step NTT",
     uses_modulus_bits=True, uses_model_shards=True,
-    _validate=_validate_polymul_mod, _bind=_bind_polymul_mod,
+    _route=_route_polymul_mod, _bind=_bind_polymul_mod,
     warmup_payload=_zeros,
     random_payload=_random_mod_payload,
     verify=_verify_mod,

@@ -76,15 +76,13 @@ class FFTService:
     """
 
     def __init__(self, n: int, batch: int, op: str = "fft",
-                 modulus_bits: int | None = None, model_shards: int = 1,
-                 auto: bool = False):
+                 modulus_bits: int | None = None, model_shards: int = 1):
         self.n = n
         self.batch = batch
         self.op = op
         self.engine = ServeEngine(max_batch=batch,
                                   modulus_bits=modulus_bits,
-                                  model_shards=model_shards,
-                                  auto=auto)
+                                  model_shards=model_shards)
         # strict: knobs the op does not consume are config errors, not
         # silently ignored flags
         self.bound = self.engine.register(op, n, strict=True)
@@ -112,8 +110,7 @@ def run_fft_service(args) -> dict:
     rng = np.random.default_rng(0)
     svc = FFTService(args.n, args.batch, args.op,
                      modulus_bits=args.modulus_bits,
-                     model_shards=args.model_shards,
-                     auto=args.auto)
+                     model_shards=args.model_shards)
     svc.warmup()
     first: dict[int, object] = {}
 
@@ -191,11 +188,9 @@ def run_engine_service(args) -> dict:
     Buckets come from the cross product of ``--ops`` and ``--ns``; the
     process-level ``--modulus-bits`` / ``--model-shards`` context is
     narrowed per op (ops without that route stay local), so one engine can
-    serve local fft next to the distributed polymul-mod tier. ``--auto``
-    hands tier/packing choice per bucket to the cost model
-    (docs/planner.md) and reports predicted-vs-observed per-bucket cost.
-    One result per bucket is verified against the registry's numpy oracle
-    after the drain.
+    serve local fft next to the distributed polymul-mod tier. One result
+    per bucket is verified against the registry's numpy oracle after the
+    drain.
 
     With ``--snapshot-dir`` the process is preemption-safe
     (docs/fault_tolerance.md): SIGTERM stops admission, the engine drains
@@ -255,7 +250,6 @@ def run_engine_service(args) -> dict:
                              max_pending=args.max_pending,
                              modulus_bits=args.modulus_bits,
                              model_shards=args.model_shards,
-                             auto=args.auto,
                              verified=args.verify,
                              fault_model=fault_model,
                              watchdog_cfg=wd_cfg,
@@ -370,15 +364,10 @@ def run_engine_service(args) -> dict:
           f"p50={lat['p50']:.2f}ms p90={lat['p90']:.2f}ms "
           f"p99={lat['p99']:.2f}ms")
     for name, b in stats["buckets"].items():
-        pred = b.get("predicted_s_per_req")
-        # predictions span ns (tiny local XLA) to ms (PIM waves): 3 sig figs
-        cost = (f" predicted={pred * 1e6:.3g}us/req "
-                f"({b['predicted_tier']}/{b['predicted_backend']})"
-                if pred is not None else "")
         print(f"[serve:engine]   {name} route={b['route']} "
               f"served={b['served']} batches={b['batches']} "
               f"mean_batch={b['mean_batch']:.1f} "
-              f"utilization={b['utilization']:.2f}{cost}")
+              f"utilization={b['utilization']:.2f}")
     return stats
 
 
@@ -451,12 +440,6 @@ def main(argv=None):
                         "model_shards",
                         "shard the sequence over this many devices via "
                         "the distributed four-step NTT/FFT tiers"))
-    ap.add_argument("--auto", action="store_true",
-                    help="cost-model auto-tiering (docs/planner.md): the "
-                         "planner chooses tier and packing per bucket; "
-                         "--model-shards becomes the AVAILABLE device "
-                         "count, and stats report predicted-vs-observed "
-                         "per-bucket cost")
     ap.add_argument("--elastic", action="store_true",
                     help="engine service: on a watchdog eviction, drain + "
                          "snapshot + warm-restart with --model-shards "

@@ -10,16 +10,13 @@ check-cost contract (docs/fault_tolerance.md).
   caught); the float checks are toleranced residuals that localize the
   corrupted batch row;
 * cost contract: ``abft.charge_check`` on a live sim == the closed form
-  ``abft.check_cycles`` == ``cost.abft_check_cycles`` (counter parity),
-  the checked overhead stays under the BENCH gate, and
-  ``workload_cost(..., verified=True)`` / ``pim_ok=False`` price exactly
-  these numbers into the planner.
+  ``abft.check_cycles`` (counter parity), and the check stays under the
+  BENCH gate's share of the closed-form cycles of the unit it verifies
+  (``abft.unit_cycles``, the ``core.pim`` closed forms).
 """
 import numpy as np
 import pytest
 
-from repro.core import cost as cost_lib
-from repro.core.fft.planner import plan
 from repro.core.ntt import NTTParams, RNSParams
 from repro.core.pim import (FOURIERPIM_8, FP32, INT32, FaultModel,
                             SparesExhausted, fft_pim, ntt_pim)
@@ -222,7 +219,7 @@ def test_rns_unsupported_modulus_rejected():
 
 
 # ---------------------------------------------------------------------------
-# Check cost: counter parity, overhead gate, planner pricing
+# Check cost: counter parity, overhead gate
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("workload", sorted(abft.CHECKS))
@@ -233,7 +230,6 @@ def test_check_cost_counter_parity(workload, n):
     abft.charge_check(sim, workload, n)
     closed = abft.check_cycles(workload, n, CFG, spec)
     assert sim.ctr.cycles == closed
-    assert cost_lib.abft_check_cycles(workload, n) == closed
 
 
 @pytest.mark.parametrize("workload", sorted(abft.CHECKS))
@@ -241,50 +237,8 @@ def test_check_cost_counter_parity(workload, n):
 def test_check_overhead_under_gate(workload, n):
     """The check must stay CHEAP relative to the transform it verifies —
     the same <= 0.25 bound the BENCH abft_overhead_ratio gate enforces."""
-    base = cost_lib.pim_local_unit_cycles(workload, n, batch=2)
-    check = cost_lib.abft_check_cycles(workload, n)
+    spec = INT32 if workload == "polymul-mod" else FP32
+    base = abft.unit_cycles(workload, n, CFG, spec)
+    check = abft.check_cycles(workload, n, CFG, spec)
     assert 0 < check <= 0.25 * base, \
         f"{workload}/n={n}: check {check} vs base {base}"
-
-
-def test_verified_pricing_adds_exactly_the_check():
-    n, batch = 1024, 8
-    for workload in cost_lib.WORKLOADS:
-        base = cost_lib.workload_cost(workload, n, batch)
-        ver = cost_lib.workload_cost(workload, n, batch, verified=True)
-        # verified pricing may reorder the sorted candidate list: match
-        # candidates by identity, not rank
-        by_key = {(c["tier"], c["real"]): c for c in ver["candidates"]}
-        assert len(by_key) == len(base["candidates"])
-        for cb in base["candidates"]:
-            cv = by_key[(cb["tier"], cb["real"])]
-            pb, pv = cb["backends"]["pim"], cv["backends"]["pim"]
-            if "infeasible" in pb:
-                continue
-            wl = cost_lib._pim_workload(workload, cb["real"])
-            assert pv["pim_cycles"] - pb["pim_cycles"] == \
-                cost_lib.abft_check_cycles(wl, n)
-            assert pv["total_s"] > pb["total_s"]
-            xv = cv["backends"]["xla"]
-            assert xv["t_compute_s"] >= cb["backends"]["xla"]["t_compute_s"]
-
-
-def test_pim_ok_false_quarantines_every_candidate():
-    c = cost_lib.workload_cost("fft", 1024, 8, pim_ok=False)
-    assert c["candidates"]
-    for cand in c["candidates"]:
-        assert cand["backend_best"] == "xla"
-        assert "quarantined" in cand["backends"]["pim"]["infeasible"]
-
-
-def test_planner_verified_and_pim_ok_passthrough():
-    p = plan(n=1024, batch=8, workload="fft", verified=True, pim_ok=False)
-    best = p.cost["best"]
-    assert best["backend_best"] == "xla"
-    assert "quarantined" in best["backends"]["pim"]["infeasible"]
-    pv = plan(n=1024, batch=8, workload="polymul-mod", verified=True)
-    pb = plan(n=1024, batch=8, workload="polymul-mod")
-    pim_v = pv.cost["best"]["backends"]["pim"]
-    pim_b = pb.cost["best"]["backends"]["pim"]
-    if "pim_cycles" in pim_v and "pim_cycles" in pim_b:
-        assert pim_v["pim_cycles"] > pim_b["pim_cycles"]

@@ -171,7 +171,7 @@ def read_state(path):
     "bare-plan-literal": {
         "flag": ("""\
 def forced_plan():
-    return FFTPlan(tier="distributed", radix=2, block_b=1)
+    return FFTPlan(tier="distributed", block_b=1, seq_shards=2)
 """, _P),
         "ok": ("""\
 from repro.core.fft.planner import plan

@@ -4,8 +4,8 @@
 The pin this file owns: under seeded stuck-at / bit-flip / dead-array
 injection, a mixed-op engine run serves EVERY request with a result that
 matches the fault-free oracle — corruption is always detected by the ABFT
-gate, recovery is bounded (retry cap, then the circuit breaker re-binds
-the bucket onto the XLA backend and quarantines the array), and no
+gate, recovery is bounded (retry cap, then the circuit breaker unwraps
+the bucket's fault injector and quarantines the array), and no
 corrupted batch is ever delivered. Plus the robustness satellites:
 per-request deadlines, non-finite rejection at submit, request_stop
 racing a blocked submit, and checked/atomic checkpoint manifests.
@@ -59,7 +59,7 @@ def test_chaos_mixed_ops_all_results_match_oracles(rng):
     clean re-execution; every delivered result matches the registry's
     fault-free numpy oracle bit-for-bit (mod) / within float tol."""
     fm = _chaos_model(bitflip_per_gate=1e-4)
-    engine = ServeEngine(max_batch=4, auto=True, modulus_bits=60,
+    engine = ServeEngine(max_batch=4, modulus_bits=60,
                          verified=True, fault_model=fm,
                          collect_timeout_s=0.01)
     combos = [("fft", N), ("polymul-real", N), ("polymul-mod", N)]
@@ -78,7 +78,7 @@ def test_chaos_mixed_ops_all_results_match_oracles(rng):
 
 def test_chaos_single_limb_mod(rng):
     fm = _chaos_model()
-    engine = ServeEngine(max_batch=4, auto=True, verified=True,
+    engine = ServeEngine(max_batch=4, verified=True,
                          fault_model=fm, collect_timeout_s=0.01)
     assert engine.bound("polymul-mod", N).rns is None   # single-limb route
     stats = _run_verified(engine, [("polymul-mod", N)], rng)
@@ -86,23 +86,20 @@ def test_chaos_single_limb_mod(rng):
     assert b["corrupted"] >= 1 and b["fell_back"] == 1 and b["breaker_open"]
 
 
-def test_permanent_fault_pins_breaker_to_xla(rng):
-    """Forced dead array: after the breaker the bucket's re-bound plan has
-    the PIM backend marked infeasible by the quarantine reason — the
-    fallback is pinned to XLA, not re-planned onto the faulty array."""
+def test_permanent_fault_breaker_quarantines_and_serves_clean(rng):
+    """Forced dead array: the breaker quarantines the array and the
+    bucket serves on its unwrapped route, not on the faulty array."""
     fm = FaultModel(seed=5, dead_arrays=(0,), n_arrays=2, spares=1)
-    engine = ServeEngine(max_batch=4, auto=True, verified=True,
+    engine = ServeEngine(max_batch=4, verified=True,
                          fault_model=fm, collect_timeout_s=0.01)
-    _run_verified(engine, [("fft", N)], rng, per_bucket=4)
+    stats = _run_verified(engine, [("fft", N)], rng, per_bucket=4)
     assert fm.is_quarantined(0)
-    rebound = engine.bound("fft", N)
-    best = rebound.plan.cost["best"]
-    assert best["backend_best"] == "xla"
-    assert "quarantined" in best["backends"]["pim"]["infeasible"]
-    # breaker is sticky: later batches serve cleanly on the re-bound op
+    corrupted = stats["buckets"][f"fft/n={N}"]["integrity"]["corrupted"]
+    # breaker is sticky: later batches serve cleanly on the unwrapped op
     stats = _run_verified(engine, [("fft", N)], rng, per_bucket=3)
     b = stats["buckets"][f"fft/n={N}"]["integrity"]
     assert b["breaker_open"] and b["fell_back"] == 1
+    assert b["corrupted"] == corrupted
 
 
 def test_fault_model_requires_verified():
@@ -111,7 +108,7 @@ def test_fault_model_requires_verified():
 
 
 def test_clean_verified_run_counts_checks_only(rng):
-    engine = ServeEngine(max_batch=4, auto=True, verified=True,
+    engine = ServeEngine(max_batch=4, verified=True,
                          collect_timeout_s=0.01)
     stats = _run_verified(engine, [("fft", N), ("polymul", N)], rng)
     for b in stats["buckets"].values():
@@ -122,7 +119,7 @@ def test_clean_verified_run_counts_checks_only(rng):
 
 
 def test_unverified_stats_report_zero_integrity(rng):
-    engine = ServeEngine(max_batch=4, auto=True, collect_timeout_s=0.01)
+    engine = ServeEngine(max_batch=4, collect_timeout_s=0.01)
     stats = _run_verified(engine, [("fft", N)], rng, per_bucket=2)
     integ = stats["buckets"][f"fft/n={N}"]["integrity"]
     assert integ == {"checked": 0, "corrupted": 0, "retried": 0,
@@ -131,7 +128,7 @@ def test_unverified_stats_report_zero_integrity(rng):
 
 def test_verified_survives_snapshot_roundtrip(tmp_path, rng):
     d = str(tmp_path / "snap")
-    engine = ServeEngine(max_batch=4, auto=True, verified=True,
+    engine = ServeEngine(max_batch=4, verified=True,
                          collect_timeout_s=0.01)
     _run_verified(engine, [("fft", N)], rng, per_bucket=2)
     engine.snapshot(d)
@@ -144,7 +141,7 @@ def test_verified_survives_snapshot_roundtrip(tmp_path, rng):
 # ---------------------------------------------------------------------------
 
 def test_deadline_expired_request_gets_structured_error(rng):
-    engine = ServeEngine(max_batch=4, auto=True, collect_timeout_s=0.01)
+    engine = ServeEngine(max_batch=4, collect_timeout_s=0.01)
     bound = engine.register("fft", N)
     rid_ok = engine.submit("fft", N, bound.random_payload(rng))
     rid_exp = engine.submit("fft", N, bound.random_payload(rng),
@@ -165,7 +162,7 @@ def test_deadline_expired_request_gets_structured_error(rng):
 
 
 def test_nonfinite_payload_rejected_at_submit(rng):
-    engine = ServeEngine(max_batch=4, auto=True, collect_timeout_s=0.01)
+    engine = ServeEngine(max_batch=4, collect_timeout_s=0.01)
     bad = np.zeros(N, np.complex64)
     bad[3] = np.nan
     with pytest.raises(OpConfigError, match="non-finite"):
@@ -185,7 +182,7 @@ def test_nonfinite_payload_rejected_at_submit(rng):
 def test_request_stop_unblocks_waiting_submit(rng):
     """A submit blocked on a FULL queue must raise EngineStopped promptly
     when request_stop lands — not wait out its backpressure timeout."""
-    engine = ServeEngine(max_batch=4, max_pending=1, auto=True,
+    engine = ServeEngine(max_batch=4, max_pending=1,
                          collect_timeout_s=0.01)
     bound = engine.register("fft", N)
     engine.submit("fft", N, bound.random_payload(rng))   # fills the queue

@@ -94,3 +94,71 @@ assert err < 2e-6, f"roundtrip rel err {err}"
 print("OK")
 """, n_devices=8)
     assert "OK" in out
+
+
+# ---------------------------------------------------------------------------
+# Collective byte closed forms == the live ledger of each sharded builder
+# ---------------------------------------------------------------------------
+
+def _traced_collective_bytes(build, *args) -> int:
+    """All-to-all + ppermute bytes the ``dist.collectives`` ledger records
+    while lowering ``build`` (an AbstractMesh trace needs no devices)."""
+    import jax
+    from repro.dist import collectives
+    with collectives.ledger() as led:
+        jax.jit(build).lower(*args)
+    return led.bytes_by_kind["all-to-all"] + led.bytes_by_kind["ppermute"]
+
+
+@pytest.mark.parametrize("builder", ["fft", "rfft", "polymul",
+                                     "polymul_real", "ntt_polymul"])
+def test_xla_collective_bytes_match_live_ledger(builder):
+    """``four_step_collective_stats`` == the ledger of the sharded builder,
+    traced at the real shard count on an AbstractMesh."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.fft import distributed as dfft
+    from repro.core.ntt import NTTParams
+    from repro.core.ntt import distributed as dntt
+    from repro.dist import compat
+    n, batch, D = 1024, 4, 4
+    mesh = compat.abstract_mesh((D,), ("model",))
+
+    def spec(dtype):
+        return jax.ShapeDtypeStruct((batch, n), dtype)
+    if builder == "ntt_polymul":
+        build = dntt.make_sharded_ntt_polymul(
+            mesh, NTTParams.make(n), axis_name="model", batch_axes=())
+        got = _traced_collective_bytes(build, spec(jnp.uint32),
+                                       spec(jnp.uint32))
+        want = dntt.four_step_collective_stats(n, batch, D,
+                                               op="polymul")["bytes"]
+    else:
+        make = {"fft": dfft.make_sharded_fft,
+                "rfft": dfft.make_sharded_rfft,
+                "polymul": dfft.make_sharded_polymul,
+                "polymul_real": dfft.make_sharded_polymul_real}[builder]
+        dtype = jnp.float32 if builder in ("rfft", "polymul_real") \
+            else jnp.complex64
+        arity = 2 if builder.startswith("polymul") else 1
+        got = _traced_collective_bytes(make(mesh, batch_axes=()),
+                                       *[spec(dtype)] * arity)
+        want = dfft.four_step_collective_stats(n, batch, D,
+                                               op=builder)["total_bytes"]
+    assert got == want
+
+
+def test_xla_collective_bytes_pad_odd_real_batches():
+    """The distributed real tier pads an odd batch to the next even size:
+    a batch of 3 moves the closed form's bytes at batch 4."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.fft import distributed as dfft
+    from repro.dist import compat
+    n, D = 1024, 4
+    mesh = compat.abstract_mesh((D,), ("model",))
+    got = _traced_collective_bytes(
+        dfft.make_sharded_rfft(mesh, batch_axes=()),
+        jax.ShapeDtypeStruct((3, n), jnp.float32))
+    assert got == dfft.four_step_collective_stats(n, 4, D,
+                                                  op="rfft")["total_bytes"]

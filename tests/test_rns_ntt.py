@@ -353,6 +353,9 @@ def test_planner_routes_exact_distributed():
     big = fft_core.plan(1 << 20, 8, model_shards=8, exact=True)
     assert big.tier == "distributed" and big.exact and big.seq_shards == 8
     assert "NTT" in big.describe()
-    # without shards the exact tier stays local at any n
-    solo = fft_core.plan(1 << 20, 8, model_shards=1, exact=True)
+    # without shards the exact tier is local up to its ceiling, and above
+    # it raises like the float tiers (no local kernel compiles there)
+    solo = fft_core.plan(1 << 16, 8, model_shards=1, exact=True)
     assert solo.tier == "local" and solo.exact
+    with pytest.raises(ValueError, match="_MAX_LOCAL_N_EXACT"):
+        fft_core.plan(1 << 20, 8, model_shards=1, exact=True)

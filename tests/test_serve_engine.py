@@ -66,29 +66,14 @@ def test_registry_covers_all_ops_and_serve_has_no_ladder():
                                                           "polymul-mod"}
     for spec in op_registry.registry():
         assert spec.summary and spec.arity in (1, 2)
-
-
-def test_registry_rejects_unknown_op_and_foreign_knobs():
-    with pytest.raises(op_registry.OpConfigError):
-        op_registry.get_op("polymul-imaginary")
-    for op, ctx in (("fft", op_registry.OpContext(modulus_bits=40)),
-                    ("rfft", op_registry.OpContext(model_shards=4)),
-                    ("polymul", op_registry.OpContext(model_shards=2))):
-        with pytest.raises(op_registry.OpConfigError):
-            op_registry.get_op(op).bind(64, ctx)
-    # narrow() strips exactly those knobs, so the mixed engine can feed one
-    # process-level context to every op
+    # narrow() strips exactly the knobs an op ignores, so the mixed engine
+    # can feed one process-level context to every op (the rejections of
+    # foreign knobs are rows of tests/test_routes.py)
     ctx = op_registry.OpContext(modulus_bits=100, model_shards=8)
     assert op_registry.get_op("fft").narrow(ctx) == op_registry.OpContext()
     assert op_registry.get_op("polymul-real").narrow(ctx) == \
         op_registry.OpContext(model_shards=8)
     assert op_registry.get_op("polymul-mod").narrow(ctx) == ctx
-
-
-def test_registry_rns_plus_shards_is_a_config_error():
-    with pytest.raises(op_registry.OpConfigError, match="single-limb"):
-        op_registry.get_op("polymul-mod").bind(
-            1024, op_registry.OpContext(modulus_bits=100, model_shards=8))
 
 
 # ---------------------------------------------------------------------------
