@@ -86,6 +86,10 @@ class BoundOp:
     ``serve.stack``, ``serve.h2d`` and ``serve.launch``; ``serve.fetch``
     over ``serve.sync``, ``serve.d2h`` and, where the result comes back as
     words, ``serve.join``.
+
+    On every route but RNS, ``stack`` writes each batch into host slots
+    the bound op owns and reuses (:class:`_SlotRing`), counting
+    ``stage.slot_alloc`` where it (re)allocates them.
     """
     spec: "OpSpec"
     n: int
@@ -98,6 +102,8 @@ class BoundOp:
     ntt_params: Any = None
     rns: Any = None
     mesh: Any = None
+    _slots: "_SlotRing | None" = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
     @property
     def key(self) -> tuple[str, int]:
@@ -105,21 +111,44 @@ class BoundOp:
 
     def stack(self, payloads: Sequence[Any]) -> tuple:
         """Host-side batch assembly: a list of per-request payloads ->
-        the operand arrays ``fn`` consumes, at the list's actual length."""
+        the operand arrays ``fn`` consumes, at the list's actual length.
+
+        The RNS route stacks into fresh object arrays and stays on the
+        host. Every other route writes the batch into the leading rows of
+        a slot of its ring (``_SlotRing.take``) and sends those views to
+        the device."""
         import jax.numpy as jnp
         with telemetry.span("serve.stack"):
-            cols = _stack_operands(self, payloads)
-        if self.payload_dtype is object:      # RNS: stays host-side
-            return cols
+            if self.payload_dtype is object:      # RNS: stays host-side
+                return _stack_operands(self, payloads)
+            cols = self._stage(payloads)
         with telemetry.span("serve.h2d"):
-            return tuple(jnp.asarray(c) for c in cols)
+            operands = tuple(jnp.asarray(c) for c in cols)
+        # until ``execute`` names the result that reads them
+        self._slots.read_by(operands)
+        return operands
+
+    def _stage(self, payloads: Sequence[Any]) -> tuple[np.ndarray, ...]:
+        """Write the batch into a slot of the ring. A batch with more rows than
+        the ring makes a new ring of at least twice the rows, so warming
+        every size from 1 to B allocates about log2(B) rings, not B."""
+        rows = len(payloads)
+        if self._slots is None or rows > self._slots.rows:
+            grown = 2 * self._slots.rows if self._slots is not None else 0
+            self._slots = _SlotRing(max(rows, grown), self.n,
+                                    self.payload_dtype, self.spec.arity)
+            telemetry.count("stage.slot_alloc")
+        return _stack_operands(self, payloads, out=self._slots.take(rows))
 
     def execute(self, payloads: Sequence[Any]):
         """Dispatch one batch (async where the route is a jitted fn)."""
         with telemetry.span("serve.dispatch"):
             operands = self.stack(payloads)
             with telemetry.span("serve.launch"):
-                return self.fn(*operands)
+                out = self.fn(*operands)
+            if self._slots is not None:
+                self._slots.read_by(out)
+            return out
 
     def to_numpy(self, out) -> np.ndarray:
         """Materialize a dispatched batch (blocks until ready)."""
@@ -324,16 +353,67 @@ def _circular_complex(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     return np.fft.ifft(np.fft.fft(a) * np.fft.fft(b))
 
 
-def _stack_operands(bound: BoundOp,
-                    payloads: Sequence[Any]) -> tuple[np.ndarray, ...]:
-    """Host-side numpy stacking: ``BoundOp.stack`` before the device
-    transfer, and the operands of the integrity checks."""
-    if bound.spec.arity == 1:
-        return (np.stack([np.asarray(p, bound.payload_dtype)
-                          for p in payloads]),)
+def _stack_operands(bound: BoundOp, payloads: Sequence[Any],
+                    out: tuple[np.ndarray, ...] | None = None
+                    ) -> tuple[np.ndarray, ...]:
+    """Host-side numpy stacking, one array per operand: into ``out``
+    (``BoundOp.stack``'s slot views) where given, else into fresh arrays
+    (the RNS route and the operands of the integrity checks)."""
+    arity = bound.spec.arity
     return tuple(
-        np.stack([np.asarray(p[i], bound.payload_dtype) for p in payloads])
-        for i in range(bound.spec.arity))
+        np.stack([np.asarray(p if arity == 1 else p[i], bound.payload_dtype)
+                  for p in payloads], out=None if out is None else out[i])
+        for i in range(arity))
+
+
+class _SlotRing:
+    """The host buffers ``BoundOp.stack`` writes batches into: two slots,
+    each one C-contiguous ``(rows, n)`` array per operand. A batch of
+    ``B`` rows fills a slot's leading ``B`` rows.
+
+    ``jnp.asarray`` returns before its copy to the device ends, and on the
+    CPU backend may alias the buffer, so a slot is written again only once
+    the result of the batch that last read it is ready. ``take`` gives out
+    the slot it gave out last where that result is already ready, so a
+    bucket whose previous batch is done keeps writing the same pages,
+    still in the caches; else the other slot, once its reader is done. In
+    ``ServeEngine.run`` that reader has been fetched by then (the engine
+    keeps one batch in flight), so the wait costs nothing; callers that
+    dispatch several batches without fetching rely on it.
+    """
+
+    def __init__(self, rows: int, n: int, dtype, arity: int):
+        self.rows = rows
+        # np.full writes every page now, in set-up, not in a served batch
+        self._bufs = [tuple(np.full((rows, n), 0, dtype)
+                            for _ in range(arity))
+                      for _ in range(2)]
+        self._readers: list[Any] = [None, None]
+        self._last = 0
+
+    def take(self, rows: int) -> tuple[np.ndarray, ...]:
+        """A slot's leading ``rows`` rows, once the work that last read
+        the slot is done."""
+        import jax
+        slot = self._last
+        if not _is_ready(self._readers[slot]):
+            slot = 1 - slot
+            jax.block_until_ready(self._readers[slot])
+        self._last = slot
+        return tuple(buf[:rows] for buf in self._bufs[slot])
+
+    def read_by(self, reader: Any) -> None:
+        """Name what reads the slot ``take`` last gave out: the device
+        operands, then the result computed from them."""
+        self._readers[self._last] = reader
+
+
+def _is_ready(tree: Any) -> bool:
+    """True where every array of ``tree`` is computed; leaves without
+    ``is_ready`` (host arrays, None) already are."""
+    import jax
+    return all(leaf.is_ready() for leaf in jax.tree.leaves(tree)
+               if hasattr(leaf, "is_ready"))
 
 
 def _integrity_fft(bound: BoundOp, payloads, rows):
