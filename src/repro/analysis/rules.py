@@ -31,7 +31,7 @@ RawFinding = Tuple[int, int, str]
 #: analyzer stays importable without jax; tests/test_analysis.py asserts
 #: this set == op_registry.op_names() so the two cannot drift.
 OP_NAMES = frozenset({"fft", "rfft", "polymul", "polymul-real",
-                      "polymul-mod"})
+                      "polymul-mod", "nas-ft"})
 
 #: Data-moving jax.lax collectives. axis_index is deliberately absent —
 #: it moves no bytes, so calling it raw cannot break ledger parity.

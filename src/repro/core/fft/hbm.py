@@ -26,6 +26,12 @@ Per batch of real products (rows a, b packed as z = a + i b):
 The twiddles are ``distributed._twiddle_tables``' (exact integer exponents,
 float64 angles, rounded once); every matrix product runs at
 ``Precision.HIGHEST``, as in the inner kernels.
+
+Both outer passes run through ``column_pass``, one Pallas pass of column
+DFTs over (B, m, L) planes in (m, lanes) tiles. ``axis_dft`` is the same
+pass without the twiddle: the DFT along a strided axis of a grid held in
+HBM, which the 3-D transforms of ``core.fft.ft3d`` take for their y and z
+axes.
 """
 from __future__ import annotations
 
@@ -49,15 +55,23 @@ _TILE_ELEMS = 1 << 16
 
 
 @functools.lru_cache(maxsize=16)
+def dft_matrix(m: int) -> tuple[np.ndarray, np.ndarray]:
+    """fp32 planes of the m-point DFT matrix F[k, j] = w_m^{k*j}, gathered
+    from ``kfft.twiddle_table(m)``. Its conjugate, the inverse's matrix, is
+    exact."""
+    wr, wi = (t[0] for t in kfft.twiddle_table(m))
+    k = np.arange(m)
+    idx = np.outer(k, k) % m
+    return wr[idx], wi[idx]
+
+
+@functools.lru_cache(maxsize=16)
 def outer_tables(n: int, n1: int) -> tuple[np.ndarray, ...]:
-    """fp32 planes of the n1-point DFT matrix F[k, j] = w_n1^{k*j} and of
-    the twiddle T[k1, j2] = w_n^{k1*j2}, (n1, n/n1). The inverse pass uses
+    """fp32 planes of the n1-point DFT matrix (``dft_matrix``) and of the
+    twiddle T[k1, j2] = w_n^{k1*j2}, (n1, n/n1). The inverse pass uses
     their conjugates, which are exact."""
-    wr, wi = (t[0] for t in kfft.twiddle_table(n1))
-    k = np.arange(n1)
-    idx = np.outer(k, k) % n1
     t = _twiddle_tables(n, n1, n // n1, False)[0]
-    return (wr[idx], wi[idx], np.ascontiguousarray(t.real),
+    return (*dft_matrix(n1), np.ascontiguousarray(t.real),
             np.ascontiguousarray(t.imag))
 
 
@@ -71,6 +85,14 @@ def outer_vmem_bytes(n: int, n1: int) -> int:
     eight tiles of the kernel's temporaries."""
     tile = kfft._tile_bytes(n1, _lanes(n1, n // n1))
     return 2 * (2 * kfft._tile_bytes(n1, n1) + 6 * tile) + 8 * tile
+
+
+def axis_vmem_bytes(m: int, width: int) -> int:
+    """VMEM of one ``axis_dft`` launch over (B, m, width) planes: the
+    double-buffered DFT matrix planes, two input and two output (m, L)
+    tiles, and eight tiles of the kernel's temporaries."""
+    tile = kfft._tile_bytes(m, _lanes(m, width))
+    return 2 * (2 * kfft._tile_bytes(m, m) + 4 * tile) + 8 * tile
 
 
 def _fwd_kernel(fr_ref, fi_ref, tr_ref, ti_ref, a_ref, b_ref, yr_ref,
@@ -91,26 +113,67 @@ def _inv_kernel(fr_ref, fi_ref, tr_ref, ti_ref, zr_ref, zi_ref, o_ref, *,
     o_ref[0, 1] = ci * scale
 
 
-def _outer_call(kernel, name: str, n: int, planes, outs, interpret: bool):
-    """One Pallas pass over (B, n1, n2) planes in (n1, L) tiles, writing
-    ``outs`` (each (B, ..., n1, n2)). The grid runs the rows innermost, so
-    each twiddle tile is read once a pass."""
-    rows, n1, n2 = planes[0].shape
-    lanes = _lanes(n1, n2)
-    fr, fi, tr, ti = (jnp.asarray(t) for t in outer_tables(n, n1))
-    const = pl.BlockSpec((n1, n1), lambda j, b: (0, 0))
-    tile = pl.BlockSpec((n1, lanes), lambda j, b: (0, j))
+def column_pass(kernel, name: str, mats, tiles, planes, outs,
+                interpret: bool):
+    """One Pallas pass of column DFTs over (B, m, L) planes in (m, lanes)
+    tiles, writing ``outs`` (each (B, ..., m, L)). ``mats`` are whole
+    (m, m) constants, the same block every step; ``tiles`` are (m, L)
+    tables tiled as the planes are. The grid runs the rows innermost, so
+    each table tile is read once a pass. The kernel takes the refs of
+    ``mats``, ``tiles``, ``planes`` and ``outs``, in that order."""
+    rows, m, width = planes[0].shape
+    lanes = _lanes(m, width)
+    const = pl.BlockSpec((m, m), lambda j, b: (0, 0))
+    tile = pl.BlockSpec((m, lanes), lambda j, b: (0, j))
 
     def rows_spec(shape):
         mid = len(shape) - 3
         return pl.BlockSpec((1, *shape[1:-1], lanes),
                             lambda j, b: (b,) + (0,) * (mid + 1) + (j,))
     return pl.pallas_call(
-        kernel, grid=(n2 // lanes, rows),
-        in_specs=[const, const, tile, tile]
+        kernel, grid=(width // lanes, rows),
+        in_specs=[const] * len(mats) + [tile] * len(tiles)
         + [rows_spec(p.shape) for p in planes],
         out_specs=[rows_spec(o.shape) for o in outs], out_shape=outs,
-        interpret=interpret, name=name)(fr, fi, tr, ti, *planes)
+        interpret=interpret, name=name)(
+            *(jnp.asarray(t) for t in (*mats, *tiles)), *planes)
+
+
+def _outer_call(kernel, name: str, n: int, planes, outs, interpret: bool):
+    """The four-step's outer pass: column DFTs of length n1 with the
+    twiddle of ``outer_tables``, over (B, n1, n2) planes."""
+    fr, fi, tr, ti = outer_tables(n, planes[0].shape[1])
+    return column_pass(kernel, name, [fr, fi], [tr, ti], planes, outs,
+                       interpret)
+
+
+def _axis_kernel(fr_ref, fi_ref, xr_ref, xi_ref, yr_ref, yi_ref, *,
+                 scale: float | None):
+    yr, yi = _cmatmul(_mm, fr_ref[...], fi_ref[...], xr_ref[0], xi_ref[0])
+    if scale is not None:
+        yr, yi = yr * scale, yi * scale
+    yr_ref[0] = yr
+    yi_ref[0] = yi
+
+
+@functools.partial(jax.jit, static_argnames=("inverse", "interpret"))
+def axis_dft(xr: jax.Array, xi: jax.Array, *, inverse: bool = False,
+             interpret: bool | None = None) -> tuple[jax.Array, jax.Array]:
+    """The DFT along axis 1 of (B, m, L) float32 planes, in place of the
+    strided axis: a dense m-point DFT matrix times each (m, lanes) tile,
+    no transpose. The inverse uses the conjugate matrix and scales by
+    1/m. A 3-D grid (NZ, NY, NX) takes its y axis as (NZ, NY, NX) and its
+    z axis as (1, NZ, NY*NX) (``core.fft.ft3d``)."""
+    assert xr.shape == xi.shape and xr.ndim == 3, (xr.shape, xi.shape)
+    fr, fi = dft_matrix(xr.shape[1])
+    if inverse:
+        fi = -fi
+    plane = jax.ShapeDtypeStruct(xr.shape, jnp.float32)
+    return column_pass(
+        functools.partial(_axis_kernel,
+                          scale=1.0 / xr.shape[1] if inverse else None),
+        "axis_dft", [fr, fi], [], [xr, xi], [plane, plane],
+        resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("inner_n", "interpret"))

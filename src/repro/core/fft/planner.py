@@ -21,6 +21,10 @@ rules, in order (docs/planner.md is the same table, and
     or exact plan above the ceiling on one device, or a shard count that
     does not tile n.
 
+``plan_grid(shape)`` plans a 3-D transform on one device (tier ``grid``,
+``core.fft.ft3d``): every axis a power of two, the x rows within the local
+ceiling, and each column pass of the y and z axes within the VMEM budget.
+
 The tiers mirror the paper's configuration ladder (§4.3-4.5): the
 r/2r-configurations are "fits in one array" (-> the single-kernel tier,
 batch across crossbars ↔ batch across devices), the 2r-beta configuration
@@ -32,12 +36,12 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.kernels.fft import plan_batch_block
+from repro.kernels.fft import VMEM_BUDGET_BYTES, plan_batch_block
 
 
 @dataclasses.dataclass(frozen=True)
 class FFTPlan:
-    tier: str           # "local" | "hbm" | "distributed"
+    tier: str           # "local" | "hbm" | "distributed" | "grid"
     block_b: int        # batch block per kernel invocation (local tier)
     seq_shards: int     # model-axis shards of the sequence (distributed tier)
     #: exact modular route: transforms dispatch to the NTT kernel
@@ -53,6 +57,8 @@ class FFTPlan:
     real: bool = False
     #: hbm tier: the row length n2 of the inner VMEM kernel (n1 = n / n2)
     inner_n: int = 0
+    #: grid tier: the grid's (NZ, NY, NX), C order, x contiguous
+    grid: tuple[int, ...] = ()
 
     def describe(self) -> str:
         if self.exact:
@@ -66,6 +72,12 @@ class FFTPlan:
                     else "four-step DFT on the MXU")
             return (f"local Pallas {kind} kernel, {algo}, "
                     f"batch block {self.block_b} (VMEM-resident)")
+        if self.tier == "grid":
+            nz, ny, nx = self.grid
+            return (f"3-D {kind} of a {nz}x{ny}x{nx} grid on one device: "
+                    f"rows of {nx} through the local kernel at batch block "
+                    f"{self.block_b}, the {ny}- and {nz}-point axes as "
+                    f"column DFT passes over HBM, no transposes")
         if self.tier == "hbm":
             return (f"HBM-resident four-step {kind} on one device: column "
                     f"DFTs and twiddles in HBM passes, rows of "
@@ -148,6 +160,30 @@ def plan(n: int, batch: int, *, model_shards: int = 1,
     _check_dist_shape(n, model_shards)
     return FFTPlan(tier="distributed", block_b=1, seq_shards=model_shards,
                    exact=exact, real=real)
+
+
+def plan_grid(shape: tuple[int, int, int]) -> FFTPlan:
+    """Plan of a 3-D transform of one (NZ, NY, NX) grid on one device
+    (tier ``grid``): the x rows through the local kernel at its planned
+    block, the y and z axes through ``hbm.axis_dft``. Raises
+    ``ValueError`` naming the constraint a grid breaks."""
+    from repro.core.fft import hbm
+    nz, ny, nx = shape
+    for m in shape:
+        if m < 2 or m & (m - 1):
+            raise ValueError(f"grid {shape}: axis of {m} points is not a "
+                             f"power of two >= 2")
+    if nx > _MAX_LOCAL_N:
+        raise ValueError(f"grid {shape}: rows of NX={nx} exceed the "
+                         f"VMEM-resident ceiling _MAX_LOCAL_N={_MAX_LOCAL_N}")
+    for m, width in ((ny, nx), (nz, ny * nx)):
+        need = hbm.axis_vmem_bytes(m, width)
+        if need > VMEM_BUDGET_BYTES:
+            raise ValueError(
+                f"grid {shape}: the {m}-point column pass needs {need} bytes "
+                f"of VMEM, over the budget of {VMEM_BUDGET_BYTES}")
+    return FFTPlan(tier="grid", block_b=plan_batch_block(nx), seq_shards=1,
+                   grid=tuple(shape))
 
 
 def _check_dist_shape(n: int, model_shards: int) -> None:

@@ -747,3 +747,107 @@ register_op(
     verify=_verify_mod,
     integrity=_integrity_polymul_mod,
 )
+
+
+# ---------------------------------------------------------------------------
+# nas-ft — NAS Parallel Benchmarks FT (NAS-95-020): one 3-D spectral solve
+# of the diffusion equation per request, on one device (core.fft.ft3d)
+# ---------------------------------------------------------------------------
+
+#: the NPB FT classes this op binds, keyed by n: (class, (NZ, NY, NX) in
+#: C order with x contiguous, steps)
+NAS_FT_CLASSES = {
+    1 << 18: ("S", (64, 64, 64), 6),
+    1 << 23: ("A", (128, 256, 256), 6),
+    1 << 25: ("B", (256, 256, 512), 20),
+    1 << 27: ("C", (512, 512, 512), 20),
+}
+#: NPB FT's diffusion constant
+NAS_FT_ALPHA = 1e-6
+
+
+def nas_ft_reference(u0: np.ndarray, shape: tuple[int, int, int],
+                     niter: int, alpha: float = NAS_FT_ALPHA) -> np.ndarray:
+    """NPB FT's checksums of one grid in plain float64 numpy: U1 =
+    fftn(u0); for t = 1..niter, W_t = ifftn(U1 * E^t) with E = exp(-4
+    alpha pi^2 |k'|^2) (k' the signed frequency of each axis), and chk_t
+    = the sum of W_t[5j mod NZ, 3j mod NY, j mod NX], j = 1..1024."""
+    u1 = np.fft.fftn(np.asarray(u0, np.complex128).reshape(shape))
+    k2 = 0.0
+    for axis, m in enumerate(shape):
+        kbar = ((np.arange(m) + m // 2) % m - m // 2).astype(np.float64)
+        k2 = np.add.outer(k2, kbar ** 2) if axis else kbar ** 2
+    j = np.arange(1, 1025)
+    pts = (5 * j % shape[0], 3 * j % shape[1], j % shape[2])
+    return np.array([
+        np.fft.ifftn(u1 * np.exp(-4.0 * alpha * np.pi ** 2 * t * k2))[pts]
+        .sum() for t in range(1, niter + 1)])
+
+
+def _route_nas_ft(spec: OpSpec, n: int, ctx: OpContext) -> tuple[Any, str]:
+    if n not in NAS_FT_CLASSES:
+        raise OpConfigError(
+            f"op 'nas-ft' binds the NPB FT classes by their grid's n: "
+            + ", ".join(f"{c} {'x'.join(map(str, s[::-1]))} (n={k})"
+                        for k, (c, s, _) in NAS_FT_CLASSES.items())
+            + f"; n={n} is none of them")
+    if ctx.verified:
+        raise OpConfigError(
+            "verified bind rejected: op 'nas-ft' has no ABFT integrity "
+            "check")
+    from repro.core.fft import planner
+    try:
+        return planner.plan_grid(NAS_FT_CLASSES[n][1]), "nas-ft-grid"
+    except ValueError as e:
+        raise OpConfigError(str(e)) from e
+
+
+def _bind_nas_ft(spec: OpSpec, n: int, ctx: OpContext, plan,
+                 route: str) -> BoundOp:
+    """The grid tier's job fn: ``ft3d.nas_ft`` returning float32 words,
+    counting each job, its 3-D transforms and their column passes
+    (``ft.jobs``, ``ft.transforms``, ``ft.axis_passes``) into the current
+    recorder."""
+    from repro.core.fft import ft3d
+    _, shape, niter = NAS_FT_CLASSES[n]
+    words = _as_words(lambda u0: ft3d.nas_ft(u0, shape=shape, niter=niter,
+                                             alpha=NAS_FT_ALPHA))
+    solve = words.pop("fn")
+
+    def fn(u0):
+        jobs = u0.shape[0]
+        telemetry.count("ft.jobs", n=jobs)
+        telemetry.count("ft.transforms", n=jobs * (1 + niter))
+        telemetry.count("ft.axis_passes",
+                        n=jobs * (1 + niter) * ft3d.AXIS_PASSES)
+        return solve(u0)
+    return BoundOp(spec=spec, n=n, ctx=ctx, plan=plan, route=route, fn=fn,
+                   payload_dtype=np.complex64, **words)
+
+
+def _verify_nas_ft(bound: BoundOp, payload, result: np.ndarray) -> float:
+    """The checksums against ``nas_ft_reference``, by the float ops'
+    measure: the largest error over the steps relative to the largest
+    reference checksum."""
+    _, shape, niter = NAS_FT_CLASSES[bound.n]
+    return _float_verify(lambda u0: nas_ft_reference(u0, shape, niter),
+                         1e-6, bound, payload, result)
+
+
+def _random_grid(bound: BoundOp, rng: np.random.Generator) -> np.ndarray:
+    """NPB FT's initial condition: both planes uniform on (0, 1)."""
+    return (rng.random(bound.n, dtype=np.float32)
+            + 1j * rng.random(bound.n, dtype=np.float32)).astype(np.complex64)
+
+
+register_op(
+    name="nas-ft", arity=1,
+    summary="NAS Parallel Benchmarks FT: one 3-D spectral solve a request "
+            "(flat complex64 grid of an NPB class S/A/B/C -> its complex "
+            "checksums), x rows VMEM-resident, y/z as column passes",
+    uses_modulus_bits=False, uses_model_shards=False,
+    _route=_route_nas_ft, _bind=_bind_nas_ft,
+    warmup_payload=_zeros,
+    random_payload=_random_grid,
+    verify=_verify_nas_ft,
+)

@@ -78,6 +78,44 @@ def test_bf16_outer_passes_fail_the_tolerance(bf16_outer, inner_n):
     assert err > 100 * TOL
 
 
+def _outer_call_before_column_pass(kernel, name, n, planes, outs, interpret):
+    """The outer pass as it was written before ``hbm.column_pass`` took it
+    over (and ``axis_dft`` with it), kept to pin gw's results bit for
+    bit."""
+    from jax.experimental import pallas as pl
+    rows, n1, n2 = planes[0].shape
+    lanes = hbm._lanes(n1, n2)
+    fr, fi, tr, ti = (jnp.asarray(t) for t in hbm.outer_tables(n, n1))
+    const = pl.BlockSpec((n1, n1), lambda j, b: (0, 0))
+    tile = pl.BlockSpec((n1, lanes), lambda j, b: (0, j))
+
+    def rows_spec(shape):
+        mid = len(shape) - 3
+        return pl.BlockSpec((1, *shape[1:-1], lanes),
+                            lambda j, b: (b,) + (0,) * (mid + 1) + (j,))
+    return pl.pallas_call(
+        kernel, grid=(n2 // lanes, rows),
+        in_specs=[const, const, tile, tile]
+        + [rows_spec(p.shape) for p in planes],
+        out_specs=[rows_spec(o.shape) for o in outs], out_shape=outs,
+        interpret=interpret, name=name)(fr, fi, tr, ti, *planes)
+
+
+@pytest.mark.parametrize("inner_n", SPLITS)
+def test_shared_column_pass_keeps_gw_bit_for_bit(monkeypatch, inner_n):
+    """``polymul_real_hbm`` through the column pass it shares with
+    ``axis_dft`` returns the same bits as through its own former pass."""
+    a, b = _rows(2**31 + 43, 3)
+    jax.clear_caches()
+    now = np.asarray(hbm.polymul_real_hbm(a, b, inner_n=inner_n))
+    monkeypatch.setattr(hbm, "_outer_call", _outer_call_before_column_pass)
+    jax.clear_caches()
+    before = np.asarray(hbm.polymul_real_hbm(a, b, inner_n=inner_n))
+    monkeypatch.undo()
+    jax.clear_caches()
+    np.testing.assert_array_equal(now, before)
+
+
 def test_outer_tables_are_exact_roots():
     fr, fi, tr, ti = hbm.outer_tables(N, 16)
     k = np.arange(16)

@@ -12,8 +12,9 @@ import pytest
 from repro.launch.ops import OpConfigError, OpContext, get_op
 
 
-def _ok(route, tier, *, real=False, exact=False, inner_n=0, seq_shards=1):
-    return (route, tier, real, exact, inner_n, seq_shards)
+def _ok(route, tier, *, real=False, exact=False, inner_n=0, seq_shards=1,
+        grid=()):
+    return (route, tier, real, exact, inner_n, seq_shards, grid)
 
 
 CEILING = "VMEM-resident ceiling _MAX_LOCAL_N="
@@ -65,6 +66,13 @@ ROWS = [
          seq_shards=8)),
     ("polymul-mod", 1024, 60, 8, "shards limbs"),
     ("polymul-mod", 1024, None, 3, TILING),
+    ("nas-ft", 1 << 18, None, 1,
+     _ok("nas-ft-grid", "grid", grid=(64, 64, 64))),
+    ("nas-ft", 1 << 25, None, 1,
+     _ok("nas-ft-grid", "grid", grid=(256, 256, 512))),
+    ("nas-ft", 1 << 24, None, 1, "NPB FT classes"),
+    ("nas-ft", 1 << 25, None, 4, "--model-shards applies to"),
+    ("nas-ft", 1 << 25, 60, 1, "--modulus-bits applies to"),
     ("dct", 1024, None, 1, "unknown op"),
 ]
 
@@ -80,4 +88,4 @@ def test_route_table(op, n, bits, shards, want):
         return
     plan, route = get_op(op).route(n, ctx)
     assert (route, plan.tier, plan.real, plan.exact, plan.inner_n,
-            plan.seq_shards) == want
+            plan.seq_shards, plan.grid) == want

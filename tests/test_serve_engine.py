@@ -45,7 +45,7 @@ from repro.launch.engine import Backpressure, ServeEngine
 def test_registry_covers_all_ops_and_serve_has_no_ladder():
     names = op_registry.op_names()
     assert set(names) == {"fft", "rfft", "polymul", "polymul-real",
-                          "polymul-mod"}
+                          "polymul-mod", "nas-ft"}
     # PR 10 promoted the old string grep ("elif op ==" in serve's source,
     # dodgeable by renaming the variable) to the AST dispatch-ladder lint
     # rule: the whole launch/ package must carry ZERO op-name string

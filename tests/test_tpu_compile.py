@@ -155,3 +155,34 @@ def test_planned_blocks_fit_the_vmem_budget():
             assert kfft.block_vmem_bytes(n, blk, real=real) \
                 <= kfft.VMEM_BUDGET_BYTES
     assert np.log2(planner._MAX_LOCAL_N) % 1 == 0
+
+
+@pytest.mark.parametrize("view", [(256, 256, 512), (1, 256, 256 * 512)],
+                         ids=["y", "z"])
+@pytest.mark.parametrize("inverse", [False, True])
+def test_axis_dft_compiles_at_npb_class_b(one_chip, view, inverse):
+    """The column pass over NPB FT Class B's (NZ, NY, NX) = (256, 256,
+    512) grid: its y view and its z view, each launch within the VMEM
+    budget."""
+    from repro.core.fft import hbm
+    x = _spec(view, jnp.float32, one_chip)
+    text = hbm.axis_dft.lower(x, x, inverse=inverse,
+                              interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text and "axis_dft" in text
+    assert hbm.axis_vmem_bytes(view[1], view[2]) <= kfft.VMEM_BUDGET_BYTES
+
+
+def test_nas_ft_route_compiles_at_npb_class_b(one_chip):
+    """The ftB-512x256x256-closed deployment: one Class B job (2^25
+    points, 20 steps) through the nas-ft route's solve, x rows, both
+    column passes and the step loop in one program."""
+    from repro.core.fft import ft3d
+    from repro.launch import ops
+    _, shape, niter = ops.NAS_FT_CLASSES[1 << 25]
+    assert planner.plan_grid(shape).tier == "grid"
+    u0 = _spec((1, 1 << 25), jnp.complex64, one_chip)
+    text = ft3d.nas_ft.lower(u0, shape=shape, niter=niter,
+                             alpha=ops.NAS_FT_ALPHA,
+                             interpret=False).compile().as_text()
+    for name in ("tpu_custom_call", "axis_dft", "fft_planes"):
+        assert name in text, name
